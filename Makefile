@@ -13,8 +13,9 @@ test-fast:
 bench:
 	$(PYTHON) bench.py
 
+# Both need a TPU: without one they exit nonzero (no CPU fallback).
 smoke:
-	$(PYTHON) bench.py --smoke
+	$(PYTHON) chip_smoke.py
 
 multichip:
 	# dryrun_multichip self-bootstraps a virtual 8-device CPU mesh when

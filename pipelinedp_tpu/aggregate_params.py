@@ -87,7 +87,7 @@ class NoiseKind(enum.Enum):
 
 
 class MechanismType(enum.Enum):
-    """Internal mechanism taxonomy used by budget accounting (reference :79-84).
+    """Internal mechanism classification used by budget accounting (reference :79-84).
 
     GENERIC covers mechanisms that consume raw (eps, delta) directly, e.g.
     private partition selection.

@@ -739,14 +739,13 @@ def _sweep_chunk_sharded(metric_names, strategy, noise_kind, P, public,
     the single-device driver's (``_pp_keep`` / ``<metric>.pp_*``)."""
     from jax.sharding import PartitionSpec as PSpec
 
-    from pipelinedp_tpu.parallel.sharded import _CHECK_KW, shard_map
+    from jax import shard_map
 
     axis = mesh.axis_names[0]
     n_dev = mesh.devices.size
     local = chunk // n_dev
     shard = PSpec(axis)
     repl = PSpec()
-    check_kw = _CHECK_KW
     # Multi-process meshes replicate the (small, [Cc]-sized) outputs
     # with one all_gather so every process fetches its own copy —
     # config-axis shards on another process are not host-addressable
@@ -775,7 +774,7 @@ def _sweep_chunk_sharded(metric_names, strategy, noise_kind, P, public,
         in_specs=(repl,) * 20,
         out_specs=((repl, repl, repl) if multiproc else
                    (shard, shard, PSpec(None, axis))),
-        **{check_kw: False})
+        check_vma=False)
     return mapped(start, marker, pk_safe, count_u, sum_u, npart_u,
                   users_pk, l0, linf, min_sum, max_sum, noise_std_rows,
                   table, thr, scale, is_tg, is_lap, is_gauss, log_rs,

@@ -61,8 +61,9 @@ class JaxBackend(LocalBackend):
         device_cache / hybrid / reship — are bit-identical.
 
     Constructing the backend also wires JAX's persistent compilation
-    cache when ``PIPELINEDP_TPU_COMPILE_CACHE`` names a directory, so
-    cold processes skip XLA recompilation of the fused kernels.
+    cache when ``JAX_COMPILATION_CACHE_DIR`` or
+    ``PIPELINEDP_TPU_COMPILE_CACHE`` names a directory, so cold
+    processes skip XLA recompilation of the fused kernels.
     """
 
     supports_fused_aggregation = True
@@ -98,6 +99,10 @@ class JaxBackend(LocalBackend):
                 # A wedged-device mesh is unusable; the CPU fallback
                 # runs single-device. NEVER silent: ``degraded`` says so.
                 self.mesh = None
+        # This process now runs on its backend: later probes must not
+        # start a child that needs the chip it holds.
+        from pipelinedp_tpu.resilience.health import hold_backend
+        hold_backend()
         from pipelinedp_tpu import obs
         # seed_fixed, never the seed itself: run reports are meant to
         # be shared, and noise draws are pure functions of the seed —

@@ -23,8 +23,9 @@ wall clock on CPU runs. This package overlaps them:
   cells with an O(n) counting-sort scatter instead of a comparison
   argsort;
 * :mod:`~pipelinedp_tpu.ingest.compile_cache` wires JAX's persistent
-  compilation cache (opt-in via ``PIPELINEDP_TPU_COMPILE_CACHE``) so a
-  cold process skips XLA recompilation.
+  compilation cache (``JAX_COMPILATION_CACHE_DIR``, else the opt-in
+  ``PIPELINEDP_TPU_COMPILE_CACHE``, else an entry point's fixed path)
+  so a cold process skips XLA recompilation.
 
 Every worker thread in the library lives here (or in ``resilience``)
 and goes through the executor's cancellable lifecycle — a lint test
@@ -38,7 +39,8 @@ The executor is ON by default and disabled with
 """
 
 from pipelinedp_tpu.ingest.assign import group_rows_by_cell
-from pipelinedp_tpu.ingest.compile_cache import maybe_enable_compile_cache
+from pipelinedp_tpu.ingest.compile_cache import (
+    compile_stats, maybe_enable_compile_cache)
 from pipelinedp_tpu.ingest.executor import (THREAD_PREFIX, BackgroundStager,
                                             IngestCancelled,
                                             OrderedFoldWorker, StagingRing,
@@ -52,5 +54,6 @@ __all__ = [
     "THREAD_PREFIX",
     "executor_enabled",
     "group_rows_by_cell",
+    "compile_stats",
     "maybe_enable_compile_cache",
 ]

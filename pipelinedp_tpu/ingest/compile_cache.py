@@ -1,22 +1,30 @@
-"""Persistent XLA compilation cache — opt-in, wired at engine init.
+"""Persistent XLA compilation cache, wired at engine init.
 
 Every cold process pays full XLA compilation for the fused kernels
-(seconds per program shape on CPU, tens of seconds on a real TPU
-toolchain). JAX ships a persistent on-disk compilation cache; setting
-``PIPELINEDP_TPU_COMPILE_CACHE=/path/to/dir`` points it at a directory
-so repeated cold runs (bench re-runs, checkpoint-resumed jobs, sweep
-restarts) reuse compiled executables across processes.
+(seconds per program shape on CPU, tens of seconds on a TPU). JAX's
+persistent on-disk compilation cache lets repeated cold runs (bench
+re-runs, checkpoint-resumed jobs, sweep restarts) reuse compiled
+executables across processes. Where the cache lives, in order:
 
-Opt-in by design: the cache directory is a shared mutable resource
-(multi-tenant hosts, version skew across jax upgrades invalidating
-entries), so the library never picks a location on its own. The
+1. ``JAX_COMPILATION_CACHE_DIR``: JAX reads it itself, and no
+   directory is set in code;
+2. ``PIPELINEDP_TPU_COMPILE_CACHE``: the library's own opt-in;
+3. ``default_dir``: the entry points (``bench.py``, ``chip_smoke.py``)
+   pass one fixed path inside their checkout. The path is part of the
+   cache key, so a moving directory would never hit.
+
+With none of them, the library picks no location (the cache directory
+is a shared mutable resource on multi-tenant hosts). The
 min-compile-time / min-entry-size thresholds are zeroed so even the
-small test-scale programs cache — the knob exists for resumability, not
-only for flagship shapes.
+small test-scale programs cache.
 
-Idempotent and failure-safe: configuring twice is a no-op, and a jax
-build without the cache options (or a read-only directory) degrades to
-a warning-free no-op rather than breaking aggregation.
+Idempotent and failure-safe: configuring twice is a no-op, and a
+read-only directory degrades to a no-op rather than breaking
+aggregation.
+
+:func:`compile_stats` counts this process's backend compiles, their
+seconds and the persistent-cache hits and misses, from
+``jax.monitoring`` events; enabling the cache starts the count.
 """
 
 from __future__ import annotations
@@ -24,59 +32,100 @@ from __future__ import annotations
 import os
 from typing import Optional
 
+JAX_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 ENV_VAR = "PIPELINEDP_TPU_COMPILE_CACHE"
 
 _configured: Optional[str] = None
 
 
-def maybe_enable_compile_cache() -> Optional[str]:
-    """Points jax's persistent compilation cache at the directory named
-    by ``PIPELINEDP_TPU_COMPILE_CACHE`` (no-op when unset). Returns the
-    configured directory, or None. Safe to call on every engine/backend
-    construction."""
+class CompileStats:
+    """Backend compile seconds and count, and persistent-cache hits and
+    misses, since the first :func:`compile_stats` call. A program
+    served from the persistent cache still counts its retrieval as a
+    backend compile."""
+
+    _COMPILE = "/jax/core/compile/backend_compile_duration"
+    _HIT = "/jax/compilation_cache/cache_hits"
+    _MISS = "/jax/compilation_cache/cache_misses"
+
+    def __init__(self):
+        self.compile_s = 0.0
+        self.compiles = 0
+        self.hits = 0
+        self.misses = 0
+
+    def _on_duration(self, event, secs, **_):
+        if event == self._COMPILE:
+            self.compile_s += secs
+            self.compiles += 1
+
+    def _on_event(self, event, **_):
+        if event == self._HIT:
+            self.hits += 1
+        elif event == self._MISS:
+            self.misses += 1
+
+    def snapshot(self):
+        """(compile_s, compiles, hits, misses) so far."""
+        return (self.compile_s, self.compiles, self.hits, self.misses)
+
+
+_stats: Optional[CompileStats] = None
+
+
+def compile_stats() -> CompileStats:
+    """The process's one :class:`CompileStats`; the first call
+    registers its ``jax.monitoring`` listeners."""
+    global _stats
+    if _stats is None:
+        import jax
+        _stats = CompileStats()
+        jax.monitoring.register_event_duration_secs_listener(
+            _stats._on_duration)
+        jax.monitoring.register_event_listener(_stats._on_event)
+    return _stats
+
+
+def maybe_enable_compile_cache(default_dir: Optional[str] = None
+                               ) -> Optional[str]:
+    """Turns on jax's persistent compilation cache (see the module
+    docstring for where it lives). Returns the directory in use, or
+    None when the cache stays off. Safe to call on every
+    engine/backend construction."""
     global _configured
-    path = os.environ.get(ENV_VAR)
+    path = (os.environ.get(JAX_ENV_VAR) or os.environ.get(ENV_VAR) or
+            default_dir)
     if not path:
         return None
+    compile_stats()
     if _configured == path:
         return _configured
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
     try:
         os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
+        if not os.environ.get(JAX_ENV_VAR):
+            jax.config.update("jax_compilation_cache_dir", path)
         # Cache everything: the default thresholds skip fast-compiling
         # programs, but resumed/test-scale runs want those too.
-        for flag, value in (
-                ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                ("jax_persistent_cache_min_entry_size_bytes", -1)):
-            try:
-                jax.config.update(flag, value)
-            except Exception:
-                pass  # older jax: threshold knob absent — cache still on
-        try:
-            # jax latches the persistent-cache state at the process's
-            # FIRST compilation; a backend constructed after any jit has
-            # run would silently get no caching without this re-init.
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:
-            pass
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        # jax latches the persistent-cache state at the process's FIRST
+        # compilation; a backend constructed after any jit has run
+        # would silently get no caching without this re-init.
+        compilation_cache.reset_cache()
         _configured = path
-        # Entry count at enable time is the observable hit evidence:
-        # a warm dir means later compilations load instead of build
-        # (XLA exposes no per-program hit counter to count directly).
-        try:
-            with os.scandir(path) as it:
-                entries = sum(1 for _ in it)
-        except OSError:
-            entries = -1
+        # Entry count at enable time is the observable warmth: a warm
+        # dir means later compilations load instead of build.
+        with os.scandir(path) as it:
+            entries = sum(1 for _ in it)
         from pipelinedp_tpu import obs
         obs.inc("compile_cache.enabled")
-        obs.inc("compile_cache.warm_entries", max(entries, 0))
+        obs.inc("compile_cache.warm_entries", entries)
         obs.event("compile_cache.enabled", dir=path, entries=entries)
-    except Exception:
-        # Never let an unwritable cache dir or an old jax break the
-        # aggregation itself.
+    except OSError:
+        # Never let an unwritable cache dir break the aggregation.
         return None
     return _configured

@@ -24,14 +24,16 @@ against this; this package is the TPU framework's native twin:
 
 The C++ source (``secure_noise.cc``) is compiled on first use with the
 toolchain's ``g++`` into a cached shared library next to this file (or
-``$PIPELINEDP_TPU_NATIVE_CACHE``). Environments without a compiler get
-``NativeUnavailableError`` and callers fall back to the NumPy path —
-``ops/noise.py`` documents the resulting security posture.
+``$PIPELINEDP_TPU_NATIVE_CACHE``), keyed by a hash of its source.
+Environments without a compiler get ``NativeUnavailableError`` and
+callers fall back to the NumPy path — ``ops/noise.py`` documents the
+resulting security posture.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -60,14 +62,21 @@ def _cache_dir() -> str:
 
 
 def _build_shared_lib(src: str, out_name: str) -> str:
-    """Compile ``src`` into the cache dir on first use (mtime-checked)."""
-    out = os.path.join(_cache_dir(), out_name)
-    if (os.path.exists(out) and
-            os.path.getmtime(out) >= os.path.getmtime(src)):
+    """Compile ``src`` into the cache dir on first use. The library's
+    file name carries a hash of the source and of the build command, so
+    a library is only ever loaded for the exact ``.cc`` it was built
+    from — never one built from another checkout and copied along."""
+    cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", src]
+    with open(src, "rb") as fh:
+        digest = hashlib.sha256(fh.read() + " ".join(cmd[:-1]).encode())
+    stem, ext = os.path.splitext(out_name)
+    out = os.path.join(_cache_dir(),
+                       f"{stem}-{digest.hexdigest()[:16]}{ext}")
+    if os.path.exists(out):
         return out
     tmp = out + f".tmp{os.getpid()}"
-    cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", src, "-o", tmp]
-    proc = subprocess.run(cmd, capture_output=True, text=True)
+    proc = subprocess.run(cmd + ["-o", tmp], capture_output=True,
+                          text=True)
     if proc.returncode != 0:
         raise NativeUnavailableError(
             f"g++ failed building {os.path.basename(src)}: "

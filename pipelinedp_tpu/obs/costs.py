@@ -101,15 +101,20 @@ def costs_enabled() -> bool:
 
 
 def device_peaks(device_kind: Optional[str]) -> Optional[Dict[str, Any]]:
-    """The peak-table row for a ``device_kind`` string, or None when no
-    row matches (the verdict is then ``unknown`` — an honest answer
-    beats a made-up ceiling)."""
+    """The peak-table row for a ``device_kind`` string. A TPU kind with
+    no row is an error: a roofline share against a made-up ceiling, or
+    an ``unknown`` verdict for the chip itself, would hide the gap in
+    the table. Any other unmatched kind gets None (verdict
+    ``unknown``)."""
     if not device_kind:
         return None
     kind = device_kind.lower()
     for row in DEVICE_PEAKS:
         if any(m in kind for m in row["match"]):
             return row
+    if "tpu" in kind:
+        raise ValueError(f"no peak row for TPU device kind "
+                         f"{device_kind!r}: add it to DEVICE_PEAKS")
     return None
 
 

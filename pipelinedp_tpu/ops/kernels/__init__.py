@@ -18,15 +18,15 @@ hand-tiled alternatives:
   and the int32 result is bit-identical to the XLA scatter path.
 * :func:`segment_sum_lanes` — the fused lane-packed segment sum: the
   ``[N, C]`` stack of 24-bit fixed-point integer lanes reduces per
-  partition as ``onehot_pk^T @ cols`` with the accumulator resident in
-  VMEM across row blocks. Lane values are at most ``2^12 - 1`` and row
-  blocks at most 512 rows, so every f32 partial sum is below 2^24 —
-  exact — and the int32 totals match ``jax.ops.segment_sum`` bit for
-  bit.
+  partition as ``onehot_pk^T @ cols`` with one partition block's
+  accumulator resident in VMEM across row blocks. Lane values ride as
+  bytes and row blocks hold at most 512 rows, so every f32 partial sum
+  is below 2^24 — exact — and the int32 totals match
+  ``jax.ops.segment_sum`` bit for bit.
 * :func:`segment_sum_wide` — the wide-D twin for VECTOR_SUM's
   fixed-point coordinate lanes: the same contraction with D tiled at
   an envelope-governed ``d_block`` (the ``segsum_wide_d_block`` knob
-  can pin it), so only a [P, Dt] accumulator slab is VMEM-resident.
+  can pin it), so only a [Pk, Dt] accumulator slab is VMEM-resident.
   Same exactness bound, same bit-identity (PARITY row 39).
 
 Dispatch is the ``kernel_backend`` knob (``plan/knobs.py``: env >

@@ -26,25 +26,31 @@ KNOWN_BACKENDS = ("xla", "pallas")
 #: reads flow through the knob registry, never this name directly.
 _KERNEL_BACKEND = "xla"
 
-#: VMEM byte budget for a kernel's resident output block. 16 MB/core
-#: minus double-buffered input blocks and one-hot operands leaves a
-#: comfortable 4 MB; larger pass-B packings fall back to XLA (which
-#: the sweep planner already sized to the HBM cap, not VMEM).
+#: VMEM byte budget for a kernel's resident output block. The kernels
+#: ask Mosaic for ``hist.VMEM_LIMIT_BYTES`` (64 MiB of v5e's 128 MiB)
+#: of scoped VMEM: the output block is double-buffered, and the one-hot
+#: operands, their iota and compare planes and the matmul result each
+#: take about as much again, so the working set is ~5x the largest of
+#: these caps. Mosaic's default 16 MiB scope refuses the wide-D kernel
+#: at an 8192-partition block; ``tests/test_tpu_compile.py`` compiles
+#: every envelope corner for v5e.
 _OUT_BYTES_CAP = 4 << 20
 
-#: One-hot operand byte budget per row block (the [Pb+span, R] or
-#: [P, R] compare planes) — bounds the row-block width choice below.
+#: One-hot operand byte budget per row block (the [Pk+span, R] or
+#: [Pk, R] compare planes) — bounds the row-block width choice below.
 _ONEHOT_BYTES_CAP = 4 << 20
 
-#: Unrolled (tile x quantile-group) loop bound for the histogram
-#: binner: each (t, q) pair emits two MXU contractions per row block,
-#: and unrolling hundreds of them would bloat the program.
-_HIST_UNROLL_CAP = 64
+#: Unrolled quantile-group loop bound for the histogram binner: each
+#: quantile emits one MXU contraction per grid step.
+_HIST_UNROLL_CAP = 32
 
-#: Lane-packed segment sum envelope: the [P, C] accumulator (and the
-#: [P, R] one-hot) must be VMEM-resident in ONE partition block —
-#: tiling P would re-stream the whole row axis once per block.
-_SEGSUM_MAX_P = 8192
+#: Partition-block width of the segment sums: the grid's outer axis
+#: walks blocks of at most this many partitions, each block's [Pk, C]
+#: accumulator and [Pk, R] one-hot VMEM-resident while the inner axis
+#: streams every row block. A partition axis past one block re-reads
+#: the rows once per block (the one-hot work is rows x partitions
+#: either way).
+_SEGSUM_P_BLOCK = 8192
 _SEGSUM_MAX_COLS = 32
 
 #: Row-block candidates, widest first. Exactness bound: every f32
@@ -52,10 +58,10 @@ _SEGSUM_MAX_COLS = 32
 #: R <= 512, so integer accumulation through the f32 MXU stays exact.
 _ROW_BLOCKS = (512, 256, 128)
 
-#: Wide-D segment sum: D-tile candidates, widest first. The [P, Dt]
+#: Wide-D segment sum: D-tile candidates, widest first. The [Pk, Dt]
 #: accumulator slab must fit ``_OUT_BYTES_CAP`` (8192 partitions x
-#: 128 lanes x 4 B is exactly 4 MB, so even the max-P envelope keeps
-#: a tile). Lane values stay below 2^12 (the vector fixed-point lane
+#: 128 lanes x 4 B is exactly 4 MB, so even a full partition block
+#: keeps a tile). Lane values stay below 2^12 (the vector fixed-point lane
 #: plan), so the scalar kernel's exactness bound carries over.
 _D_BLOCKS = (512, 256, 128)
 
@@ -88,10 +94,11 @@ def pallas_available() -> bool:
 
 
 def use_interpret() -> bool:
-    """Pallas interpret mode everywhere but a real TPU: the kernels
-    then lower to plain jax ops (bit-identical arithmetic), so the
-    CPU proxy and tier-1 CI assert the same parity the TPU path
-    claims."""
+    """Pallas interpret mode everywhere but a TPU: the kernels then
+    lower to plain jax ops (bit-identical arithmetic), so tier-1 CI on
+    the CPU asserts the same parity the TPU path claims. The compiled
+    (Mosaic) path is covered by ``tests/test_tpu_compile.py`` for a
+    described v5e and by ``chip_smoke.py``'s Pallas phase on the chip."""
     import jax
     return jax.default_backend() != "tpu"
 
@@ -105,41 +112,56 @@ def _row_block(per_row_bytes: int) -> Optional[int]:
     return None
 
 
-def hist_envelope(T: int, Pb: int, Qc: int, span: int) -> Optional[int]:
-    """Row-block width for an in-envelope ``[T, Pb, Qc, span]``
-    histogram request, or None when the shape falls outside the tiled
-    envelope (output not VMEM-resident, one-hots too wide, or the
-    (t, q) unroll too deep)."""
-    if T * Pb * Qc * span * 4 > _OUT_BYTES_CAP:
+def hist_envelope(T: int, Pb: int, Qc: int, span: int):
+    """``(row_block, p_block)`` for an in-envelope ``[T, Pb, Qc, span]``
+    histogram request, or None when out of envelope (more quantiles
+    than the unroll cap, or one-hots too wide). Tiles run one after
+    another on the grid, and a tile's partitions in blocks of
+    ``p_block`` (the whole tile when it fits, else a multiple of 128,
+    the walk-start block's lane width) whose [Qc, p_block, span]
+    output stays VMEM-resident."""
+    if Qc > _HIST_UNROLL_CAP:
         return None
-    if T * Qc > _HIST_UNROLL_CAP:
-        return None
-    return _row_block((Pb + span) * 4)
+    per_p = Qc * span * 4
+    if Pb * per_p <= _OUT_BYTES_CAP:
+        pb = Pb
+    else:
+        pb = (_OUT_BYTES_CAP // per_p) // 128 * 128
+        if pb < 128:
+            return None
+    rb = _row_block((pb + span) * 4)
+    return None if rb is None else (rb, pb)
 
 
-def segsum_envelope(P: int, C: int) -> Optional[int]:
-    """Row-block width for an in-envelope ``[P, C]`` lane segment-sum
-    request, or None when out of envelope."""
-    if P > _SEGSUM_MAX_P or C > _SEGSUM_MAX_COLS or C < 1:
+def _segsum_p_block(P: int) -> int:
+    return min(P, _SEGSUM_P_BLOCK)
+
+
+def segsum_envelope(P: int, C: int):
+    """``(row_block, p_block)`` for an in-envelope ``[P, C]`` lane
+    segment-sum request, or None when out of envelope."""
+    if C > _SEGSUM_MAX_COLS or C < 1:
         return None
-    if P * C * 4 > _OUT_BYTES_CAP:
-        return None
-    return _row_block(P * 4)
+    pb = _segsum_p_block(P)
+    rb = _row_block(pb * 4)
+    return None if rb is None else (rb, pb)
 
 
 def segsum_wide_envelope(P: int, D: int):
-    """``(row_block, d_block)`` for an in-envelope wide-D ``[P, D]``
-    vector segment-sum request, or None when out of envelope. Unlike
-    :func:`segsum_envelope` there is no column cap — D is tiled — but
-    the [P, Dt] slab and the [P, R] one-hot must both fit VMEM."""
-    if P > _SEGSUM_MAX_P or D < 1:
+    """``(row_block, d_block, p_block)`` for an in-envelope wide-D
+    ``[P, D]`` vector segment-sum request, or None when out of
+    envelope. Unlike :func:`segsum_envelope` there is no column cap —
+    D is tiled — but the [Pk, Dt] slab and the [Pk, R] one-hot must
+    both fit VMEM."""
+    if D < 1:
         return None
-    rb = _row_block(P * 4)
+    pb = _segsum_p_block(P)
+    rb = _row_block(pb * 4)
     if rb is None:
         return None
     for db in _D_BLOCKS:
-        if P * db * 4 <= _OUT_BYTES_CAP:
-            return rb, db
+        if pb * db * 4 <= _OUT_BYTES_CAP:
+            return rb, db, pb
     return None
 
 
@@ -176,12 +198,14 @@ def try_segment_sum_lanes(cols, pk, P: int, requested: str):
     if requested != "pallas":
         return None
     C = int(cols.shape[1])
-    rb = segsum_envelope(P, C)
-    if select_backend(requested, "segment_sum_lanes", rb, P=int(P),
-                      C=C, rows=int(pk.shape[0])) != "pallas":
+    env = segsum_envelope(P, C)
+    if select_backend(requested, "segment_sum_lanes",
+                      env[0] if env else None, P=int(P), C=C,
+                      rows=int(pk.shape[0])) != "pallas":
         return None
     from pipelinedp_tpu.ops.kernels.segsum import segment_sum_lanes
-    return segment_sum_lanes(cols, pk, P, rb, use_interpret())
+    rb, pb = env
+    return segment_sum_lanes(cols, pk, P, rb, pb, use_interpret())
 
 
 def try_segment_sum_wide(cols, pk, P: int, requested: str,
@@ -200,11 +224,11 @@ def try_segment_sum_wide(cols, pk, P: int, requested: str,
     if select_backend(requested, "segment_sum_wide", rb, P=int(P),
                       D=D, rows=int(pk.shape[0])) != "pallas":
         return None
-    db = env[1]
-    if d_block in _D_BLOCKS and P * d_block * 4 <= _OUT_BYTES_CAP:
+    _, db, pb = env
+    if d_block in _D_BLOCKS and pb * d_block * 4 <= _OUT_BYTES_CAP:
         db = d_block
     from pipelinedp_tpu.ops.kernels.segsum import segment_sum_wide
-    return segment_sum_wide(cols, pk, P, rb, db, use_interpret())
+    return segment_sum_wide(cols, pk, P, rb, db, pb, use_interpret())
 
 
 def try_hist_bin_multi(qpk, leaf, kept, sub_starts, p_offsets, Pb: int,
@@ -214,11 +238,12 @@ def try_hist_bin_multi(qpk, leaf, kept, sub_starts, p_offsets, Pb: int,
     if requested != "pallas":
         return None
     T, _, Qc = sub_starts.shape
-    rb = hist_envelope(int(T), int(Pb), int(Qc), int(span))
-    if select_backend(requested, "hist_bin_multi", rb, T=int(T),
-                      Pb=int(Pb), Qc=int(Qc),
-                      span=int(span)) != "pallas":
+    env = hist_envelope(int(T), int(Pb), int(Qc), int(span))
+    if select_backend(requested, "hist_bin_multi",
+                      env[0] if env else None, T=int(T), Pb=int(Pb),
+                      Qc=int(Qc), span=int(span)) != "pallas":
         return None
     from pipelinedp_tpu.ops.kernels.hist import hist_bin_multi
+    rb, pb = env
     return hist_bin_multi(qpk, leaf, kept, sub_starts, p_offsets, Pb,
-                          span, rb, use_interpret())
+                          span, rb, pb, use_interpret())

@@ -1,7 +1,7 @@
 """The multi-tile pass-B histogram binner, as a Pallas TPU kernel.
 
 One batch's rows bin into EVERY packed ``[T, Pb, Qc, span]`` pass-B
-tile histogram in a single VMEM-resident pass — the Pallas twin of
+tile histogram in a single ``pallas_call`` — the Pallas twin of
 ``jax_engine._subtree_counts_multi`` (one masked XLA scatter per
 (tile, quantile), T*Qc row passes in the generic lowering).
 
@@ -14,27 +14,24 @@ Scatter-free formulation: for tile ``t`` and quantile-group column
 which is the matmul ``onehot_p^T @ onehot_s`` over a row block, where
 ``onehot_p[p, r] = (qpk[r] - p_offsets[t] == p) & kept[r]`` and
 ``onehot_s[s, r] = (leaf[r] - start_row[r] == s)``. The per-row walk
-start gathers through the SAME one-hot as a matvec
-(``sub_starts[t, :, q] @ onehot_p``, exact — one nonzero per row), so
-the kernel needs no gather, no scatter and no sort: two MXU
-contractions per (t, q) per row block, with the whole [T, Pb, Qc,
-span] output resident in VMEM across the row grid.
+start gathers through the SAME one-hot (``sub_starts[t, :, q] @
+onehot_p``, one nonzero per row), so the kernel needs no gather, no
+scatter and no sort. The grid is (tiles, partition blocks, row
+blocks): each tile's partitions go in blocks whose [Qc, Pk, span]
+output stays VMEM-resident while every row block streams past.
 
-Bit-identity: every product is 0/1 (or a single leaf index < 2^16),
-every per-block partial sum is at most the row-block width (<= 512 <
-2^24), so the f32 MXU arithmetic is exact integer arithmetic and the
+Bit-identity: every one-hot product is 0/1 and every per-block partial
+sum is at most the row-block width (<= 512 < 2^24), so one bf16 MXU
+pass with f32 accumulation is exact integer arithmetic; the walk
+starts (below 2^16) gather as their two bytes, each exact in bf16. The
 int32 accumulator equals the XLA scatter path bit for bit — asserted
 four ways in ``tests/test_pass_b.py`` and at the kernel level in
-``tests/test_kernels.py``. The tile-relative partition index is
-computed in INT32 (``qpk - p_offsets[t]``) before the one f32 cast:
-any int32 magnitude below 2^24 casts exactly, and anything at or past
-2^24 casts to a float of at least that magnitude — which can never
-equal an iota value below ``Pb`` — so the membership compare is
-correct for EVERY int32 partition id, not just ids below 2^24.
+``tests/test_kernels.py``. Every membership test compares int32 ids
+against an int32 iota, so it is exact for EVERY int32 partition id.
 
-Rows out of a tile's partition block, rows outside [0, span) of a
-walk start and padding rows all match no one-hot column: masking is
-free and identical to the XLA path's ``ok`` predicate.
+Rows out of a partition block, rows outside [0, span) of a walk start
+and padding rows all match no one-hot column: masking is free and
+identical to the XLA path's ``ok`` predicate.
 """
 
 from __future__ import annotations
@@ -44,105 +41,126 @@ import jax.numpy as jnp
 
 from pipelinedp_tpu.obs.costs import instrumented_jit
 
+#: Scoped-VMEM budget handed to Mosaic for every kernel here. The
+#: dispatch envelopes (``dispatch.py``) size the resident output and
+#: the one-hot planes against it.
+VMEM_LIMIT_BYTES = 64 << 20
 
-def _compiler_params(interpret: bool):
-    """Mosaic params for the compiled path: the row grid accumulates
-    into a revisited output block, so its dimension is 'arbitrary'
-    (never parallelized). Interpret mode takes none."""
-    if interpret:
-        return {}
+
+def compiler_params(n_grid_axes: int):
+    """Mosaic params for a grid whose LAST axis walks the rows into a
+    revisited output block ('arbitrary': never split across cores);
+    the outer axes (tiles, partition blocks, D tiles) are independent
+    ('parallel'). Interpret mode ignores them."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "TPUCompilerParams", None) or getattr(
-        pltpu, "CompilerParams", None)
-    if cls is None:
-        return {}
-    try:
-        return {"compiler_params": cls(
-            dimension_semantics=("arbitrary",))}
-    except TypeError:
-        return {"compiler_params": cls()}
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * (n_grid_axes - 1) +
+        ("arbitrary",),
+        vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
-def _hist_kernel_body(T: int, Qc: int):
-    """The kernel body for a static (T, Qc) — python loops unroll the
-    (tile, quantile-group) grid (bounded by the dispatch envelope)."""
+def row_ids(ids, row_block: int, index_map):
+    """A per-row int32 column laid out for the row grid: zero-padded
+    to whole blocks and shaped ``[n_blocks, 1, row_block]``, with the
+    BlockSpec that hands the kernel one ``[1, row_block]`` row per
+    grid step. The block's last two dims equal the array's, which is
+    what Mosaic's (8, 128) tiling rule accepts for a one-row block."""
+    from jax.experimental import pallas as pl
+    n = ids.shape[0]
+    n_pad = -(-n // row_block) * row_block
+    ids3 = jnp.pad(ids.astype(jnp.int32), (0, n_pad - n)).reshape(
+        -1, 1, row_block)
+    return ids3, pl.BlockSpec((None, 1, row_block), index_map)
+
+
+def _hist_kernel_body(Qc: int):
+    """The kernel body for a static quantile-group width ``Qc`` (a
+    python loop, bounded by the dispatch envelope)."""
 
     def body(qpk_ref, leaf_ref, kept_ref, starts_ref, poff_ref,
              out_ref):
         from jax.experimental import pallas as pl
-        _, Pb, _, span = out_ref.shape
-        R = qpk_ref.shape[1]
+        _, _, Pk, span = out_ref.shape
+        R = qpk_ref.shape[-1]
+        bf16 = jnp.bfloat16
 
-        @pl.when(pl.program_id(0) == 0)
+        @pl.when(pl.program_id(2) == 0)
         def _init():
             out_ref[...] = jnp.zeros_like(out_ref)
 
-        qpk = qpk_ref[0, :]                           # [R] int32
-        leaf = leaf_ref[0, :].astype(jnp.float32)     # < 2^16: exact
-        kept = kept_ref[0, :] != 0
-        iota_p = jax.lax.broadcasted_iota(jnp.float32, (Pb, R), 0)
-        iota_s = jax.lax.broadcasted_iota(jnp.float32, (span, R), 0)
-        for t in range(T):
-            # int32 subtract FIRST (see module docstring): the f32
-            # cast of the small relative index is then exact for any
-            # int32 partition id / offset.
-            rel_pk = (qpk - poff_ref[t, 0]).astype(jnp.float32)
-            oh_p = jnp.where(
-                (rel_pk[None, :] == iota_p) & kept[None, :],
-                1.0, 0.0)                              # [Pb, R]
-            for q in range(Qc):
-                starts = starts_ref[t, :, q].astype(jnp.float32)
-                # Gather-as-matvec: one nonzero per row -> exact.
-                start_row = jax.lax.dot_general(
-                    starts[None, :], oh_p,
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)[0]  # [R]
-                rel = leaf - start_row
-                oh_s = jnp.where(rel[None, :] == iota_s, 1.0, 0.0)
-                part = jax.lax.dot_general(
-                    oh_p, oh_s,
-                    dimension_numbers=(((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)  # [Pb, span]
-                out_ref[t, :, q, :] += part.astype(jnp.int32)
+        # Integer compares throughout: exact for every int32
+        # partition id and offset.
+        local = (qpk_ref[...] - poff_ref[pl.program_id(0)] -
+                 pl.program_id(1) * Pk)               # [1, R]
+        iota_p = jax.lax.broadcasted_iota(jnp.int32, (Pk, R), 0)
+        iota_s = jax.lax.broadcasted_iota(jnp.int32, (span, R), 0)
+        oh_p = jnp.where((local == iota_p) & (kept_ref[...] != 0),
+                         1.0, 0.0).astype(bf16)       # [Pk, R]
+        leaf = leaf_ref[...]                          # [1, R]
+
+        def gather(byte):
+            # [Qc, Pk] bytes of the walk starts @ the one-hot: one
+            # nonzero per row, a value below 256 — exact in bf16.
+            return jax.lax.dot_general(
+                byte.astype(jnp.float32).astype(bf16), oh_p,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(jnp.int32)
+
+        starts = starts_ref[0]                        # [Qc, Pk] int32
+        start_rows = gather(starts >> 8) * 256 + gather(starts & 255)
+        for q in range(Qc):
+            oh_s = jnp.where(leaf - start_rows[q:q + 1, :] == iota_s,
+                             1.0, 0.0).astype(bf16)   # [span, R]
+            part = jax.lax.dot_general(
+                oh_p, oh_s,
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)   # [Pk, span]
+            out_ref[0, q] += part.astype(jnp.int32)
 
     return body
 
 
 def hist_bin_multi(qpk, leaf, kept, sub_starts, p_offsets, Pb: int,
-                   span: int, row_block: int, interpret: bool):
+                   span: int, row_block: int, p_block: int,
+                   interpret: bool):
     """Pallas multi-tile subtree-leaf counts: same contract as
     ``jax_engine._subtree_counts_multi`` — ``sub_starts`` [T, Pb, Qc],
     ``p_offsets`` [T], output [T, Pb, Qc, span] int32, bit-identical
-    to the per-tile XLA scatters. ``row_block`` comes from
-    ``dispatch.hist_envelope`` (callers dispatch through
-    ``select_backend``; this function assumes in-envelope shapes)."""
+    to the per-tile XLA scatters. ``row_block`` and ``p_block`` come
+    from ``dispatch.hist_envelope`` (callers dispatch through
+    ``select_backend``; this function assumes in-envelope shapes).
+
+    Inside the kernel the walk starts and the histogram are laid out
+    quantile-major (``[T, Qc, Pb]`` and ``[T, Qc, Pb, span]``) so each
+    quantile reads and writes whole (sublane, lane) tiles; the output
+    is transposed back outside."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
     T, _, Qc = sub_starts.shape
-    n = qpk.shape[0]
-    n_pad = -(-n // row_block) * row_block
-    pad = n_pad - n
+    p_pad = -(-Pb // p_block) * p_block
+    index_map = lambda t, j, i: (i, 0, 0)  # noqa: E731
     # Padding rows carry kept=0 and match no one-hot column.
-    qpk2 = jnp.pad(qpk, (0, pad)).reshape(-1, row_block)
-    leaf2 = jnp.pad(leaf, (0, pad)).reshape(-1, row_block)
-    kept2 = jnp.pad(kept.astype(jnp.int32), (0, pad)).reshape(
-        -1, row_block)
-    poff = p_offsets.astype(jnp.int32).reshape(T, 1)
-    return pl.pallas_call(
-        _hist_kernel_body(T, Qc),
-        grid=(n_pad // row_block,),
+    qpk3, spec = row_ids(qpk, row_block, index_map)
+    leaf3, _ = row_ids(leaf, row_block, index_map)
+    kept3, _ = row_ids(kept, row_block, index_map)
+    starts = jnp.pad(
+        jnp.transpose(sub_starts.astype(jnp.int32), (0, 2, 1)),
+        ((0, 0), (0, 0), (0, p_pad - Pb)))
+    out = pl.pallas_call(
+        _hist_kernel_body(Qc),
+        grid=(T, p_pad // p_block, qpk3.shape[0]),
         in_specs=[
-            pl.BlockSpec((1, row_block), lambda i: (i, 0)),
-            pl.BlockSpec((1, row_block), lambda i: (i, 0)),
-            pl.BlockSpec((1, row_block), lambda i: (i, 0)),
-            pl.BlockSpec((T, Pb, Qc), lambda i: (0, 0, 0)),
-            pl.BlockSpec((T, 1), lambda i: (0, 0)),
+            spec, spec, spec,
+            pl.BlockSpec((1, Qc, p_block), lambda t, j, i: (t, 0, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((T, Pb, Qc, span),
-                               lambda i: (0, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((T, Pb, Qc, span), jnp.int32),
+        out_specs=pl.BlockSpec((1, Qc, p_block, span),
+                               lambda t, j, i: (t, 0, j, 0)),
+        out_shape=jax.ShapeDtypeStruct((T, Qc, p_pad, span), jnp.int32),
         interpret=interpret,
-        **_compiler_params(interpret),
-    )(qpk2, leaf2, kept2, sub_starts, poff)
+        compiler_params=compiler_params(3),
+    )(qpk3, leaf3, kept3, starts, p_offsets.astype(jnp.int32))
+    return jnp.transpose(out[:, :, :Pb], (0, 2, 1, 3))
 
 
 #: Standalone instrumented entry (phase ``pass_b``): direct host
@@ -154,4 +172,5 @@ def hist_bin_multi(qpk, leaf, kept, sub_starts, p_offsets, Pb: int,
 #: ``kernel_backend`` static argument keys the before/after entries.)
 hist_bin_multi_program = instrumented_jit(
     phase="pass_b", static_argnames=("Pb", "span", "row_block",
-                                     "interpret"))(hist_bin_multi)
+                                     "p_block", "interpret"))(
+                                         hist_bin_multi)

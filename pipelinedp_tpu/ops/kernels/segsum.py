@@ -10,13 +10,18 @@ keeps the lanes in registers/VMEM across the whole reduction::
     out[p, c] = sum_r (pk[r] == p) * cols[r, c]
               = (onehot_pk^T @ cols)[p, c]
 
-with the ``[P, C]`` accumulator VMEM-resident across row blocks.
+with a ``[Pk, C]`` accumulator block VMEM-resident across row blocks:
+the grid's outer axis walks partition blocks of ``Pk`` (the whole axis
+when it fits), the inner axis every row block.
 
 Bit-identity: lane values are at most ``2^12 - 1`` (the widest lane
-plan) and count/marker columns are 0/1, so with row blocks of at most
-512 rows every f32 partial sum is below ``512 * 4095 < 2^21 < 2^24``
-— exact f32 integer arithmetic — and the int32 accumulation across
-blocks is associative integer addition. The result equals
+plan) and count/marker columns are 0/1. Each value contracts as its
+two bytes, each exact in bf16, in two one-pass bf16 MXU contractions
+with f32 accumulation (a third of the passes of an f32 contraction at
+``Precision.HIGHEST``). With row blocks of at most 512 rows every f32
+partial sum is below ``512 * 255 < 2^24`` — exact integer arithmetic
+— and the int32 accumulation across blocks is associative integer
+addition. Exact for every value in [-2^16, 2^16). The result equals
 ``jax.ops.segment_sum`` bit for bit (asserted in
 ``tests/test_kernels.py``, including at the lane-plan boundary
 widths).
@@ -25,12 +30,11 @@ Invalid rows already arrive masked (pk 0, all columns 0 — the XLA
 path's convention), so they add exact zeros; padding rows appended
 here do the same.
 
-:func:`segment_sum_wide` is the wide-D twin for VECTOR_SUM's
-fixed-point coordinate lanes: the same contraction with the D axis
-tiled at an envelope-governed ``d_block`` so a [P, Dt] accumulator
-slab (not the whole [P, D] block) is VMEM-resident, with the row axis
-as the inner grid dimension so each slab sees every row block before
-the next tile starts.
+:func:`segment_sum_wide` is the one kernel, also used for VECTOR_SUM's
+fixed-point coordinate lanes: the D axis is tiled at an
+envelope-governed ``d_block`` so a [Pk, Dt] accumulator slab (not the
+whole [P, D] block) is VMEM-resident; :func:`segment_sum_lanes` runs
+it with the C lane columns as one tile.
 """
 
 from __future__ import annotations
@@ -39,115 +43,89 @@ import jax
 import jax.numpy as jnp
 
 from pipelinedp_tpu.obs.costs import instrumented_jit
-from pipelinedp_tpu.ops.kernels.hist import _compiler_params
+from pipelinedp_tpu.ops.kernels.hist import compiler_params, row_ids
 
 
 def _segsum_kernel_body(pk_ref, cols_ref, out_ref):
+    """One grid step of ``out += onehot(pk)^T @ cols``: the grid is
+    (partition blocks, D tiles, row blocks), so each [Pk, Dt]
+    accumulator slab is zeroed at its first row block and sees every
+    row block before the grid moves on."""
     from jax.experimental import pallas as pl
-    P, _ = out_ref.shape
-    R = pk_ref.shape[1]
+    Pk = out_ref.shape[0]
+    R = pk_ref.shape[-1]
 
-    @pl.when(pl.program_id(0) == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    pk = pk_ref[0, :].astype(jnp.float32)             # [R], exact ints
-    iota_p = jax.lax.broadcasted_iota(jnp.float32, (P, R), 0)
-    oh = jnp.where(pk[None, :] == iota_p, 1.0, 0.0)   # [P, R]
-    part = jax.lax.dot_general(
-        oh, cols_ref[...].astype(jnp.float32),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)           # [P, C]
-    out_ref[...] += part.astype(jnp.int32)
+    # Integer iota against integer ids: exact for every int32.
+    iota_p = jax.lax.broadcasted_iota(jnp.int32, (Pk, R), 0)
+    local = pk_ref[...] - pl.program_id(0) * Pk       # [1, R]
+    oh = jnp.where(local == iota_p, 1.0, 0.0).astype(jnp.bfloat16)
+    cols = cols_ref[...]                              # [R, Dt] int32
+
+    def contract(byte):
+        # [Pk, R] 0/1 @ [R, Dt] bytes: one bf16 pass, exact.
+        return jax.lax.dot_general(
+            oh, byte.astype(jnp.float32).astype(jnp.bfloat16),
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(jnp.int32)
+
+    out_ref[...] += contract(cols >> 8) * 256 + contract(cols & 255)
 
 
-def segment_sum_lanes(cols, pk, P: int, row_block: int,
+def segment_sum_wide(cols, pk, P: int, row_block: int, d_block: int,
+                     p_block: int, interpret: bool):
+    """Tiled segment sum: ``cols`` [N, D] int32 (fixed-point lanes),
+    ``pk`` [N] int32 in [0, P) — returns [P, D] int32 bit-identical to
+    ``jax.ops.segment_sum(cols, pk, num_segments=P)``.
+
+    The grid is (partition blocks of ``p_block``, D tiles of
+    ``d_block``, row blocks of ``row_block``), row blocks innermost, so
+    only a [Pk, Dt] accumulator slab is VMEM-resident at a time. The
+    three widths come from ``dispatch.segsum_wide_envelope``."""
+    from jax.experimental import pallas as pl
+    n, D = cols.shape
+    d_pad = -(-D // d_block) * d_block
+    p_pad = -(-P // p_block) * p_block
+    pk3, pk_spec = row_ids(pk, row_block, lambda p, j, i: (i, 0, 0))
+    cols2 = jnp.pad(cols, ((0, pk3.size - n), (0, d_pad - D)))
+    out = pl.pallas_call(
+        _segsum_kernel_body,
+        grid=(p_pad // p_block, d_pad // d_block, pk3.shape[0]),
+        in_specs=[pk_spec,
+                  pl.BlockSpec((row_block, d_block),
+                               lambda p, j, i: (i, j))],
+        out_specs=pl.BlockSpec((p_block, d_block),
+                               lambda p, j, i: (p, j)),
+        out_shape=jax.ShapeDtypeStruct((p_pad, d_pad), jnp.int32),
+        interpret=interpret,
+        compiler_params=compiler_params(3),
+    )(pk3, cols2)
+    return out[:P, :D]
+
+
+def segment_sum_lanes(cols, pk, P: int, row_block: int, p_block: int,
                       interpret: bool):
     """Pallas lane-packed segment sum: ``cols`` [N, C] int32, ``pk``
     [N] int32 in [0, P) — returns [P, C] int32 bit-identical to
-    ``jax.ops.segment_sum(cols, pk, num_segments=P)``. ``row_block``
-    comes from ``dispatch.segsum_envelope``."""
-    from jax.experimental import pallas as pl
-    n, C = cols.shape
-    n_pad = -(-n // row_block) * row_block
-    pad = n_pad - n
-    pk2 = jnp.pad(pk, (0, pad)).reshape(-1, row_block)
-    cols2 = jnp.pad(cols, ((0, pad), (0, 0)))
-    return pl.pallas_call(
-        _segsum_kernel_body,
-        grid=(n_pad // row_block,),
-        in_specs=[
-            pl.BlockSpec((1, row_block), lambda i: (i, 0)),
-            pl.BlockSpec((row_block, C), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((P, C), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((P, C), jnp.int32),
-        interpret=interpret,
-        **_compiler_params(interpret),
-    )(pk2, cols2)
+    ``jax.ops.segment_sum(cols, pk, num_segments=P)``: the tiled
+    kernel with all C columns as one tile. ``row_block`` and
+    ``p_block`` come from ``dispatch.segsum_envelope``."""
+    return segment_sum_wide(cols, pk, P, row_block, cols.shape[1],
+                            p_block, interpret)
 
 
 #: Standalone instrumented entry (phase ``engine``) — see
 #: ``hist.hist_bin_multi_program`` for the seam rationale.
 segment_sum_lanes_program = instrumented_jit(
-    phase="engine", static_argnames=("P", "row_block", "interpret"))(
-        segment_sum_lanes)
-
-
-def _segsum_wide_kernel_body(pk_ref, cols_ref, out_ref):
-    from jax.experimental import pallas as pl
-    P, _ = out_ref.shape
-    R = pk_ref.shape[1]
-
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    pk = pk_ref[0, :].astype(jnp.float32)             # [R], exact ints
-    iota_p = jax.lax.broadcasted_iota(jnp.float32, (P, R), 0)
-    oh = jnp.where(pk[None, :] == iota_p, 1.0, 0.0)   # [P, R]
-    part = jax.lax.dot_general(
-        oh, cols_ref[...].astype(jnp.float32),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)           # [P, Dt]
-    out_ref[...] += part.astype(jnp.int32)
-
-
-def segment_sum_wide(cols, pk, P: int, row_block: int, d_block: int,
-                     interpret: bool):
-    """Wide-D tiled segment sum: ``cols`` [N, D] int32 (fixed-point
-    vector lanes), ``pk`` [N] int32 in [0, P) — returns [P, D] int32
-    bit-identical to ``jax.ops.segment_sum(cols, pk, num_segments=P)``.
-
-    Same one-hot MXU contraction as :func:`segment_sum_lanes`, but D
-    is tiled at ``d_block`` (the outer grid axis) so only a [P, Dt]
-    accumulator slab is VMEM-resident at a time; the row axis is the
-    INNER grid axis, so each slab accumulates across all row blocks
-    before the grid advances to the next D tile. ``row_block`` and
-    ``d_block`` come from ``dispatch.segsum_wide_envelope``."""
-    from jax.experimental import pallas as pl
-    n, D = cols.shape
-    n_pad = -(-n // row_block) * row_block
-    d_pad = -(-D // d_block) * d_block
-    pk2 = jnp.pad(pk, (0, n_pad - n)).reshape(-1, row_block)
-    cols2 = jnp.pad(cols, ((0, n_pad - n), (0, d_pad - D)))
-    out = pl.pallas_call(
-        _segsum_wide_kernel_body,
-        grid=(d_pad // d_block, n_pad // row_block),
-        in_specs=[
-            pl.BlockSpec((1, row_block), lambda j, i: (i, 0)),
-            pl.BlockSpec((row_block, d_block), lambda j, i: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((P, d_block), lambda j, i: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((P, d_pad), jnp.int32),
-        interpret=interpret,
-        **_compiler_params(interpret),
-    )(pk2, cols2)
-    return out[:, :D]
+    phase="engine", static_argnames=("P", "row_block", "p_block",
+                                     "interpret"))(segment_sum_lanes)
 
 
 #: Standalone instrumented entry for the wide-D kernel.
 segment_sum_wide_program = instrumented_jit(
     phase="engine",
-    static_argnames=("P", "row_block", "d_block", "interpret"))(
-        segment_sum_wide)
+    static_argnames=("P", "row_block", "d_block", "p_block",
+                     "interpret"))(segment_sum_wide)

@@ -49,16 +49,7 @@ from jax.sharding import PartitionSpec as PSpec
 from pipelinedp_tpu import jax_engine
 from pipelinedp_tpu.obs.costs import instrumented_jit
 
-try:  # jax>=0.6 exposes shard_map at the top level
-    from jax import shard_map  # type: ignore
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
-
-import inspect
-
-# The replication-check kwarg was renamed check_rep -> check_vma.
-_CHECK_KW = ("check_vma" if "check_vma" in inspect.signature(
-    shard_map).parameters else "check_rep")
+from jax import shard_map
 
 # ---------------------------------------------------------------------------
 # Mesh topology: the two-axis ("dcn", "ici") view over the 1-D data axis
@@ -535,7 +526,7 @@ def _sharded_kernel(config, num_partitions, mesh, pid, pk, values, valid,
         in_specs=(shard, shard, shard, shard, repl, repl, repl, repl,
                   repl, repl, repl),
         out_specs=shard,
-        **{_CHECK_KW: False})
+        check_vma=False)
     return mapped(pid, pk, values, valid, noise_scales, keep_table,
                   sel_threshold, sel_scale, sel_min_count,
                   sel_rows_per_uid, key)

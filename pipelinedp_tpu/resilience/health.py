@@ -1,14 +1,20 @@
-"""Device-health probing with timeout, bounded retry, and graceful —
-flagged, never silent — degradation to CPU.
+"""Device-health probing with timeout and bounded retry, plus the
+library's opt-in, flagged degradation to CPU.
 
-The failure mode this guards (BENCH_r05: ``DEVICE UNREACHABLE: device
-probe did not return within 300s``, rc=3) is a *wedged* accelerator
-runtime: ``jax.devices()`` blocks forever inside backend init, so the
-probe must run in a subprocess it can kill. On exhaustion the caller
-gets a ``HealthReport`` with ``degraded=True`` and the process is
-steered to ``JAX_PLATFORMS=cpu`` — results produced in this mode must
-carry the flag all the way to the output (bench emits
-``"degraded": true``).
+The failure mode this guards is a *wedged* accelerator runtime:
+``jax.devices()`` blocks forever inside backend init, so a process that
+has not initialized a backend yet probes in a subprocess it can kill.
+A process that already holds its backend — it took its devices
+through :func:`hold_backend`, as ``JaxBackend`` and the entry points
+do, or probed in-process before — probes in-process: the chip belongs
+to one process at a time, so a child could never get it.
+
+:func:`probe_with_retry` only reports. :func:`ensure_device_or_degrade`
+(``JaxBackend(health_policy=...)``) and :func:`resilient_make_mesh` are
+the explicit, flagged library API that falls back to CPU on exhaustion;
+results produced in that mode carry ``degraded=True``. The entry points
+(``bench.py``, ``chip_smoke.py``) never degrade: without a healthy TPU
+they exit nonzero.
 """
 
 from __future__ import annotations
@@ -25,7 +31,7 @@ from pipelinedp_tpu.resilience.clock import Clock, SystemClock
 from pipelinedp_tpu.resilience.retry import (RetriesExhausted, RetryPolicy,
                                              call_with_retry)
 
-#: Per-attempt probe timeout; the r05 wedge took the full 300s default.
+#: Per-attempt probe timeout.
 PROBE_TIMEOUT_ENV = "PIPELINEDP_TPU_PROBE_TIMEOUT"
 DEFAULT_PROBE_TIMEOUT_S = 300.0
 
@@ -44,9 +50,9 @@ def cancel_active_probe() -> None:
     """Abort the in-flight device probe attempt, if any. This is the
     stall watchdog's hook (bench wires it as its ``on_stall`` action):
     a probe that has emitted no span activity past the stall deadline
-    is almost certainly the r05 wedge — kill it NOW, emit the flight
-    record, and let the retry/degrade machinery take over, instead of
-    sitting silently through the remaining minutes of probe timeout."""
+    is almost certainly a wedged runtime — kill it NOW, emit the flight
+    record, and let the retry machinery take over, instead of sitting
+    silently through the remaining minutes of probe timeout."""
     _PROBE_CANCEL.set()
 
 #: Set (alongside ``JAX_PLATFORMS=cpu``) when degradation steered this
@@ -81,13 +87,32 @@ def probe_timeout_s() -> float:
                                 DEFAULT_PROBE_TIMEOUT_S))
 
 
+#: Whether this process took its jax backend through the library
+#: (:func:`hold_backend` or an in-process probe). JAX has no public
+#: query for it, so the library keeps its own.
+_backend_held = False
+
+
+def hold_backend():
+    """This process's jax devices (``jax.devices()`` initializes the
+    backend, and on a TPU takes the chip). From here on every probe of
+    this process runs in-process. Returns the device list."""
+    global _backend_held
+    import jax
+    devices = jax.devices()
+    _backend_held = True
+    return devices
+
+
 def probe_devices(timeout_s: Optional[float] = None,
                   clock: Optional[Clock] = None):
-    """One device probe: run ``jax.devices()`` in a killable subprocess
-    (a wedged runtime blocks *inside* backend init — an in-process call
-    could never time out). The wait polls in short beats so the stall
-    watchdog's :func:`cancel_active_probe` can cut a wedged probe short
-    at the stall deadline instead of the full timeout. Returns
+    """One device probe. Before this process has a backend, run
+    ``jax.devices()`` in a killable subprocess (a wedged runtime blocks
+    *inside* backend init — an in-process call could never time out);
+    the wait polls in short beats so the stall watchdog's
+    :func:`cancel_active_probe` can cut a wedged probe short at the
+    stall deadline instead of the full timeout. A process that already
+    holds its backend (:func:`hold_backend`) probes in-process. Returns
     ``(ok, detail)``."""
     timeout_s = probe_timeout_s() if timeout_s is None else timeout_s
     clock = clock or SystemClock()
@@ -110,6 +135,12 @@ def probe_devices(timeout_s: Optional[float] = None,
                 waited += step
         return False, (f"device probe did not return within {timeout_s:g}s"
                        " (injected wedge)")
+    if _backend_held:
+        try:
+            hold_backend()
+        except RuntimeError as e:
+            return False, f"{type(e).__name__}: {e}"[-300:]
+        return True, "ok"
     probe_env = dict(os.environ)
     if probe_env.get(DEGRADED_ENV):
         # A prior degradation forced JAX_PLATFORMS=cpu; the probe must
@@ -381,23 +412,13 @@ def supervisor_from_env(mesh) -> Optional[MeshSupervisor]:
                           jax.process_count())
 
 
-def ensure_device_or_degrade(policy: Optional[RetryPolicy] = None,
-                             clock: Optional[Clock] = None,
-                             timeout_s: Optional[float] = None,
-                             env=None) -> HealthReport:
-    """Probe the accelerator with bounded retry + backoff; on exhaustion
-    degrade to CPU by setting ``JAX_PLATFORMS=cpu`` in ``env`` (effective
-    only if jax has not initialized its backend in this process yet) and
-    report ``degraded=True``. Never raises: the caller always gets a
-    usable platform and an honest report.
-
-    ``env`` defaults to ``os.environ`` — the only mapping jax (and the
-    probe subprocess) actually reads. Passing a custom mapping is for
-    TESTS ONLY: it records what the function *would* install without
-    touching process state, so the returned report describes the
-    simulated outcome, not an applied one."""
+def probe_with_retry(policy: Optional[RetryPolicy] = None,
+                     clock: Optional[Clock] = None,
+                     timeout_s: Optional[float] = None) -> HealthReport:
+    """Probe the accelerator with bounded retry + backoff. Only reports:
+    it never raises and never changes the platform. On exhaustion the
+    report is ``healthy=False`` with the last probe's detail."""
     policy = policy or DEFAULT_HEALTH_POLICY
-    env = os.environ if env is None else env
     attempts = [0]
     backoffs: List[float] = []
 
@@ -421,6 +442,36 @@ def ensure_device_or_degrade(policy: Optional[RetryPolicy] = None,
             attempt, policy, clock, retry_on=(_ProbeFailed,),
             on_retry=lambda a, d, e: backoffs.append(d),
             label="health.device_probe")
+    except RetriesExhausted as e:
+        return HealthReport(healthy=False, degraded=False,
+                            attempts=attempts[0], backoff_s=backoffs,
+                            detail=str(e.last_error))
+    return HealthReport(healthy=True, degraded=False,
+                        attempts=attempts[0], backoff_s=backoffs,
+                        detail=detail)
+
+
+def ensure_device_or_degrade(policy: Optional[RetryPolicy] = None,
+                             clock: Optional[Clock] = None,
+                             timeout_s: Optional[float] = None,
+                             env=None) -> HealthReport:
+    """:func:`probe_with_retry`; on exhaustion degrade to CPU by setting
+    ``JAX_PLATFORMS=cpu`` in ``env`` (effective only if jax has not
+    initialized its backend in this process yet) and report
+    ``degraded=True``. Never raises: the caller always gets a usable
+    platform and an honest report. The library's opt-in API
+    (``JaxBackend(health_policy=...)``); the entry points never call it.
+
+    ``env`` defaults to ``os.environ`` — the only mapping jax (and the
+    probe subprocess) actually reads. Passing a custom mapping is for
+    TESTS ONLY: it records what the function *would* install without
+    touching process state, so the returned report describes the
+    simulated outcome, not an applied one."""
+    env = os.environ if env is None else env
+    from pipelinedp_tpu import obs
+
+    report = probe_with_retry(policy, clock, timeout_s)
+    if report.healthy:
         if env.get(DEGRADED_ENV):
             # The accelerator recovered: lift the degradation override
             # we installed (the CPU pin only, never a user's own
@@ -430,21 +481,16 @@ def ensure_device_or_degrade(policy: Optional[RetryPolicy] = None,
             env.pop(DEGRADED_ENV, None)
             if env.get("JAX_PLATFORMS") == "cpu":
                 env.pop("JAX_PLATFORMS")
-            obs.event("health.recovered", attempts=attempts[0])
-        return HealthReport(healthy=True, degraded=False,
-                            attempts=attempts[0], backoff_s=backoffs,
-                            detail=detail)
-    except RetriesExhausted as e:
-        env["JAX_PLATFORMS"] = "cpu"
-        env[DEGRADED_ENV] = "1"
-        # A formerly-silent branch (the caller saw only the report):
-        # the degradation is now a first-class ledger event.
-        obs.inc("health.degradations")
-        obs.event("health.degraded", target="cpu_platform",
-                  attempts=attempts[0], detail=str(e.last_error))
-        return HealthReport(healthy=False, degraded=True,
-                            attempts=attempts[0], backoff_s=backoffs,
-                            detail=str(e.last_error))
+            obs.event("health.recovered", attempts=report.attempts)
+        return report
+    env["JAX_PLATFORMS"] = "cpu"
+    env[DEGRADED_ENV] = "1"
+    # A formerly-silent branch (the caller saw only the report): the
+    # degradation is a first-class ledger event.
+    obs.inc("health.degradations")
+    obs.event("health.degraded", target="cpu_platform",
+              attempts=report.attempts, detail=report.detail)
+    return dataclasses.replace(report, degraded=True)
 
 
 def resilient_make_mesh(n_devices: Optional[int] = None,
@@ -457,9 +503,10 @@ def resilient_make_mesh(n_devices: Optional[int] = None,
     (the sharded kernels are platform-agnostic), just slow, and the
     report says so.
 
-    Each attempt first runs the KILLABLE subprocess probe: a wedged
-    runtime blocks *inside* ``jax.devices()``, so calling ``make_mesh``
-    directly could hang forever — the probe times out instead and the
+    Each attempt first runs :func:`probe_devices` (a killable
+    subprocess while this process has no backend): a wedged runtime
+    blocks *inside* ``jax.devices()``, so calling ``make_mesh`` directly
+    could hang forever — the probe times out instead and the
     retry/fallback machinery keeps control. (A runtime that wedges in
     the window between a passing probe and the in-process call can
     still hang; the probe shrinks that window, it cannot close it.)
@@ -483,7 +530,9 @@ def resilient_make_mesh(n_devices: Optional[int] = None,
         ok, detail = probe_devices()
         if not ok:
             raise TimeoutError(detail)
-        return sharded.make_mesh(n_devices, axis_name)
+        mesh = sharded.make_mesh(n_devices, axis_name)
+        hold_backend()
+        return mesh
 
     from pipelinedp_tpu import obs
 
@@ -524,17 +573,12 @@ def resilient_distributed_initialize(coordinator_address: str,
     ``RetriesExhausted`` when the coordinator never answers — a hard
     deadline, not a hang.
 
-    Extra keyword arguments are forwarded to the underlying
-    initializer. The heartbeat tolerances
-    (``service_max_missing_heartbeats`` et al.) matter for elastic
-    recovery: the coordination service's default is to FATALLY
-    terminate every surviving client ~100s after any peer stops
-    heartbeating — exactly the window in which the mesh supervisor is
-    re-forming the mesh and resuming. On jax versions whose public
-    ``jax.distributed.initialize`` does not yet accept them, they are
-    routed through the distributed state object that does."""
-    import inspect
-
+    Extra keyword arguments are forwarded to
+    ``jax.distributed.initialize``. ``heartbeat_timeout_seconds``
+    matters for elastic recovery: by default the coordination service
+    FATALLY terminates every surviving client ~100s after any peer
+    stops heartbeating — exactly the window in which the mesh
+    supervisor is re-forming the mesh and resuming."""
     import jax
 
     policy = policy or RetryPolicy(max_attempts=2, base_delay_s=1.0,
@@ -542,20 +586,7 @@ def resilient_distributed_initialize(coordinator_address: str,
                                    jitter=0.25, seed=process_id)
 
     def _initialize():
-        public = jax.distributed.initialize
-        accepted = inspect.signature(public).parameters
-        if all(k in accepted for k in initialize_kwargs):
-            public(coordinator_address=coordinator_address,
-                   num_processes=num_processes, process_id=process_id,
-                   **initialize_kwargs)
-            return
-        from jax._src import distributed as _dist
-        from jax._src import xla_bridge as _bridge
-        if _bridge.backends_are_initialized():
-            raise RuntimeError(
-                "jax.distributed.initialize() must be called before "
-                "any JAX computations are executed.")
-        _dist.global_state.initialize(
+        jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes, process_id=process_id,
             **initialize_kwargs)

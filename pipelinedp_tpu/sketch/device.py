@@ -138,7 +138,7 @@ def sharded_sketch_chunk_program(width: int, backend: str, mesh,
     mapped = psh.shard_map(
         local_fn, mesh=mesh, in_specs=(row_shard,),
         out_specs=psh.PSpec() if multiproc else psh.PSpec(None, axis),
-        **{psh._CHECK_KW: False})
+        check_vma=False)
     return mapped(buckets)
 
 

@@ -63,8 +63,7 @@ def main() -> None:
         # client after ~100s — the exact recovery this test exists to
         # prove. Stretch the tolerance past the harness deadline so
         # OUR supervisor, not jax's, owns death detection here.
-        service_max_missing_heartbeats=1000,
-        client_max_missing_heartbeats=1000)
+        heartbeat_timeout_seconds=1000)
     assert len(jax.devices()) == 4 * n_proc, jax.devices()
     assert len(jax.local_devices()) == 4
 

@@ -943,7 +943,7 @@ class TestFusedSweepFuzz:
 
 
 class TestFusedSweepPerPartition:
-    """``return_per_partition=True`` runs fused too (VERDICT r3 #6): the
+    """``return_per_partition=True`` runs fused too: the
     per-(partition, config) SumMetrics rows fetched from stage B must
     match the host oracle's per-partition rows; past the fetch byte cap
     the sweep reroutes itself to the host graph and still returns the
@@ -1013,8 +1013,8 @@ class TestFusedSweepPerPartition:
         self._assert_rows_match(host, fused, private=False)
 
     def test_matches_host_rows_on_mesh(self, monkeypatch):
-        """return_per_partition stays FUSED on a multi-device mesh
-        (VERDICT r4 #7): the config-axis-sharded [P, C] blocks gather
+        """return_per_partition stays FUSED on a multi-device mesh:
+        the config-axis-sharded [P, C] blocks gather
         to the same rows the host oracle produces."""
         import jax
         from pipelinedp_tpu.backends import JaxBackend
@@ -1057,7 +1057,7 @@ class TestFusedSweepPerPartition:
 
 
 class TestFusedSweepMixedMechanisms:
-    """VERDICT r2 #6: per-config ``noise_kind`` /
+    """Per-config ``noise_kind`` /
     ``partition_selection_strategy`` vectors run FUSED (previously host
     fallback), matching the host oracle per configuration."""
 
@@ -1093,7 +1093,7 @@ class TestFusedSweepMixedMechanisms:
 
 
 class TestFusedSweepPreAggregated:
-    """VERDICT r2 #6: pre-aggregated input runs fused (stage A skipped);
+    """Pre-aggregated input runs fused (stage A skipped);
     results must match the host graph on the same pre-aggregated rows."""
 
     _assert_metrics_close = staticmethod(TestFusedSweep._assert_metrics_close)

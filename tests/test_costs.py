@@ -87,7 +87,9 @@ class TestRoofline:
         assert costs.device_peaks("TPU v4")["kind"] == "tpu_v4"
         cpu = costs.device_peaks("cpu")
         assert cpu["kind"] == "cpu_proxy" and cpu["proxy"] is True
-        assert costs.device_peaks("TPU v9000") is None
+        # A TPU kind the table lacks is an error, not "unknown".
+        with pytest.raises(ValueError, match="TPU v9000"):
+            costs.device_peaks("TPU v9000")
         assert costs.device_peaks(None) is None
 
     def test_verdict_flips_exactly_at_the_ridge(self):

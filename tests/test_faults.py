@@ -507,17 +507,20 @@ class TestElasticMeshRecovery:
                     np.asarray(baseline[k].vector_sum), rtol=1e-6)
 
 
-class TestBenchDegradation:
-    """The BENCH_r05 failure mode, end to end: a wedged device probe
-    must yield rc=0 and parseable ``"degraded": true`` JSON, not rc=3 —
-    and with the heartbeat monitor on, the wedge (held for real via
-    ``wedged_hold``) is cancelled at the stall deadline, the degraded
-    artifact embeds the flight-record path + stall diagnosis, and the
-    heartbeat file survives the run."""
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-    def test_wedged_probe_bench_exits_zero_with_degraded_json(
+
+class TestNoCpuFallback:
+    """The entry points never fall back to the CPU. A wedged device
+    probe must yield a NONZERO exit whose stderr explains itself — with
+    the heartbeat monitor on, the wedge (held for real via
+    ``wedged_hold``) is cancelled at the stall deadline and stderr
+    carries the stall diagnosis and the flight-record path, while the
+    heartbeat file survives the run. A host without a TPU exits
+    nonzero too, and no headline is ever printed."""
+
+    def test_wedged_probe_bench_exits_nonzero_with_stall_diagnosis(
             self, tmp_path):
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         ledger_dir = str(tmp_path / "ledger")
         env = dict(os.environ)
         env["PIPELINEDP_TPU_FAULTS"] = "wedged_init=99,wedged_hold=1"
@@ -529,25 +532,21 @@ class TestBenchDegradation:
         env["PIPELINEDP_TPU_STALL_S"] = "0.3"
         env["PIPELINEDP_TPU_LEDGER_DIR"] = ledger_dir
         env["JAX_PLATFORMS"] = "cpu"
-        env.pop("PIPELINEDP_TPU_DEGRADED", None)  # fresh process state
         env.pop("PYTHONPATH", None)
         proc = subprocess.run(
             [sys.executable, "bench.py", "--smoke", "--flagship-only",
              "--stream-rows", "0"],
-            cwd=repo, env=env, capture_output=True, text=True,
-            timeout=1200)
-        assert proc.returncode == 0, proc.stderr[-3000:]
-        headline = json.loads(proc.stdout.strip().splitlines()[-1])
-        assert headline["degraded"] is True
-        assert headline["value"] > 0
-        assert "DEVICE UNREACHABLE" in proc.stderr
+            cwd=REPO, env=env, capture_output=True, text=True,
+            timeout=300)
+        assert proc.returncode != 0, proc.stderr[-3000:]
+        assert not proc.stdout.strip(), proc.stdout[-2000:]
+        assert "DEVICE UNREACHABLE after 2 probe attempts" in proc.stderr
         # The watchdog, not the 30s probe timeout, ended each attempt.
-        diagnosis = headline["degraded_diagnosis"]
-        assert diagnosis["probe_attempts"] == 2
-        assert "cancelled by the stall watchdog" in diagnosis["detail"]
-        assert "flight_record" in diagnosis
-        flight = json.load(open(diagnosis["flight_record"],
-                                encoding="utf-8"))
+        assert "cancelled by the stall watchdog" in proc.stderr
+        assert "## stall: " in proc.stderr
+        flight_path = proc.stderr.split("## flight record: ")[1].split(
+            "\n")[0].strip()
+        flight = json.load(open(flight_path, encoding="utf-8"))
         assert flight["stall"]["deadline_s"] == 0.3
         # The live heartbeat streamed next to the durable ledger,
         # namespaced by the bench's run name (resident processes
@@ -559,19 +558,60 @@ class TestBenchDegradation:
         hb = json.load(open(hb_files[0], encoding="utf-8"))
         assert hb["phase"]
 
-    def test_probe_helper_degrades_without_subprocess(self, monkeypatch):
+    def test_probe_helper_exits_nonzero_without_subprocess(
+            self, monkeypatch, capsys):
         """The bench probe helper itself (fast, tier-1): wedged probe →
-        degraded report, backoff schedule from the env knobs."""
+        SystemExit(1) after the env knobs' attempt count, and the
+        process platform is left alone."""
         monkeypatch.setenv("PIPELINEDP_TPU_PROBE_ATTEMPTS", "2")
         monkeypatch.setenv("PIPELINEDP_TPU_PROBE_BACKOFF", "0.0")
-        # Roll back the degradation the helper writes into os.environ.
-        monkeypatch.setenv("JAX_PLATFORMS",
-                           os.environ.get("JAX_PLATFORMS", "cpu"))
-        monkeypatch.setenv("PIPELINEDP_TPU_DEGRADED", "")
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        monkeypatch.syspath_prepend(repo)
+        platforms = os.environ.get("JAX_PLATFORMS")
+        monkeypatch.syspath_prepend(REPO)
         import bench
         with injected_faults(FaultPlan(wedged_init=99)):
-            report = bench._ensure_device_or_degrade()
-        assert report.degraded
-        assert report.attempts == 2
+            with pytest.raises(SystemExit) as exc:
+                bench._require_device()
+        assert exc.value.code == 1
+        assert ("DEVICE UNREACHABLE after 2 probe attempts"
+                in capsys.readouterr().err)
+        assert os.environ.get("JAX_PLATFORMS") == platforms
+        assert not os.environ.get("PIPELINEDP_TPU_DEGRADED")
+
+    @pytest.mark.parametrize("cmd", [
+        ["bench.py", "--smoke", "--flagship-only", "--stream-rows", "0"],
+        ["chip_smoke.py"],
+    ])
+    def test_entry_point_without_tpu_exits_nonzero(self, cmd):
+        """On the CPU both entry points refuse within seconds and print
+        no result line."""
+        env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
+        env.pop("PIPELINEDP_TPU_FAULTS", None)
+        proc = subprocess.run([sys.executable] + cmd, cwd=REPO, env=env,
+                              capture_output=True, text=True,
+                              timeout=120)
+        assert proc.returncode != 0
+        assert '"ok": true' not in proc.stdout
+        assert not proc.stdout.strip().startswith("{")
+        assert "TPU" in proc.stderr, proc.stderr[-2000:]
+
+    @pytest.mark.parametrize("held", [False, True])
+    def test_probe_child_only_before_the_backend_is_held(
+            self, monkeypatch, held):
+        """A process that took its backend through ``hold_backend``
+        (as JaxBackend and the entry points do) probes in-process: a
+        child could not get the chip it holds. Before that, the probe
+        is a killable child."""
+        from pipelinedp_tpu.resilience import health
+        spawned = []
+
+        class NoChild:
+            def __init__(self, *a, **kw):
+                spawned.append(a)
+                raise OSError("no child in this test")
+
+        monkeypatch.setattr(health, "_backend_held", held)
+        monkeypatch.setattr(health.subprocess, "Popen", NoChild)
+        ok, detail = health.probe_devices(timeout_s=5.0)
+        assert bool(spawned) != held
+        assert ok == held, detail

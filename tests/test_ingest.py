@@ -554,6 +554,7 @@ class TestCompileCache:
         from pipelinedp_tpu.ingest import compile_cache
         import jax
         cache_dir = tmp_path / "xla_cache"
+        monkeypatch.delenv(compile_cache.JAX_ENV_VAR, raising=False)
         monkeypatch.setenv(compile_cache.ENV_VAR, str(cache_dir))
         monkeypatch.setattr(compile_cache, "_configured", None)
         try:
@@ -568,6 +569,9 @@ class TestCompileCache:
             # cache hit never reaches the persistent cache.
             import jax
             jax.clear_caches()
+            # Enabling the cache started the process's compile count.
+            _, compiles0, _, misses0 = (
+                compile_cache.compile_stats().snapshot())
             rng = np.random.default_rng(0)
             ds = pdp.ArrayDataset(
                 privacy_ids=rng.integers(0, 50, 3_000),
@@ -586,18 +590,59 @@ class TestCompileCache:
             assert len(dict(res)) == 5
             assert any(cache_dir.iterdir()), (
                 "no compiled executables persisted to the cache dir")
+            _, compiles1, _, misses1 = (
+                compile_cache.compile_stats().snapshot())
+            assert compiles1 > compiles0 and misses1 > misses0
         finally:
             # Un-point jax from the tmp dir (deleted after the test).
             jax.config.update("jax_compilation_cache_dir", None)
-            try:
-                from jax._src import compilation_cache as _cc
-                _cc.reset_cache()
-            except Exception:
-                pass
+            from jax.experimental.compilation_cache import (
+                compilation_cache as _cc)
+            _cc.reset_cache()
             monkeypatch.setattr(compile_cache, "_configured", None)
 
     def test_unset_env_is_noop(self, monkeypatch):
         from pipelinedp_tpu.ingest import compile_cache
         monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+        monkeypatch.delenv(compile_cache.JAX_ENV_VAR, raising=False)
         monkeypatch.setattr(compile_cache, "_configured", None)
         assert compile_cache.maybe_enable_compile_cache() is None
+
+    def test_jax_env_var_wins_and_sets_no_dir(self, tmp_path,
+                                              monkeypatch):
+        """``JAX_COMPILATION_CACHE_DIR`` is JAX's own: it beats the
+        library knob and the entry point's default, and no directory
+        is set in code."""
+        from pipelinedp_tpu.ingest import compile_cache
+        import jax
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv(compile_cache.JAX_ENV_VAR,
+                           str(tmp_path / "jax"))
+        monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path / "pdp"))
+        monkeypatch.setattr(compile_cache, "_configured", None)
+        try:
+            assert (compile_cache.maybe_enable_compile_cache(
+                default_dir=str(tmp_path / "default")) ==
+                    str(tmp_path / "jax"))
+            assert jax.config.jax_compilation_cache_dir == before
+        finally:
+            monkeypatch.setattr(compile_cache, "_configured", None)
+
+    def test_default_dir_when_no_env(self, tmp_path, monkeypatch):
+        """The entry points' fixed in-checkout path applies only when
+        neither variable names a directory."""
+        from pipelinedp_tpu.ingest import compile_cache
+        from jax.experimental.compilation_cache import compilation_cache
+        import jax
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+        monkeypatch.delenv(compile_cache.JAX_ENV_VAR, raising=False)
+        monkeypatch.setattr(compile_cache, "_configured", None)
+        default = str(tmp_path / "default")
+        try:
+            assert (compile_cache.maybe_enable_compile_cache(
+                default_dir=default) == default)
+            assert jax.config.jax_compilation_cache_dir == default
+        finally:
+            jax.config.update("jax_compilation_cache_dir", None)
+            compilation_cache.reset_cache()
+            monkeypatch.setattr(compile_cache, "_configured", None)

@@ -386,7 +386,10 @@ class TestFusedPercentile:
 
     def test_compound_with_other_metrics_field_order(self):
         noise_ops.seed_host_rng(0)
-        data = [(u, "a", float(u % 100)) for u in range(1000)]
+        # 1001 rows: the median's rank (500.5) sits strictly inside the
+        # run of 49s (cumulative counts 491 and 501), never on a count
+        # boundary where the noise would pick between 49 and 50.
+        data = [(u, "a", float(u % 100)) for u in range(1001)]
         params = self._percentile_params(
             [50], metrics=[pdp.Metrics.COUNT, pdp.Metrics.SUM,
                            pdp.Metrics.PERCENTILE(50)])
@@ -396,6 +399,7 @@ class TestFusedPercentile:
         assert fused["a"].count == pytest.approx(local["a"].count, abs=0.5)
         assert fused["a"].percentile_50 == pytest.approx(
             local["a"].percentile_50, abs=0.2)
+        assert fused["a"].percentile_50 == pytest.approx(49.0, abs=0.2)
 
     def test_degenerate_clip_range_rejected_at_params(self):
         """A zero-width clip range with percentiles fails at params
@@ -586,7 +590,7 @@ class TestEnforcedBoundsSelectionEstimate:
 
 
 class TestShardedMultiChipBroad:
-    """VERDICT r1 #8: VARIANCE, VECTOR_SUM, per-partition-bound SUM and
+    """VARIANCE, VECTOR_SUM, per-partition-bound SUM and
     public partitions on the 8-device mesh, each pinned to the
     single-device output."""
 
@@ -846,7 +850,7 @@ class TestFusedSelectMore:
 
 
 class TestPartitionAxisSharding:
-    """VERDICT r2 #1: the pk axis is sharded over the mesh — per-device
+    """The pk axis is sharded over the mesh — per-device
     accumulator state is O(P/n_dev) (owner blocks via psum_scatter), and
     owner-mode selection reproduces the single-chip decisions
     bit-for-bit."""
@@ -930,7 +934,7 @@ class TestPartitionAxisSharding:
 
 
 class TestFixedPointAccumulation:
-    """VERDICT r2 weak #2 / next #4: value partials accumulate as exact
+    """Value partials accumulate as exact
     fixed-point int32 lanes on device (``_fixedpoint_layout``), leaving
     only the per-row quantization error (bound/2^23, independent of
     partition size). A partition of ~10^7 identical values is where a
@@ -1046,6 +1050,37 @@ class TestCompactFetchFallback:
         assert len(fused) == n_parts
         assert fused[0].count == pytest.approx(3, abs=0.3)
         assert fused[n_parts - 1].count == pytest.approx(3, abs=0.3)
+
+    def test_past_cap_release_matches_streamed(self, monkeypatch):
+        """Past the cap the single-batch release still draws host noise
+        over the kept rows only, in ascending pk order: a streamed run
+        with the same seed releases the same values bit for bit."""
+        from pipelinedp_tpu import jax_engine as je
+        monkeypatch.setattr(je, "_COMPACT_FETCH_CAP", 8)
+        rng = np.random.default_rng(5)
+        n = 3_000
+        # One row per privacy id: bounding drops nothing on either path.
+        # Partitions 0..39 hold ~75 ids each and are kept; every
+        # partition from 40 up holds one id and is dropped, so the kept
+        # rows are not a prefix of the pk axis.
+        pk = np.concatenate([rng.integers(0, 40, n - 20),
+                             40 + np.arange(20)])
+        ds = pdp.ArrayDataset(privacy_ids=np.arange(n),
+                              partition_keys=pk,
+                              values=rng.uniform(0.0, 10.0, n))
+        params = pdp.AggregateParams(
+            metrics=[pdp.Metrics.VARIANCE, pdp.Metrics.PERCENTILE(90)],
+            max_partitions_contributed=3,
+            max_contributions_per_partition=1, min_value=0.0,
+            max_value=10.0)
+        single = run(JaxBackend(rng_seed=3), ds, params, eps=1e3,
+                     delta=1e-6, ext=pdp.DataExtractors())
+        assert 8 < len(single) < 60  # past the (shrunken) cap
+        ds.invalidate_cache()
+        monkeypatch.setenv("PIPELINEDP_TPU_STREAM_CHUNK", "1000")
+        streamed = run(JaxBackend(rng_seed=3), ds, params, eps=1e3,
+                       delta=1e-6, ext=pdp.DataExtractors())
+        assert streamed == single
 
 
 class TestLanePlanBoundary:

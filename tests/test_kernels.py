@@ -70,9 +70,25 @@ class TestSegsumKernelParity:
         pk = jnp.asarray(rng.integers(0, P, n).astype(np.int32))
         cols = jnp.asarray(
             rng.integers(0, 4096, (n, C)).astype(np.int32))
-        rb = kernels.segsum_envelope(P, C)
-        assert rb is not None
-        got = kernels.segment_sum_lanes(cols, pk, P, rb,
+        env = kernels.segsum_envelope(P, C)
+        assert env is not None
+        got = kernels.segment_sum_lanes(cols, pk, P, *env,
+                                        kernels.use_interpret())
+        ref = jax.ops.segment_sum(cols, pk, num_segments=P)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+    @pytest.mark.parametrize("P,block", [(200, 64), (130, 128)])
+    def test_partition_blocks_parity(self, monkeypatch, P, block):
+        """Past one partition block the grid walks several (the last
+        one ragged): still bit-identical."""
+        monkeypatch.setattr(dispatch, "_SEGSUM_P_BLOCK", block)
+        rng = np.random.default_rng(P)
+        n = 3000
+        pk = jnp.asarray(rng.integers(0, P, n).astype(np.int32))
+        cols = jnp.asarray(rng.integers(0, 4096, (n, 5)).astype(np.int32))
+        rb, pb = kernels.segsum_envelope(P, 5)
+        assert pb == block < P
+        got = kernels.segment_sum_lanes(cols, pk, P, rb, pb,
                                         kernels.use_interpret())
         ref = jax.ops.segment_sum(cols, pk, num_segments=P)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
@@ -87,9 +103,9 @@ class TestSegsumKernelParity:
         lane_max = (1 << bits) - 1
         pk = jnp.zeros(n, jnp.int32)
         cols = jnp.full((n, 3), lane_max, jnp.int32)
-        rb = kernels.segsum_envelope(P, 3)
         got = np.asarray(kernels.segment_sum_lanes(
-            cols, pk, P, rb, kernels.use_interpret()))
+            cols, pk, P, *kernels.segsum_envelope(P, 3),
+            kernels.use_interpret()))
         assert int(got[0, 0]) == n * lane_max
         ref = np.asarray(jax.ops.segment_sum(cols, pk, num_segments=P))
         np.testing.assert_array_equal(got, ref)
@@ -111,8 +127,7 @@ class TestWideSegsumKernelParity:
             rng.integers(0, 4096, (n, D)).astype(np.int32))
         env = kernels.segsum_wide_envelope(P, D)
         assert env is not None
-        rb, db = env
-        got = kernels.segment_sum_wide(cols, pk, P, rb, db,
+        got = kernels.segment_sum_wide(cols, pk, P, *env,
                                        kernels.use_interpret())
         ref = jax.ops.segment_sum(cols, pk, num_segments=P)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
@@ -126,9 +141,9 @@ class TestWideSegsumKernelParity:
         lane_max = (1 << 12) - 1
         pk = jnp.zeros(n, jnp.int32)
         cols = jnp.full((n, D), lane_max, jnp.int32)
-        rb, db = kernels.segsum_wide_envelope(P, D)
         got = np.asarray(kernels.segment_sum_wide(
-            cols, pk, P, rb, db, kernels.use_interpret()))
+            cols, pk, P, *kernels.segsum_wide_envelope(P, D),
+            kernels.use_interpret()))
         assert int(got[0, 0]) == n * lane_max
         ref = np.asarray(jax.ops.segment_sum(cols, pk, num_segments=P))
         np.testing.assert_array_equal(got, ref)
@@ -143,9 +158,9 @@ class TestWideSegsumKernelParity:
         cols = jnp.asarray(
             rng.integers(0, 4096, (n, D)).astype(np.int32))
         ref = np.asarray(jax.ops.segment_sum(cols, pk, num_segments=P))
-        rb, _ = kernels.segsum_wide_envelope(P, D)
+        rb, _, pb = kernels.segsum_wide_envelope(P, D)
         for db in dispatch._D_BLOCKS:
-            got = kernels.segment_sum_wide(cols, pk, P, rb, db,
+            got = kernels.segment_sum_wide(cols, pk, P, rb, db, pb,
                                            kernels.use_interpret())
             np.testing.assert_array_equal(np.asarray(got), ref,
                                           err_msg=f"d_block={db}")
@@ -157,29 +172,33 @@ class TestWideSegsumDispatch:
     silent path change)."""
 
     def test_envelope_geometry(self):
-        # Max-P narrows BOTH axes: the [P, R] one-hot and the [P, Dt]
-        # slab each hit their 4 MB budget exactly at 128.
-        assert kernels.segsum_wide_envelope(8192, 1024) == (128, 128)
+        # A full partition block narrows BOTH axes: the [Pk, R] one-hot
+        # and the [Pk, Dt] slab each hit their 4 MB budget at 128.
+        assert kernels.segsum_wide_envelope(8192, 1024) == (128, 128,
+                                                             8192)
         # Small P affords the widest tile.
-        assert kernels.segsum_wide_envelope(64, 1024) == (512, 512)
+        assert kernels.segsum_wide_envelope(64, 1024) == (512, 512, 64)
         # No column cap — D is tiled, unlike the scalar lane kernel.
         assert kernels.segsum_wide_envelope(
             64, dispatch._SEGSUM_MAX_COLS * 128) is not None
-        # P past the one-block one-hot/accumulator cap is out.
+        # P past one block tiles the partition axis.
         assert kernels.segsum_wide_envelope(
-            dispatch._SEGSUM_MAX_P * 2, 64) is None
+            dispatch._SEGSUM_P_BLOCK * 2, 64) == (128, 128, 8192)
 
-    def test_out_of_envelope_event(self):
+    def test_partition_blocks_dispatch_pallas(self, monkeypatch):
+        """P past one partition block stays a pallas dispatch (no
+        fallback event) and matches XLA bit for bit."""
+        monkeypatch.setattr(dispatch, "_SEGSUM_P_BLOCK", 64)
         rng = np.random.default_rng(1)
-        n, P = 200, dispatch._SEGSUM_MAX_P * 2
+        n, P = 500, 150
         pk = jnp.asarray(rng.integers(0, P, n).astype(np.int32))
         cols = jnp.asarray(
-            rng.integers(0, 4096, (n, 8)).astype(np.int32))
+            rng.integers(0, 4096, (n, 200)).astype(np.int32))
         obs.reset()
-        assert dispatch.try_segment_sum_wide(cols, pk, P,
-                                             "pallas") is None
-        events = _fallback_events("out_of_envelope")
-        assert events and events[0]["site"] == "segment_sum_wide"
+        got = dispatch.try_segment_sum_wide(cols, pk, P, "pallas")
+        assert got is not None and not _fallback_events()
+        ref = jax.ops.segment_sum(cols, pk, num_segments=P)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
     def test_xla_request_short_circuits(self):
         pk = jnp.zeros(4, jnp.int32)
@@ -280,15 +299,47 @@ class TestHistKernelParity:
             rng.integers(0, 48, (T, Pb, Qc)).astype(np.int32))
         p_offsets = jnp.asarray(
             (np.arange(T) * Pb).astype(np.int32))
-        rb = kernels.hist_envelope(T, Pb, Qc, span)
-        assert rb is not None
+        env = kernels.hist_envelope(T, Pb, Qc, span)
+        assert env is not None
         got = kernels.hist_bin_multi(qpk, leaf, kept, sub_starts,
-                                     p_offsets, Pb, span, rb,
+                                     p_offsets, Pb, span, *env,
                                      kernels.use_interpret())
         ref = je._subtree_counts_multi(qpk, leaf, kept, sub_starts,
                                        p_offsets, Pb, span)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
         # Dense shape: the parity must not be vacuous.
+        assert int(np.asarray(ref).sum()) > 100
+
+    @pytest.mark.parametrize("T,Pb,Qc", [(1, 300, 3), (2, 256, 2)])
+    def test_partition_blocks_parity(self, monkeypatch, T, Pb, Qc):
+        """A tile wider than one VMEM-resident partition block walks
+        blocks of 128 (the last one ragged when Pb is not a multiple),
+        with walk starts up to the 2^16 leaf range: bit-identical."""
+        span = 16
+        monkeypatch.setattr(dispatch, "_OUT_BYTES_CAP",
+                            128 * Qc * span * 4)
+        rng = np.random.default_rng(T * Pb)
+        n = 4000
+        qpk = jnp.asarray(rng.integers(0, T * Pb, n).astype(np.int32))
+        leaf = jnp.asarray(
+            rng.integers(0, 1 << 16, n).astype(np.int32))
+        kept = jnp.asarray(rng.random(n) < 0.9)
+        # Walk starts just below each row's leaf, so most rows land in
+        # their partition's subtree.
+        starts = np.zeros((T, Pb, Qc), np.int64)
+        lf, pk = np.asarray(leaf), np.asarray(qpk)
+        for q in range(Qc):
+            starts[pk // Pb, pk % Pb, q] = np.maximum(lf - 3 * q, 0)
+        sub_starts = jnp.asarray(starts.astype(np.int32))
+        p_offsets = jnp.asarray((np.arange(T) * Pb).astype(np.int32))
+        rb, pb = kernels.hist_envelope(T, Pb, Qc, span)
+        assert pb == 128 < Pb
+        got = kernels.hist_bin_multi(qpk, leaf, kept, sub_starts,
+                                     p_offsets, Pb, span, rb, pb,
+                                     kernels.use_interpret())
+        ref = je._subtree_counts_multi(qpk, leaf, kept, sub_starts,
+                                       p_offsets, Pb, span)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
         assert int(np.asarray(ref).sum()) > 100
 
 
@@ -337,8 +388,9 @@ class TestEnvelopeFallback:
     silent path change."""
 
     def test_segsum_out_of_envelope(self):
-        assert kernels.segsum_envelope(dispatch._SEGSUM_MAX_P * 2,
-                                       4) is None
+        # Past one partition block the grid tiles P: still in envelope.
+        assert kernels.segsum_envelope(dispatch._SEGSUM_P_BLOCK * 2,
+                                       4) == (128, 8192)
         assert kernels.segsum_envelope(
             64, dispatch._SEGSUM_MAX_COLS + 1) is None
         obs.reset()
@@ -348,17 +400,16 @@ class TestEnvelopeFallback:
         assert events and events[0]["site"] == "segment_sum_lanes"
 
     def test_hist_out_of_envelope_falls_back_bit_identical(self):
-        """An over-VMEM [T, Pb, Qc, span] request through the REAL
+        """More quantiles than the kernel unrolls, through the REAL
         dispatch seam: XLA result, fallback event."""
-        span = 256
-        Pb = (dispatch._OUT_BYTES_CAP // (span * 4)) * 2  # 2x the cap
-        assert kernels.hist_envelope(1, Pb, 1, span) is None
+        span, Pb, Qc = 16, 8, dispatch._HIST_UNROLL_CAP + 1
+        assert kernels.hist_envelope(1, Pb, Qc, span) is None
         rng = np.random.default_rng(3)
         n = 1000
         qpk = jnp.asarray(rng.integers(0, Pb, n).astype(np.int32))
-        leaf = jnp.asarray(rng.integers(0, 512, n).astype(np.int32))
+        leaf = jnp.asarray(rng.integers(0, 32, n).astype(np.int32))
         kept = jnp.ones(n, bool)
-        sub_starts = jnp.zeros((1, Pb, 1), jnp.int32)
+        sub_starts = jnp.zeros((1, Pb, Qc), jnp.int32)
         p_offsets = jnp.zeros(1, jnp.int32)
         obs.reset()
         got = je._subtree_counts_multi(qpk, leaf, kept, sub_starts,

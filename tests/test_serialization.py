@@ -1,4 +1,4 @@
-"""Real-serialization proof for the cluster adapters (VERDICT r2 #7).
+"""Real-serialization proof for the cluster adapters.
 
 Beam/Spark runners pickle the engine's closures to ship them to workers
 (reference ``private_beam``/Beam ``CombinePerKey``; SURVEY.md §3.3). The

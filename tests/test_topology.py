@@ -181,7 +181,7 @@ def _run_collective(mesh, x_global, body):
     fn = psh.shard_map(
         lambda v: body(v[0], axis, topo),
         mesh=mesh, in_specs=psh.PSpec(axis), out_specs=psh.PSpec(axis),
-        **{psh._CHECK_KW: False})
+        check_vma=False)
     return np.asarray(jax.jit(fn)(x_global))
 
 
@@ -191,7 +191,7 @@ def _run_replicated(mesh, x_global, body):
     fn = psh.shard_map(
         lambda v: body(v[0], axis, topo),
         mesh=mesh, in_specs=psh.PSpec(axis), out_specs=psh.PSpec(),
-        **{psh._CHECK_KW: False})
+        check_vma=False)
     return np.asarray(jax.jit(fn)(x_global))
 
 
