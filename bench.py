@@ -2093,242 +2093,6 @@ def run_autotune(args):
     return 0
 
 
-def roofline_probe(ds):
-    """Roofline numbers for the fused kernel's dominant device ops on this
-    chip: the 3-key lexsort and one per-pk segment_sum, reported as
-    achieved bytes/s against the v5e HBM peak (~810 GB/s). The sort's
-    traffic model is a bitonic network: ~log2(n)(log2(n)+1)/2 stages,
-    each reading+writing 4 operands (3 sort keys + the index payload) of
-    4 bytes."""
-    import math
-
-    import jax
-    import jax.numpy as jnp
-
-    import pipelinedp_tpu as pdp
-    from pipelinedp_tpu import jax_engine
-    from pipelinedp_tpu.ops import segment as seg_ops
-
-    enc = jax_engine.encode(ds, pdp.DataExtractors(), None)
-    pid, pk, _, _ = jax_engine.pad_and_put(enc, None, with_values=False)
-    n = int(pid.shape[0])
-    key = jax.random.PRNGKey(0)
-
-    @jax.jit
-    def sort_only(pid, pk, key):
-        k_tie, k_salt = jax.random.split(key)
-        salt = jax.random.bits(k_salt, (), dtype=jnp.uint32)
-        tie = jax.random.bits(k_tie, (n,), dtype=jnp.uint32)
-        hpk = seg_ops.fmix32(
-            seg_ops.fmix32(pid.astype(jnp.uint32) ^ salt) ^
-            pk.astype(jnp.uint32))
-        return jnp.lexsort((tie, hpk, pid))[0]
-
-    @jax.jit
-    def segsum_only(pk):
-        return jax.ops.segment_sum(jnp.ones_like(pk), pk,
-                                   num_segments=65536)[0]
-
-    def timed(fn, *args, label="op"):
-        best = 1e9
-        for _ in range(3):
-            # np.asarray forces execution and the copy back to host.
-            with tracer().span(f"roofline.{label}",
-                               cat="roofline") as sp:
-                np.asarray(fn(*args))
-            best = min(best, sp.duration)
-        return best
-
-    # Quantile-walk pieces at bench shape: the per-quantile relevance
-    # flags + prefix-sum compaction (the r5 sub-histogram path: one
-    # packed-block gather + byte compares per 4 quantiles, a cumsum of
-    # the flags and two monotone int32 scatters into the n/8 prefix —
-    # replacing the former stable argsort's bitonic network) and one
-    # [P, 256] top-histogram scatter. Traffic models: flags read
-    # qpk+leaf+1 gather word and write 1 byte (~13 B/row); cumsum +
-    # dest + 2 scatters are ~4 more int32 passes (~16 B/row); the
-    # top-hist scatter reads key+payload and read-modify-writes its
-    # output (~16 B/row).
-    P_walk = 1 << 17
-    Q = 3
-    blk = jax.random.randint(jax.random.fold_in(key, 1), (P_walk, Q), 0,
-                             255, jnp.int32)
-    leaf = jax.random.randint(jax.random.fold_in(key, 2), (n,), 0, 65536,
-                              jnp.int32)
-    qpk = jnp.abs(pk) % P_walk
-
-    @jax.jit
-    def walk_flags_and_sort(qpk, leaf, blk):
-        packed = blk[:, 0] | (blk[:, 1] << 8) | (blk[:, 2] << 16)
-        pr = packed[qpk]
-        mid = leaf >> 8
-        rel_any = ((mid == (pr & 0xFF)) | (mid == ((pr >> 8) & 0xFF)) |
-                   (mid == ((pr >> 16) & 0xFF)))
-        cap = max(8192, n // 8)
-        dest = jnp.where(rel_any,
-                         jnp.cumsum(rel_any.astype(jnp.int32)) - 1, cap)
-        qpk_c = jnp.zeros(cap, jnp.int32).at[dest].set(qpk, mode="drop")
-        row_c = jnp.zeros(cap, jnp.int32).at[dest].set(leaf, mode="drop")
-        return qpk_c[0] + row_c[0]
-
-    @jax.jit
-    def top_hist(qpk, leaf):
-        return jax.ops.segment_sum(
-            jnp.ones_like(qpk), qpk * 256 + (leaf >> 8),
-            num_segments=P_walk * 256)[0]
-
-    sort_only(pid, pk, key)
-    segsum_only(pk)
-    walk_flags_and_sort(qpk, leaf, blk)
-    top_hist(qpk, leaf)
-    sort_s = timed(sort_only, pid, pk, key, label="sort")
-    seg_s = timed(segsum_only, pk, label="segment_sum")
-    walk_s = timed(walk_flags_and_sort, qpk, leaf, blk,
-                   label="walk_flags")
-    hist_s = timed(top_hist, qpk, leaf, label="top_hist")
-    stages = math.log2(n) * (math.log2(n) + 1) / 2
-    sort_bytes = stages * n * 16 * 2
-    hbm_peak = 810e9
-    walk_bytes = n * (13 + 16)  # flags + cumsum/dest/2-scatter passes
-    hist_bytes = n * 16
-    rec = {
-        "metric": "roofline",
-        "rows": n,
-        "sort_s": round(sort_s, 4),
-        "sort_model_gb": round(sort_bytes / 1e9, 1),
-        "sort_gb_per_s": round(sort_bytes / sort_s / 1e9, 1),
-        "sort_hbm_frac": round(sort_bytes / sort_s / hbm_peak, 3),
-        "segment_sum_s": round(seg_s, 4),
-        "segment_sum_gb_per_s": round(n * 8 * 2 / seg_s / 1e9, 1),
-        "walk_flag_sort_s": round(walk_s, 4),
-        "walk_flag_sort_gb_per_s": round(walk_bytes / walk_s / 1e9, 1),
-        "walk_flag_sort_hbm_frac": round(
-            walk_bytes / walk_s / hbm_peak, 3),
-        "walk_hist_scatter_s": round(hist_s, 4),
-        "walk_hist_scatter_gb_per_s": round(
-            hist_bytes / hist_s / 1e9, 1),
-        "walk_hist_scatter_hbm_frac": round(
-            hist_bytes / hist_s / hbm_peak, 3),
-    }
-    log(f"## roofline: sort {sort_s:.3f}s ({rec['sort_gb_per_s']} GB/s, "
-        f"{rec['sort_hbm_frac']:.0%} of HBM peak), segment_sum "
-        f"{seg_s:.3f}s, walk flags+compaction {walk_s:.3f}s "
-        f"({rec['walk_flag_sort_hbm_frac']:.0%} of peak), walk top-hist "
-        f"scatter {hist_s:.3f}s "
-        f"({rec['walk_hist_scatter_hbm_frac']:.0%} of peak)")
-    emit(rec)
-    return rec
-
-
-def walk_breakdown_probe(n_partitions, n_rows, n_quantiles=3):
-    """Per-phase breakdown of the quantile walk at the config-4 shape,
-    mirroring the ingest record's ``t_stage/t_fold/t_device/t_total``
-    split: ``t_noise`` (the per-level node-noise generation alone — the
-    counter-based threefry draws, 4 levels with the root deduped),
-    ``t_hist`` (the [P, 256] top-histogram row scatter — the walk's one
-    unconditional full-row scatter; the data-dependent compacted
-    subtree build lands in the residual), ``t_walk`` (the residual,
-    t_total minus the other two, floored at 0) and
-    ``t_total`` (the full ``_percentile_values`` wall clock). Driver-
-    measurable: re-deriving the node-noise speedup claim needs exactly
-    one clean run of this record before and after a change."""
-    import jax
-    import jax.numpy as jnp
-
-    from pipelinedp_tpu import jax_engine as je
-    from pipelinedp_tpu.aggregate_params import NoiseKind
-    from pipelinedp_tpu.ops import quantile_tree as qt
-
-    P = je._pad_pow2(n_partitions)
-    n = je._pad_rows(n_rows)
-    b = qt.DEFAULT_BRANCHING_FACTOR
-    height = qt.DEFAULT_TREE_HEIGHT
-    n_leaves = b**height
-    Q = n_quantiles
-    percentiles = tuple(float(p) for p in
-                        np.linspace(50, 99, Q).round(0))
-    config = je.FusedConfig(
-        metrics=("PERCENTILE",), percentiles=percentiles,
-        noise_kind=NoiseKind.LAPLACE, linf=2, l0=4,
-        per_partition_bounds=False, min_value=0.0, max_value=10.0,
-        min_sum_per_partition=None, max_sum_per_partition=None,
-        vector_size=None, vector_norm_kind=None, vector_max_norm=None,
-        selection=None, bounds_already_enforced=False)
-    key = jax.random.PRNGKey(0)
-    qpk = jax.random.randint(jax.random.fold_in(key, 1), (n,), 0,
-                             n_partitions, jnp.int32)
-    leaf = jax.random.randint(jax.random.fold_in(key, 2), (n,), 0,
-                              n_leaves, jnp.int32)
-    kept = jnp.ones(n, bool)
-    scale = jnp.float32(2.0)
-    bucket_w = b**(height - 2)  # the top histogram's bucket width
-
-    @jax.jit
-    def noise_only(key):
-        # The walk's exact per-level draw structure: root deduped to
-        # [P, 1, b] and broadcast, three [P, Q, b] levels below.
-        tot = jnp.float32(0)
-        base = jnp.zeros((P, Q), jnp.int32)
-        level_offset = 0
-        for level in range(height):
-            node_ids = (level_offset + base)[..., None] + jnp.arange(
-                b, dtype=jnp.int32)
-            ids = node_ids[:, :1, :] if level_offset == 0 else node_ids
-            tot += je._node_noise(config.noise_kind, key, ids).sum()
-            level_offset += b**(level + 1)
-        return tot
-
-    @jax.jit
-    def hist_only(qpk, leaf, kept):
-        # The [P, b^2] top-histogram scatter — the walk's one
-        # unconditional full-row scatter (the bottom-level sub-histogram
-        # build is data-dependent: prefix-sum compaction makes its cost
-        # a function of subtree concentration, so it lands in the
-        # t_walk residual rather than being modeled separately).
-        n_mid = b * b
-        hist = jax.ops.segment_sum(
-            kept.astype(jnp.int32),
-            qpk * n_mid + jnp.minimum(leaf // bucket_w, n_mid - 1),
-            num_segments=P * n_mid)
-        return hist[0]
-
-    @jax.jit
-    def walk_full(qpk, leaf, kept, scale, key):
-        return je._percentile_values(config, P, (qpk, leaf, kept),
-                                     scale, key)[0, 0]
-
-    def timed(fn, *args, label="phase"):
-        np.asarray(fn(*args))  # compile warm-up
-        best = 1e9
-        for _ in range(3):
-            # The acceptance spans for the three walk phases: each
-            # repetition is one "walk.<phase>" span in the ledger.
-            with tracer().span(f"walk.{label}", cat="walk") as sp:
-                np.asarray(fn(*args))
-            best = min(best, sp.duration)
-        return best
-
-    t_noise = timed(noise_only, key, label="noise")
-    t_hist = timed(hist_only, qpk, leaf, kept, label="hist")
-    t_total = timed(walk_full, qpk, leaf, kept, scale, key,
-                    label="walk")
-    rec = {
-        "metric": "quantile_walk_breakdown",
-        "partitions": P,
-        "rows": n,
-        "quantiles": Q,
-        "t_noise": round(t_noise, 4),
-        "t_hist": round(t_hist, 4),
-        "t_walk": round(max(0.0, t_total - t_noise - t_hist), 4),
-        "t_total": round(t_total, 4),
-    }
-    log(f"## quantile walk breakdown [{P} parts, {n} rows, {Q} q]: "
-        f"noise {t_noise:.3f}s + hist {t_hist:.3f}s + walk "
-        f"{rec['t_walk']:.3f}s (total {t_total:.3f}s)")
-    emit(rec)
-    return rec
-
-
 def record_run_report(snapshot=None):
     """Build this run's schema-v2 run report (env fingerprint + spans +
     counters/events + the privacy audit section) and append it to the
@@ -2799,7 +2563,6 @@ def main():
     ds_60k = zipf_dataset(n_rows, n_users, 2_000 if args.smoke else 60_000)
     flagship = bench_config("dp_count_sum_mean_rows_per_sec",
                             flagship_params(), ds_60k, local_rows)
-    roofline_probe(ds_60k)
 
     if not args.flagship_only:
         # Config 1: COUNT over ~1k partitions.
@@ -2851,12 +2614,6 @@ def main():
                 max_contributions_per_partition=2,
                 min_value=0.0, max_value=10.0),
             ds_q, min(local_rows, 50_000), repeats=3)  # 10M rows: 3 is enough
-
-        # Per-phase walk breakdown at (at least) a 2^16-partition
-        # synthetic — the driver-measurable evidence for walk-phase
-        # claims (t_noise / t_hist / t_walk / t_total).
-        walk_breakdown_probe(max(1 << 16, q_parts),
-                             min(q_rows, 4_000_000))
 
         # Streamed two-pass percentiles + the pass-B multi-tile sweep
         # record (shrunken cap seam, so CPU runs witness the

@@ -58,7 +58,7 @@ from pipelinedp_tpu.aggregate_params import (AggregateParams, NoiseKind,
                                              NormKind,
                                              PartitionSelectionStrategy)
 from pipelinedp_tpu.combiners import _create_named_tuple_instance
-from pipelinedp_tpu.obs.costs import instrumented_jit
+from pipelinedp_tpu.obs.costs import instrumented_jit, scope, scoped
 from pipelinedp_tpu.ops import partition_selection as ps_ops
 from pipelinedp_tpu.ops import quantile_tree as quantile_tree_ops
 from pipelinedp_tpu.ops import segment as seg_ops
@@ -772,15 +772,17 @@ def _partials(config: FusedConfig, num_partitions: int, pid, pk, values,
     if config.bounds_already_enforced:
         # No privacy ids: every row is its own "segment"; no sampling.
         row_keep = valid
-        pk_safe = jnp.where(valid, pk, 0)
-        clipped = _clip_values(config, values)
-        masked = jnp.where(_expand(row_keep, clipped), clipped, 0.0)
-        if config.per_partition_bounds:
-            # One row = one segment: the per-segment sum clip is a row clip.
-            masked = jnp.where(
-                row_keep,
-                jnp.clip(masked, config.min_sum_per_partition,
-                         config.max_sum_per_partition), 0.0)
+        with scope("bound_caps"):
+            pk_safe = jnp.where(valid, pk, 0)
+            clipped = _clip_values(config, values)
+            masked = jnp.where(_expand(row_keep, clipped), clipped, 0.0)
+            if config.per_partition_bounds:
+                # One row = one segment: the per-segment sum clip is a
+                # row clip.
+                masked = jnp.where(
+                    row_keep,
+                    jnp.clip(masked, config.min_sum_per_partition,
+                             config.max_sum_per_partition), 0.0)
         qrows = (_qrows(config, pk_safe, values, row_keep)
                  if config.percentiles else None)
         part, _ = _reduce_per_pk(config, pk_safe, masked, row_keep, masked,
@@ -803,85 +805,98 @@ def _partials(config: FusedConfig, num_partitions: int, pid, pk, values,
     # pure function of (key, real rows): padding the same request to a
     # larger pow2 fusion bucket is bit-identical to its solo padding
     # (PARITY row 35, asserted in tests/test_fusion.py).
-    # lint: disable=rng-purity(bounding tie-break bits, keyed by k_bound)
-    k_tie, k_salt, k_m = jax.random.split(key, 3)
-    # lint: disable=rng-purity(per-run salt from the bounding stream)
-    salt = jax.random.bits(k_salt, (), dtype=jnp.uint32)
-    tiebreak = counter_rng.row_bits(k_tie, n)
-    big_pid = jnp.where(valid, pid, seg_ops.PAD_ID)
-    big_pk = jnp.where(valid, pk, seg_ops.PAD_ID)
-    # Sampling priority of segment (pid, pk): an independent uniform
-    # permutation of each pid's partitions (salted per run).
-    hpk = seg_ops.fmix32(
-        seg_ops.fmix32(big_pid.astype(jnp.uint32) ^ salt) ^
-        big_pk.astype(jnp.uint32))
-    # For fixed (pid, salt), pk -> hpk is injective (fmix32 is a bijection
-    # on uint32 composed with an xor by a per-pid constant), so (pid, hpk)
-    # already identifies the (pid, pk) segment — pk itself is redundant as
-    # a sort key, cutting one operand from the sort network.
-    sort_idx = jnp.lexsort((tiebreak, hpk, big_pid))
-    spid = big_pid[sort_idx]
-    spk = big_pk[sort_idx]
-    # COUNT-style metrics never read the value column: skip the gather of
-    # the (all-zero) values array entirely.
-    svalues = values[sort_idx] if config.needs_values else values
-    idx = jnp.arange(n)
-    # Valid rows sort before padding (PAD_ID keys): no gather needed.
-    svalid = idx < jnp.sum(valid.astype(jnp.int32))
+    with scope("bound_sort"):
+        # lint: disable=rng-purity(bounding tie-break bits, keyed by k_bound)
+        k_tie, k_salt, k_m = jax.random.split(key, 3)
+        # lint: disable=rng-purity(per-run salt from the bounding stream)
+        salt = jax.random.bits(k_salt, (), dtype=jnp.uint32)
+        tiebreak = counter_rng.row_bits(k_tie, n)
+        big_pid = jnp.where(valid, pid, seg_ops.PAD_ID)
+        big_pk = jnp.where(valid, pk, seg_ops.PAD_ID)
+        # Sampling priority of segment (pid, pk): an independent uniform
+        # permutation of each pid's partitions (salted per run).
+        hpk = seg_ops.fmix32(
+            seg_ops.fmix32(big_pid.astype(jnp.uint32) ^ salt) ^
+            big_pk.astype(jnp.uint32))
+        # For fixed (pid, salt), pk -> hpk is injective (fmix32 is a
+        # bijection on uint32 composed with an xor by a per-pid
+        # constant), so (pid, hpk) already identifies the (pid, pk)
+        # segment — pk itself is redundant as a sort key, cutting one
+        # operand from the sort network.
+        sort_idx = jnp.lexsort((tiebreak, hpk, big_pid))
+    with scope("bound_permute"):
+        spid = big_pid[sort_idx]
+        spk = big_pk[sort_idx]
+        # COUNT-style metrics never read the value column: skip the
+        # gather of the (all-zero) values array entirely.
+        svalues = values[sort_idx] if config.needs_values else values
+    with scope("bound_caps"):
+        idx = jnp.arange(n)
+        # Valid rows sort before padding (PAD_ID keys): no gather needed.
+        svalid = idx < jnp.sum(valid.astype(jnp.int32))
 
-    new_pid = (idx == 0) | (spid != jnp.roll(spid, 1))
-    new_seg = new_pid | (spk != jnp.roll(spk, 1))
-    if config.max_contributions is not None:
-        # Total-cap mode: a uniform without-replacement sample of M rows
-        # per privacy unit, across all its partitions (the fused twin of
-        # SamplingPerPrivacyIdContributionBounder). The sample must be
-        # uniform over the unit's ROWS, not follow the hpk segment order,
-        # so rank rows by an independent random key in a second sort and
-        # carry the keep bits back through the permutations.
-        tie_m = counter_rng.row_bits(k_m, n)
-        order_m = jnp.lexsort((tie_m, big_pid))
-        mpid = big_pid[order_m]
-        new_pid_m = (idx == 0) | (mpid != jnp.roll(mpid, 1))
-        keep_sorted = seg_ops.rank_in_run(new_pid_m) < config.max_contributions
-        keep_m = jnp.zeros(n, bool).at[order_m].set(keep_sorted)
-        keep_row = svalid & keep_m[sort_idx]
-        # First KEPT row of each segment marks the (pid, pk) pair as
-        # contributing; fully-sampled-away segments must not count
-        # toward the privacy-id count or selection.
-        wk = jnp.cumsum(keep_row.astype(jnp.int32))
-        seg_start = seg_ops.run_starts(new_seg)
-        kept_before_seg = wk[seg_start] - keep_row[seg_start]
-        seg_marker = keep_row & (wk == kept_before_seg + 1)
-    else:
-        # Linf bound: keep the first linf (randomly ordered) rows per
-        # segment.
-        linf_cap = config.linf if config.linf is not None else n
-        row_keep = svalid & (seg_ops.rank_in_run(new_seg) < linf_cap)
-        # L0 bound: the segment's ordinal within its pid — uniform by the
-        # hpk sort key — must be < l0.
-        keep_l0 = seg_ops.run_ordinal_in_group(new_seg,
-                                               new_pid) < config.l0
-        keep_row = row_keep & keep_l0
-        # Kept-segment indicator on the segment's first row: the per-pk
-        # sum of these is the privacy-id count (row_count in the
-        # reference's compound accumulator, dp_engine.py:339).
-        seg_marker = new_seg & svalid & keep_l0
+        new_pid = (idx == 0) | (spid != jnp.roll(spid, 1))
+        new_seg = new_pid | (spk != jnp.roll(spk, 1))
+        if config.max_contributions is not None:
+            # Total-cap mode: a uniform without-replacement sample of M
+            # rows per privacy unit, across all its partitions (the fused
+            # twin of SamplingPerPrivacyIdContributionBounder). The
+            # sample must be uniform over the unit's ROWS, not follow the
+            # hpk segment order, so rank rows by an independent random
+            # key in a second sort and carry the keep bits back through
+            # the permutations.
+            with scope("bound_sort"):
+                tie_m = counter_rng.row_bits(k_m, n)
+                order_m = jnp.lexsort((tie_m, big_pid))
+            with scope("bound_permute"):
+                mpid = big_pid[order_m]
+            new_pid_m = (idx == 0) | (mpid != jnp.roll(mpid, 1))
+            keep_sorted = (seg_ops.rank_in_run(new_pid_m) <
+                           config.max_contributions)
+            with scope("bound_permute"):
+                keep_m = jnp.zeros(n, bool).at[order_m].set(keep_sorted)
+                keep_row = svalid & keep_m[sort_idx]
+            # First KEPT row of each segment marks the (pid, pk) pair as
+            # contributing; fully-sampled-away segments must not count
+            # toward the privacy-id count or selection.
+            wk = jnp.cumsum(keep_row.astype(jnp.int32))
+            seg_start = seg_ops.run_starts(new_seg)
+            kept_before_seg = wk[seg_start] - keep_row[seg_start]
+            seg_marker = keep_row & (wk == kept_before_seg + 1)
+        else:
+            # Linf bound: keep the first linf (randomly ordered) rows per
+            # segment.
+            linf_cap = config.linf if config.linf is not None else n
+            row_keep = svalid & (seg_ops.rank_in_run(new_seg) < linf_cap)
+            # L0 bound: the segment's ordinal within its pid — uniform by
+            # the hpk sort key — must be < l0.
+            keep_l0 = seg_ops.run_ordinal_in_group(new_seg,
+                                                   new_pid) < config.l0
+            keep_row = row_keep & keep_l0
+            # Kept-segment indicator on the segment's first row: the
+            # per-pk sum of these is the privacy-id count (row_count in
+            # the reference's compound accumulator, dp_engine.py:339).
+            seg_marker = new_seg & svalid & keep_l0
 
-    clipped = _clip_values(config, svalues)
-    masked = jnp.where(_expand(keep_row, clipped), clipped, 0.0)
-    pk_safe = jnp.where(svalid, spk, 0)
+        clipped = _clip_values(config, svalues)
+        masked = jnp.where(_expand(keep_row, clipped), clipped, 0.0)
+        pk_safe = jnp.where(svalid, spk, 0)
+
+        if config.per_partition_bounds:
+            # Clip each (pid, pk) segment's SUM, contributed once per
+            # segment. seg_ord is monotone, so this segment_sum is the
+            # one per-segment scatter this mode still needs;
+            # precision-safe (no cumsum diff).
+            seg_ord = jnp.cumsum(new_seg.astype(jnp.int32)) - 1
+            seg_total = jax.ops.segment_sum(masked, seg_ord,
+                                            num_segments=n)
+            tot_row = seg_total[seg_ord]
+            contrib = jnp.where(
+                seg_marker,
+                jnp.clip(tot_row, config.min_sum_per_partition,
+                         config.max_sum_per_partition), 0.0)
 
     if config.per_partition_bounds:
-        # Clip each (pid, pk) segment's SUM, contributed once per segment.
-        # seg_ord is monotone, so this segment_sum is the one per-segment
-        # scatter this mode still needs; precision-safe (no cumsum diff).
-        seg_ord = jnp.cumsum(new_seg.astype(jnp.int32)) - 1
-        seg_total = jax.ops.segment_sum(masked, seg_ord, num_segments=n)
-        tot_row = seg_total[seg_ord]
-        contrib = jnp.where(
-            seg_marker,
-            jnp.clip(tot_row, config.min_sum_per_partition,
-                     config.max_sum_per_partition), 0.0)
         part, part_nseg = _reduce_per_pk(config, pk_safe, masked, keep_row,
                                          contrib, P, seg_marker=seg_marker,
                                          fx_bits=fx_bits,
@@ -989,6 +1004,7 @@ def _vector_fx_scale(config: FusedConfig) -> float:
     return (_FX_STEPS - 1) / bound if bound > 0 else 1.0
 
 
+@scoped("segsum")
 def _reduce_per_pk(config: FusedConfig, pk_safe, masked, keep_row,
                    per_partition_sum_contrib, P, seg_marker=None,
                    fx_bits: int = 7, kernel_backend: str = "xla"):
@@ -1267,10 +1283,11 @@ def _selection_and_metrics(config: FusedConfig, num_partitions: int, part,
 
     def owned(draw_fn):
         """Draws a [P_total] random vector, returns this device's block."""
-        full = draw_fn((P_total,))
-        if offset is None:
-            return full
-        return jax.lax.dynamic_slice(full, (offset,), (P,))
+        with scope("noise"):
+            full = draw_fn((P_total,))
+            if offset is None:
+                return full
+            return jax.lax.dynamic_slice(full, (offset,), (P,))
 
     # --- partition selection (batched over all partitions) ---
     if config.selection is None:
@@ -1278,33 +1295,34 @@ def _selection_and_metrics(config: FusedConfig, num_partitions: int, part,
         # (The public-partition empty-accumulator sum adjustment happens
         # in the float64 host release, _host_release.)
     else:
-        # Without privacy ids one row is not one user; the conservative
-        # user-count estimate is ceil(rows / max_rows_per_privacy_id)
-        # (reference dp_engine.py:341-348).
-        est_users = jnp.ceil(part_nseg.astype(jnp.float32) /
-                             sel_rows_per_uid)
-        counts = est_users.astype(jnp.int32)
-        if config.selection == (
-                PartitionSelectionStrategy.TRUNCATED_GEOMETRIC):
-            idx = jnp.clip(counts, 0, keep_table.shape[0] - 1)
-            p_keep = keep_table[idx]
-            # Selection draws route through the blessed noise module
-            # (unit scale here; sel_scale applies outside the draw).
-            keep_pk = owned(
-                lambda s: noise_ops.jax_uniform(k_sel, s)) < p_keep
-        else:
+        with scope("select"):
+            # Without privacy ids one row is not one user; the conservative
+            # user-count estimate is ceil(rows / max_rows_per_privacy_id)
+            # (reference dp_engine.py:341-348).
+            est_users = jnp.ceil(part_nseg.astype(jnp.float32) /
+                                 sel_rows_per_uid)
+            counts = est_users.astype(jnp.int32)
             if config.selection == (
-                    PartitionSelectionStrategy.LAPLACE_THRESHOLDING):
-                noise_sel = owned(
-                    lambda s: noise_ops.jax_laplace(k_sel, s, 1.0)
-                ) * sel_scale
+                    PartitionSelectionStrategy.TRUNCATED_GEOMETRIC):
+                idx = jnp.clip(counts, 0, keep_table.shape[0] - 1)
+                p_keep = keep_table[idx]
+                # Selection draws route through the blessed noise module
+                # (unit scale here; sel_scale applies outside the draw).
+                keep_pk = owned(
+                    lambda s: noise_ops.jax_uniform(k_sel, s)) < p_keep
             else:
-                noise_sel = owned(
-                    lambda s: noise_ops.jax_gaussian(k_sel, s, 1.0)
-                ) * sel_scale
-            keep_pk = ((est_users + noise_sel) >= sel_threshold) & (
-                est_users >= sel_min_count)  # pre-threshold hard floor
-        keep_pk = keep_pk & (part_nseg > 0)
+                if config.selection == (
+                        PartitionSelectionStrategy.LAPLACE_THRESHOLDING):
+                    noise_sel = owned(
+                        lambda s: noise_ops.jax_laplace(k_sel, s, 1.0)
+                    ) * sel_scale
+                else:
+                    noise_sel = owned(
+                        lambda s: noise_ops.jax_gaussian(k_sel, s, 1.0)
+                    ) * sel_scale
+                keep_pk = ((est_users + noise_sel) >= sel_threshold) & (
+                    est_users >= sel_min_count)  # pre-threshold hard floor
+            keep_pk = keep_pk & (part_nseg > 0)
 
     # --- accumulator partials out; the scalar release happens on HOST in
     # float64 (see LazyFusedResult._host_release): float32 noise on a
@@ -1345,6 +1363,7 @@ def _percentile_field_names(percentiles) -> List[str]:
     return names
 
 
+@scoped("noise")
 def _node_noise(noise_kind: NoiseKind, key, node_ids, pk_index=None):
     """One noise draw per (partition, tree node), as a pure function of
     the indices: every quantile walk that visits a node sees the same
@@ -2154,6 +2173,7 @@ _COMPACT_FETCH_CAP = 8192
 
 @instrumented_jit(phase="fetch", static_argnames=("num_partitions",
                                                   "cap"))
+@scoped("compact")
 def _compact_fetch_kernel(keep_pk, cols, num_partitions, cap):
     """Device-side output compaction: stable-sorts kept partitions first
     (ascending pk index), gathers the first ``cap`` of every column and
@@ -2522,69 +2542,77 @@ class LazyFusedResult:
         with tr.span("engine.device", cat="engine", path="single_batch"):
             keep_pk, raw, fx_bits = _run_fused_kernel(
                 config, encoded, scales, keep_table, thr, s_scale,
-                min_count, rows_per_uid, self._rng_seed, self._mesh)
+                min_count, rows_per_uid, self._rng_seed, self._mesh,
+                tracer=tr)
 
-            # Fetching the outputs forces device execution; the fetch
-            # is attributed to device_s, the float64 release + row
-            # assembly to decode_s. All rank-1 outputs ride ONE stacked
-            # transfer: one device-to-host round trip, not one per
-            # column. The stack is int32 with float
-            # columns BITCAST into it: integer lanes move bit-exactly,
-            # whereas small ints bitcast to float32 become subnormals
-            # that TPUs flush to zero (and a float32 CAST would corrupt
-            # counts above 2^24).
-            flat = sorted(k for k, v in raw.items() if v.ndim == 1)
-            cols = []
-            for name in flat:
-                arr = raw[name]
-                cols.append(arr if arr.dtype == jnp.int32 else
-                            jax.lax.bitcast_convert_type(
-                                arr.astype(jnp.float32), jnp.int32))
-            # With private selection most partitions are usually
-            # dropped: compact ON DEVICE and fetch kept count + kept
-            # indices + kept columns as ONE packed block — a single
-            # transfer instead of a full [K, P] fetch plus extra round
-            # trips. Past the static cap (a huge keyspace where
-            # selection keeps >8192 keys) every column is fetched and
-            # the kept rows are taken on host. Either way the release
-            # holds ONLY the kept rows in ascending pk order, so the
-            # seeded host noise draws are the same as the streamed
-            # path's (and the serve-fusion path's) at any kept count.
-            if self._public is None:
-                cap = min(P, _COMPACT_FETCH_CAP)
-                packed = np.asarray(_compact_fetch_kernel(
-                    keep_pk, tuple(cols), P, cap))
-                n_keep = int(packed[0, 0])
-                if n_keep <= cap:
-                    stacked = packed[1:, :n_keep]
+            # From the kernel's dispatch to the blocking fetch's end: the
+            # wait for the device program, the compaction and the d2h.
+            with tr.span("engine.fetch", cat="engine"):
+                # Fetching the outputs forces device execution; the fetch
+                # is attributed to device_s, the float64 release + row
+                # assembly to decode_s. All rank-1 outputs ride ONE stacked
+                # transfer: one device-to-host round trip, not one per
+                # column. The stack is int32 with float
+                # columns BITCAST into it: integer lanes move bit-exactly,
+                # whereas small ints bitcast to float32 become subnormals
+                # that TPUs flush to zero (and a float32 CAST would corrupt
+                # counts above 2^24).
+                flat = sorted(k for k, v in raw.items() if v.ndim == 1)
+                cols = []
+                for name in flat:
+                    arr = raw[name]
+                    cols.append(arr if arr.dtype == jnp.int32 else
+                                jax.lax.bitcast_convert_type(
+                                    arr.astype(jnp.float32), jnp.int32))
+                # With private selection most partitions are usually
+                # dropped: compact ON DEVICE and fetch kept count + kept
+                # indices + kept columns as ONE packed block — a single
+                # transfer instead of a full [K, P] fetch plus extra round
+                # trips. Past the static cap (a huge keyspace where
+                # selection keeps >8192 keys) every column is fetched and
+                # the kept rows are taken on host. Either way the release
+                # holds ONLY the kept rows in ascending pk order, so the
+                # seeded host noise draws are the same as the streamed
+                # path's (and the serve-fusion path's) at any kept count.
+                if self._public is None:
+                    cap = min(P, _COMPACT_FETCH_CAP)
+                    packed = np.asarray(_compact_fetch_kernel(
+                        keep_pk, tuple(cols), P, cap))
+                    n_keep = int(packed[0, 0])
+                    if n_keep <= cap:
+                        stacked = packed[1:, :n_keep]
+                    else:
+                        full = np.asarray(jnp.stack(
+                            [keep_pk.astype(jnp.int32)] + cols))[:, :P]
+                        kept = np.flatnonzero(full[0] > 0)
+                        stacked = np.concatenate(
+                            [kept[None].astype(np.int32), full[1:, kept]])
+                    kept_idx = stacked[0]
+                    rel_sel = np.arange(len(kept_idx))
+                    vocab_idx = kept_idx
+                    kept_order = jnp.asarray(kept_idx)  # rank-2 gathers
                 else:
-                    full = np.asarray(jnp.stack(
-                        [keep_pk.astype(jnp.int32)] + cols))[:, :P]
-                    kept = np.flatnonzero(full[0] > 0)
-                    stacked = np.concatenate(
-                        [kept[None].astype(np.int32), full[1:, kept]])
-                kept_idx = stacked[0]
-                rel_sel = np.arange(len(kept_idx))
-                vocab_idx = kept_idx
-                kept_order = jnp.asarray(kept_idx)  # rank-2 gathers
-            else:
-                stacked = np.asarray(
-                    jnp.stack([keep_pk.astype(jnp.int32)] +
-                              cols))[:, :P]
-                kept_idx = np.flatnonzero(stacked[0] > 0)
-                rel_sel = vocab_idx = np.arange(P)
-                kept_order = None
-            fetched = {}
-            for i, name in enumerate(flat):
-                col = stacked[1 + i]
-                fetched[name] = (col if raw[name].dtype == jnp.int32
-                                 else col.view(np.float32))
-            for name, arr in raw.items():  # rank-2 (vector) outputs
-                if arr.ndim != 1:
-                    fetched[name] = (np.asarray(arr)[:P]
-                                     if kept_order is None
-                                     else np.asarray(arr[kept_order]))
+                    stacked = np.asarray(
+                        jnp.stack([keep_pk.astype(jnp.int32)] +
+                                  cols))[:, :P]
+                    kept_idx = np.flatnonzero(stacked[0] > 0)
+                    rel_sel = vocab_idx = np.arange(P)
+                    kept_order = None
+                fetched = {}
+                for i, name in enumerate(flat):
+                    col = stacked[1 + i]
+                    fetched[name] = (col if raw[name].dtype == jnp.int32
+                                     else col.view(np.float32))
+                for name, arr in raw.items():  # rank-2 (vector) outputs
+                    if arr.ndim != 1:
+                        fetched[name] = (np.asarray(arr)[:P]
+                                         if kept_order is None
+                                         else np.asarray(arr[kept_order]))
         self.timings["device_s"] = tr.total("engine.device")
+        self.timings["h2d_s"] = tr.total("engine.h2d")
+        self.timings["fetch_s"] = tr.total("engine.fetch")
+        if self._mesh is not None:
+            self.timings["reshard_s"] = tr.total("engine.reshard")
         if config.selection is not None:
             # The selection seam: every vocab entry is a populated
             # partition, so P is the pre-selection count and the kept
@@ -2732,10 +2760,16 @@ class LazyFusedResult:
 
 def _run_fused_kernel(config: FusedConfig, encoded: EncodedData, scales,
                       keep_table, thr, s_scale, min_count, rows_per_uid,
-                      rng_seed, mesh):
+                      rng_seed, mesh, tracer=None):
     """Shared encode→seed→dispatch scaffolding of the lazy results: one
-    place owns the kernel/sharded invocation and the seed protocol."""
+    place owns the kernel/sharded invocation and the seed protocol.
+    ``tracer`` (an ``obs`` tracer) times the transfer of the inputs,
+    until they have landed, as ``engine.h2d``, and on a mesh the host
+    re-shard before it as ``engine.reshard``."""
+    from pipelinedp_tpu import obs
     from pipelinedp_tpu.ops import noise as noise_ops
+
+    tracer = tracer if tracer is not None else obs.NOOP_TRACER
 
     P = len(encoded.pk_vocab)
     P_pad = _pad_pow2(P)
@@ -2763,7 +2797,6 @@ def _run_fused_kernel(config: FusedConfig, encoded: EncodedData, scales,
     # keys the two signatures apart for before/after verdicts).
     from pipelinedp_tpu import plan as plan_mod
     kernel_backend = str(plan_mod.knob_value("kernel_backend"))
-    from pipelinedp_tpu import obs
     if kernel_backend == "pallas" and config.percentiles:
         # The single-batch quantile walk builds its subtree counts
         # through the compacted/block-chunked ``_subtree_counts``
@@ -2784,16 +2817,21 @@ def _run_fused_kernel(config: FusedConfig, encoded: EncodedData, scales,
                 encoded.values if config.needs_values else None,
                 np.ones(encoded.n_rows, bool), scales, keep_table, thr,
                 s_scale, min_count, rows_per_uid, key, fx_bits,
-                kernel_backend=kernel_backend)
+                kernel_backend=kernel_backend, tracer=tracer)
         return keep_pk, raw, fx_bits
-    pid, pk, values, valid = pad_and_put(encoded, config.vector_size,
-                                         with_values=config.needs_values)
+    with tracer.span("engine.h2d", cat="engine"):
+        placed = pad_and_put(encoded, config.vector_size,
+                             with_values=config.needs_values)
+        scalars = (jnp.asarray(scales), jnp.asarray(keep_table),
+                   jnp.float32(thr), jnp.float32(s_scale),
+                   jnp.float32(min_count), jnp.float32(rows_per_uid))
+        # The span ends when the inputs have landed, not when the
+        # transfer is enqueued; the kernel waits for them anyway. A
+        # dataset whose columns the device cache holds reads ~0 here.
+        jax.block_until_ready((placed, scalars))
     with obs.device_annotation("pdp.fused_aggregate"):
         keep_pk, raw = fused_aggregate_kernel(
-            config, P_pad, pid, pk, values, valid, jnp.asarray(scales),
-            jnp.asarray(keep_table), jnp.float32(thr),
-            jnp.float32(s_scale), jnp.float32(min_count),
-            jnp.float32(rows_per_uid), key, fx_bits=fx_bits,
+            config, P_pad, *placed, *scalars, key, fx_bits=fx_bits,
             kernel_backend=kernel_backend)
     return keep_pk, raw, fx_bits
 

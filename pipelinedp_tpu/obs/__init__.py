@@ -26,9 +26,19 @@ This package is the one place that knowledge accumulates:
 * :func:`build_run_report` / :func:`write_chrome_trace` — exporters:
   the schema-versioned run report (merged into bench records) and the
   Perfetto-loadable Chrome-trace file.
-* :func:`device_annotation` — optional ``jax.profiler`` trace
-  annotation around device kernel dispatches, active only under
-  ``PIPELINEDP_TPU_TRACE``.
+* The profiler bridge: every measuring span, and every
+  :func:`device_annotation` around a kernel dispatch (``pdp.*``), is
+  also a ``jax.profiler.TraceAnnotation`` of the same name, so a
+  profiler trace shows ``engine.*`` and ``pdp.*`` on the host plane on
+  the device's clock. It records only inside a profiler session and
+  does not depend on ``PIPELINEDP_TPU_TRACE``, which keeps its meaning:
+  span records in the ledger and the Chrome-trace export.
+* :data:`SCOPES` / :func:`program_scopes` — the named phases of the
+  fused aggregation programs (``jax.named_scope``: bounding sort,
+  permutation, caps, segment sum, selection, noise, combine, compact)
+  and, on request, the table from each compiled program's HLO
+  instruction names to those phases, which turns a profiler trace's
+  device operations into per-phase device time.
 * :mod:`~pipelinedp_tpu.obs.audit` — the structured privacy/utility
   audit registry behind the run report's schema-v2 ``privacy`` section:
   per-mechanism eps/delta splits + noise stddevs (pushed by
@@ -64,9 +74,11 @@ from typing import Any, Dict, Optional
 
 from pipelinedp_tpu.obs import audit, costs, metrics, store, trace_context
 from pipelinedp_tpu.obs import report as _report
+from pipelinedp_tpu.obs.costs import SCOPES, program_scopes
 from pipelinedp_tpu.obs.tracer import (ACTIVITY, ENV_VAR, MAX_EVENTS,
                                        MAX_SPANS, NOOP_SPAN, NOOP_TRACER,
                                        NoopTracer, RunLedger, Span, Tracer,
+                                       profiler_annotation,
                                        trace_destination, trace_enabled)
 from pipelinedp_tpu.obs.report import SCHEMA_VERSION, environment_fingerprint
 from pipelinedp_tpu.obs import monitor  # noqa: E402 (needs store first)
@@ -79,7 +91,8 @@ __all__ = [
     "ledger", "tracer", "run_tracer", "span", "inc", "event", "reset",
     "gauge", "gauge_max", "sample",
     "environment_fingerprint", "build_run_report", "write_chrome_trace",
-    "device_annotation", "audit", "costs", "store", "monitor",
+    "device_annotation", "profiler_annotation", "SCOPES",
+    "program_scopes", "audit", "costs", "store", "monitor",
     "metrics", "trace_context",
 ]
 
@@ -188,33 +201,8 @@ def write_chrome_trace(path: Optional[str] = None,
         snapshot if snapshot is not None else _LEDGER.snapshot())
 
 
-#: ``jax.profiler.TraceAnnotation`` resolved ONCE per process (False =
-#: not yet resolved, None = jax doesn't expose it). The resolution is
-#: deferred to the first annotated dispatch rather than obs import —
-#: this package must stay importable without touching jax (platform
-#: selection may not have settled) — but never repeats: the old
-#: per-call ``from jax.profiler import ...`` paid the import-machinery
-#: lookup on every kernel dispatch of a traced run.
-_TRACE_ANNOTATION: Any = False
-
-
 def device_annotation(name: str):
-    """``jax.profiler.TraceAnnotation`` around a kernel dispatch so
-    device profiles line up with host spans — active only under
-    ``PIPELINEDP_TPU_TRACE`` (and only when jax exposes the API);
-    otherwise the shared no-op context."""
-    global _TRACE_ANNOTATION
-    if not trace_enabled():
-        return NOOP_SPAN
-    if _TRACE_ANNOTATION is False:
-        try:
-            from jax.profiler import TraceAnnotation
-            _TRACE_ANNOTATION = TraceAnnotation
-        except Exception:
-            _TRACE_ANNOTATION = None
-    if _TRACE_ANNOTATION is None:
-        return NOOP_SPAN
-    try:
-        return _TRACE_ANNOTATION(name)
-    except Exception:
-        return NOOP_SPAN
+    """A ``pdp.*`` dispatch region on the profiler's clock: the same
+    bridge every measuring span opens (:func:`obs.tracer.
+    profiler_annotation`), for call sites with no span of their own."""
+    return profiler_annotation(name)

@@ -12,9 +12,10 @@ measurements that argument needs, per compiled program:
   (``jax_engine.py``, ``streaming.py``, ``analysis/jax_sweep.py``,
   ``parallel/sharded.py``) compiles through. Same signature as
   ``functools.partial(jax.jit, ...)`` plus a ``phase=`` label. With
-  ``PIPELINEDP_TPU_COSTS`` unset it IS ``jax.jit`` (one env check per
-  call, nothing else). Enabled, the first call per (function,
-  abstract-shape signature) compiles ahead-of-time via
+  ``PIPELINEDP_TPU_COSTS`` unset it IS ``jax.jit`` (one env check and
+  two reads of jax's dispatch-cache size per call; a call that grew the
+  cache keeps its abstract signature). Enabled, the first call per
+  (function, abstract-shape signature) compiles ahead-of-time via
   ``jitted.lower(...).compile()`` — the SAME program XLA would build
   for the traced call — records a ``compile.program`` span with the
   compile wall time and the persistent-compile-cache hit/miss verdict,
@@ -41,10 +42,21 @@ measurements that argument needs, per compiled program:
   max (and a ledger time-series for the Chrome-trace counter track),
   so the heartbeat shows live device memory and leaks between sweeps
   become visible as a watermark that never comes back down.
+* :data:`SCOPES`, :func:`scope` and :func:`program_scopes` — the
+  fused programs name their phases with ``jax.named_scope`` (one of
+  :data:`SCOPES` each). Every ``instrumented_jit`` program keeps the
+  abstract signature of each call that compiled a new executable
+  (static values, and a ``ShapeDtypeStruct`` with its sharding per
+  array: no array stays referenced). ``program_scopes()`` compiles
+  those signatures again on request — the persistent compile cache
+  hands back the same executable — and maps every HLO instruction to
+  the innermost declared scope in its ``op_name`` metadata, the names
+  a profiler trace's device operations carry.
 
 Bit-identity: the AOT executable is the same XLA program as the traced
 call's, so DP outputs are bit-identical with the flag on vs off —
-asserted as PARITY row 31, exactly like trace/audit/heartbeat.
+asserted as PARITY row 31, exactly like trace/audit/heartbeat. Named
+scopes change HLO metadata only, never an operation.
 
 This module imports only the stdlib at module level (``obs`` must stay
 importable before jax platform selection settles); jax is imported
@@ -54,14 +66,60 @@ has long since imported it.
 
 from __future__ import annotations
 
+import collections
 import functools
 import inspect
 import os
+import re
 import threading
 import time as _time
+import weakref
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 ENV_VAR = "PIPELINEDP_TPU_COSTS"
+
+#: The phases of the fused aggregation programs, each a
+#: ``jax.named_scope`` around the code that does it: the bounding sort
+#: (and the total-cap sort), the gathers by its order, the per-row
+#: rank/ordinal/clip masks, the per-partition segment sum, partition
+#: selection, on-device noise draws, the cross-chip combine, and the
+#: compact fetch's output compaction.
+SCOPES = ("bound_sort", "bound_permute", "bound_caps", "segsum", "select",
+          "noise", "combine", "compact")
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for one of :data:`SCOPES` (any other
+    name is a ValueError, so a typo cannot silently leave a phase
+    unnamed)."""
+    if name not in SCOPES:
+        raise ValueError(f"{name!r} is not a declared scope {SCOPES}")
+    import jax
+    return jax.named_scope(name)
+
+
+def scoped(name: str):
+    """Decorator form of :func:`scope`: the function's whole body runs
+    inside a fresh scope per call."""
+    scope(name)  # a typo fails at import, not at the first trace
+
+    def wrap(fn: Callable) -> Callable:
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
+
+
+#: Every ``instrumented_jit`` program of the process, for
+#: :func:`program_scopes`.
+_PROGRAMS: "weakref.WeakSet[_InstrumentedFunction]" = weakref.WeakSet()
+
+#: A compile option (at its default) that only GPU compiles read: it
+#: keys a compile apart in jax's caches without changing a CPU or TPU
+#: program (see ``_InstrumentedFunction.scope_tables``).
+_FRESH_COMPILE = {"xla_gpu_enable_fast_min_max": False}
 
 #: Nominal peak FLOP/s and HBM bytes/s per device kind — the roofline
 #: ceilings arithmetic intensity is judged against. Matching is by
@@ -338,8 +396,14 @@ class _InstrumentedFunction:
         self._phase = phase
         self._jit_kwargs = jit_kwargs
         self._jitted = jax.jit(fn, **jit_kwargs)
+        self._cache_size = self._jitted._cache_size
         self._compiled: Dict[Any, Any] = {}
+        #: abstract signature key -> (args, kwargs) to lower it again,
+        #: and -> (module name, fingerprint, scope table) once lowered.
+        self._signatures: Dict[Any, Tuple[tuple, dict]] = {}
+        self._scope_tables: Dict[Any, Tuple[str, str, Dict[str, str]]] = {}
         self._lock = threading.Lock()
+        _PROGRAMS.add(self)
         # Static-parameter resolution mirrors jax's: names from
         # ``static_argnames``, positions from ``static_argnums``,
         # mapped onto the function's signature once.
@@ -368,7 +432,13 @@ class _InstrumentedFunction:
 
     def __call__(self, *args, **kwargs):
         if not costs_enabled():
-            return self._jitted(*args, **kwargs)
+            # jax's dispatch cache grows exactly when this call's
+            # signature is new: only then is it worth keeping.
+            before = self._cache_size()
+            out = self._jitted(*args, **kwargs)
+            if self._cache_size() != before:
+                self._note_signature(args, kwargs)
+            return out
         split = self._split(args, kwargs)
         if split is None:  # *args/**kwargs signature: capture skipped
             return self._jitted(*args, **kwargs)
@@ -433,6 +503,77 @@ class _InstrumentedFunction:
         except TypeError:
             return None  # unhashable static: let jax handle it
         return key, dyn_args, dyn_kwargs
+
+    def _note_signature(self, args, kwargs) -> None:
+        """Keeps the abstract signature of one call: static values as
+        given, every other leaf as a ``ShapeDtypeStruct`` (with its
+        sharding where the array is committed to one), so no array
+        stays referenced. Never raises: an unkeepable signature only
+        leaves its program out of :func:`program_scopes`."""
+        import jax
+        from jax.api_util import shaped_abstractify
+
+        def abstract(x):
+            aval = shaped_abstractify(x)
+            sharding = (x.sharding if getattr(x, "_committed", False)
+                        else None)
+            return jax.ShapeDtypeStruct(aval.shape, aval.dtype,
+                                        sharding=sharding,
+                                        weak_type=aval.weak_type)
+
+        def keep(name, value):
+            return (value if name in self._static_names
+                    else jax.tree.map(abstract, value))
+
+        try:
+            a_args = tuple(keep(self._pos_names[i], a)
+                           for i, a in enumerate(args))
+            a_kwargs = {k: keep(k, v) for k, v in kwargs.items()}
+            leaves, treedef = jax.tree.flatten((a_args, a_kwargs))
+            key = (treedef, tuple(leaves))
+            # lint: disable=sketch-confinement(in-process hashability probe of a jit signature tuple, not a data key)
+            hash(key)
+        except Exception as e:  # a signature must never fail the call
+            from pipelinedp_tpu import obs
+            obs.event("cost.signature_skipped", program=self._fn.__name__,
+                      error=f"{type(e).__name__}: {e}")
+            return
+        with self._lock:
+            self._signatures.setdefault(key, (a_args, a_kwargs))
+
+    def scope_tables(self) -> List[Tuple[str, str, Dict[str, str]]]:
+        """(HLO module name, executable fingerprint, {instruction:
+        scope}) for every signature this program ran with, compiling
+        the ones not yet mapped (from the persistent cache where it
+        holds them)."""
+        with self._lock:
+            pending = [(k, v) for k, v in self._signatures.items()
+                       if k not in self._scope_tables]
+        for key, (a_args, a_kwargs) in pending:
+            lowered = self._jitted.lower(*a_args, **a_kwargs)
+            compiled = lowered.compile()
+            module, table = hlo_scope_table(compiled.as_text())
+            if not table and any(f"/{s}/" in lowered.as_text(debug_info=True)
+                                 for s in SCOPES):
+                # The compile caches key programs with their metadata
+                # stripped, so they can hand back an executable built
+                # from the same program before it named its scopes.
+                # An option that is in the key but read by no CPU or
+                # TPU compile misses both caches; XLA names the
+                # instructions of the same program the same way.
+                try:
+                    fresh = lowered.compile(compiler_options=_FRESH_COMPILE)
+                except Exception as e:  # the table stays empty, loudly
+                    from pipelinedp_tpu import obs
+                    obs.event("cost.scope_table_stale", program=module,
+                              error=f"{type(e).__name__}: {e}")
+                else:
+                    module, table = hlo_scope_table(fresh.as_text())
+            with self._lock:
+                self._scope_tables[key] = (module, _fingerprint(compiled),
+                                           table)
+        with self._lock:
+            return list(self._scope_tables.values())
 
     def _table_key(self, key) -> str:
         # lint: disable=sketch-confinement(in-process program-table digest of a jit signature, not a data key; never persisted)
@@ -523,6 +664,7 @@ class _InstrumentedFunction:
                 "calls": 0,
             })
             obs.inc("cost.programs_captured")
+            self._note_signature(args, kwargs)
             entry = (compiled, table_key)
             # lint: disable=blocking-under-lock(leaf dict lock; never held around _CAPTURE_LOCK)
             with self._lock:
@@ -543,6 +685,117 @@ def instrumented_jit(fn: Optional[Callable] = None, *,
     def wrap(f: Callable) -> _InstrumentedFunction:
         return _InstrumentedFunction(f, phase, jit_kwargs)
     return wrap
+
+
+# --- HLO instruction -> named scope (profiler trace attribution) ---
+
+_HLO_MODULE = re.compile(r"HloModule\s+([^\s,]+)")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?%?([\w.\-]+)\s+=\s")
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_HLO_APPLIES = re.compile(r"\bto_apply=%?([\w.\-]+)")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_OPERAND = re.compile(r"%([\w.\-]+)")
+_HLO_COLLECTIVE = re.compile(
+    r"\s(?:all-reduce|reduce-scatter|all-gather|all-to-all|"
+    r"collective-permute)(?:-start|-done)?\(")
+
+
+def _op_scope(op_name: str) -> Optional[str]:
+    """The innermost declared scope on an ``op_name`` path
+    (``jit(f)/shard_map/bound_caps/segsum/scatter-add`` -> segsum)."""
+    for part in reversed(op_name.split("/")):
+        if part in SCOPES:
+            return part
+    return None
+
+
+def hlo_scope_table(hlo_text: str) -> Tuple[str, Dict[str, str]]:
+    """(module name, {instruction name: scope}) of one compiled
+    program's HLO text, for the instructions a profiler trace shows as
+    device operations: those of every computation that is not a fusion
+    body or an applied reduction. An instruction takes the scope of its
+    own ``op_name``; a fusion whose ``op_name`` names none takes its
+    root's, then the scope most of its instructions name; a cross-chip
+    collective still without one is ``combine`` (XLA merges the
+    combine's collectives into one and drops their metadata); any other
+    instruction without one (a layout copy, a scan XLA emits without
+    metadata) takes its first scoped operand's. Instructions under no
+    declared scope are left out."""
+    m = _HLO_MODULE.search(hlo_text)
+    module = m.group(1) if m else ""
+    comps: Dict[str, List[Tuple[str, Optional[str], Optional[str],
+                                bool, bool, List[str]]]] = {}
+    inner = set()
+    current: Optional[List] = None
+    for line in hlo_text.splitlines():
+        if not line.startswith(" "):
+            c = _HLO_COMPUTATION.match(line)
+            current = comps.setdefault(c.group(1), []) if c else None
+            continue
+        i = _HLO_INSTRUCTION.match(line)
+        if current is None or i is None:
+            continue
+        op = _HLO_OP_NAME.search(line)
+        calls = _HLO_CALLS.search(line)
+        inner.update(x.group(1) for x in (calls, _HLO_APPLIES.search(line))
+                     if x)
+        current.append((i.group(2), _op_scope(op.group(1)) if op else None,
+                        calls.group(1) if calls else None, bool(i.group(1)),
+                        bool(_HLO_COLLECTIVE.search(line)),
+                        _HLO_OPERAND.findall(line[i.end():])))
+
+    def comp_scope(name: str, depth: int = 0) -> Optional[str]:
+        body = comps.get(name, ())
+        for _, own, calls, root, _, _ in body:
+            if root:
+                got = own or (comp_scope(calls, depth + 1)
+                              if calls and depth < 8 else None)
+                if got:
+                    return got
+        votes = collections.Counter(b[1] for b in body if b[1])
+        return votes.most_common(1)[0][0] if votes else None
+
+    table: Dict[str, str] = {}
+    for name, body in comps.items():
+        if name in inner:
+            continue
+        for instr, own, calls, _, collective, operands in body:
+            got = (own or (comp_scope(calls) if calls else None) or
+                   ("combine" if collective else None) or
+                   next((table[o] for o in operands if o in table), None))
+            if got:
+                table[instr] = got
+    return module, table
+
+
+def _fingerprint(compiled) -> str:
+    """The executable's fingerprint as text: as given where the backend
+    spells it in digits (CPU), else its bytes in hex (TPU)."""
+    raw = compiled.runtime_executable().fingerprint
+    if isinstance(raw, bytes):
+        return raw.decode() if raw.isdigit() else raw.hex()
+    return str(raw)
+
+
+def program_scopes() -> Dict[str, Dict[str, str]]:
+    """``{program: {HLO instruction name: scope}}`` for every
+    ``instrumented_jit`` program that has run in this process, compiled
+    on request (not on any hot path). ``program`` is the HLO module
+    name a profiler trace gives the program's ``XLA Modules`` events
+    (``jit_fused_aggregate_kernel``); a program that ran with several
+    signatures keys each apart as ``<name>(<fingerprint>)``, the
+    fingerprint of its executable (:func:`_fingerprint`)."""
+    by_module: Dict[str, List[Tuple[str, Dict[str, str]]]] = {}
+    for fn in list(_PROGRAMS):
+        for module, fingerprint, table in fn.scope_tables():
+            by_module.setdefault(module, []).append((fingerprint, table))
+    out: Dict[str, Dict[str, str]] = {}
+    for module, tables in by_module.items():
+        for fingerprint, table in tables:
+            out[module if len(tables) == 1
+                else f"{module}({fingerprint})"] = table
+    return out
 
 
 # --- HBM watermark sampling (monitor beat hook) ---

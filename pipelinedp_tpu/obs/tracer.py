@@ -28,12 +28,19 @@ Clock: tracers accept any ``pipelinedp_tpu.resilience.clock.Clock``
 with a ``FakeClock`` and assert exact durations in zero wall time. The
 default clock reads ``time.perf_counter`` — ``obs/`` is the ONE package
 allowed to touch the raw timer (``make noperf`` bans it elsewhere).
+
+Profiler clock: every measuring span (a :class:`Tracer`'s, not the
+no-op's) also opens a ``jax.profiler.TraceAnnotation`` of its name
+(:func:`profiler_annotation`), so a ``jax.profiler`` trace holds the
+program's spans on the host plane beside the device's operations. With
+no profiler session running the annotation records nothing.
 """
 
 from __future__ import annotations
 
 import collections
 import os
+import sys
 import threading
 import time as _time
 from typing import Any, Dict, List, Optional, Tuple
@@ -81,6 +88,31 @@ def trace_destination(default: str = "pdp_trace.json") -> str:
     if os.sep in v or "/" in v or v.endswith(".json"):
         return v
     return default
+
+
+#: ``jax.profiler.TraceAnnotation``, resolved once per process at the
+#: first annotation after jax is imported (None: not yet; False: this
+#: jax has no profiler). ``obs`` itself never imports jax.
+_TRACE_ANNOTATION: Any = None
+
+
+def profiler_annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named ``name``: the one bridge
+    from the program's spans and dispatch regions to the profiler's
+    trace. It records only while a profiler session runs, and a session
+    needs jax, so before jax is imported this returns the shared no-op
+    span and imports nothing."""
+    global _TRACE_ANNOTATION
+    annotation = _TRACE_ANNOTATION
+    if annotation is None:
+        if "jax" not in sys.modules:
+            return NOOP_SPAN
+        try:
+            from jax.profiler import TraceAnnotation as annotation
+        except ImportError:
+            annotation = False
+        _TRACE_ANNOTATION = annotation
+    return annotation(name) if annotation else NOOP_SPAN
 
 
 class _PerfClock:
@@ -290,10 +322,11 @@ class RunLedger:
 class _SpanHandle:
     """Context manager for one span. ``duration`` holds the measured
     seconds after exit (bench helpers read it directly, replacing their
-    two-``perf_counter`` idiom)."""
+    two-``perf_counter`` idiom). The span's profiler annotation
+    brackets the measured interval."""
 
     __slots__ = ("_tracer", "name", "cat", "args", "_t0", "duration",
-                 "_ctx_token")
+                 "_ctx_token", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Dict[str, Any]):
@@ -304,8 +337,11 @@ class _SpanHandle:
         self._t0 = 0.0
         self.duration = 0.0
         self._ctx_token = None
+        self._annotation = NOOP_SPAN
 
     def __enter__(self) -> "_SpanHandle":
+        self._annotation = profiler_annotation(self.name)
+        self._annotation.__enter__()
         self._t0 = self._tracer._clock.monotonic()
         sid = self.args.get("span_id")
         if sid is not None:
@@ -325,6 +361,7 @@ class _SpanHandle:
         if ACTIVITY.enabled:
             ACTIVITY.span_closed(self, self.duration)
         self._tracer._finish(self, self._t0, self.duration)
+        self._annotation.__exit__(exc_type, exc, tb)
         return False
 
 

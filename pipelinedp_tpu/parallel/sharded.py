@@ -35,7 +35,6 @@ global device mesh.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import os
 from typing import Dict, List, Optional, Tuple
 
@@ -47,7 +46,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as PSpec
 
 from pipelinedp_tpu import jax_engine
-from pipelinedp_tpu.obs.costs import instrumented_jit
+from pipelinedp_tpu.obs.costs import instrumented_jit, scope
 
 from jax import shard_map
 
@@ -509,7 +508,8 @@ def _sharded_kernel(config, num_partitions, mesh, pid, pk, values, valid,
         # gather + owner-scatter protocol internally, with the same
         # topology).
         def to_owner(x):
-            return combine_shards(x, axis, 0, False, topo=topo)
+            with scope("combine"):
+                return combine_shards(x, axis, 0, False, topo=topo)
 
         part = jax.tree.map(to_owner, part)
         part_nseg = to_owner(part_nseg)
@@ -538,12 +538,16 @@ def sharded_fused_aggregate(mesh: Mesh, config, num_partitions: int,
                             noise_scales, keep_table, sel_threshold,
                             sel_scale, sel_min_count, sel_rows_per_uid,
                             key, fx_bits: int = 7,
-                            kernel_backend: str = "xla"):
+                            kernel_backend: str = "xla", tracer=None):
     """Host entry: re-shards rows by hash(pid), pads each shard to a
     common length, places arrays over the mesh and runs the sharded
     kernel. Returns (keep_pk[P], accumulator dict) with the partition
     axis sharded over the mesh (device d owns block d); the scalar
-    release happens downstream on host."""
+    release happens downstream on host. ``tracer`` (an ``obs`` tracer)
+    times the host re-shard as ``engine.reshard`` and the placement on
+    the mesh, until the arrays have landed, as ``engine.h2d``."""
+    from pipelinedp_tpu import obs
+    tracer = tracer if tracer is not None else obs.NOOP_TRACER
     n_dev = mesh.devices.size
     # Owner blocks must tile the pk axis evenly. When this rounding is a
     # no-op (any power-of-two mesh: the padded axis is a power of two),
@@ -551,45 +555,48 @@ def sharded_fused_aggregate(mesh: Mesh, config, num_partitions: int,
     # size that does NOT divide the padded axis widens it, so the draws
     # differ from single-chip (still valid DP, just not replay-identical).
     num_partitions = -(-num_partitions // n_dev) * n_dev
-    # Hash before the modulo: raw ids pass through the encode step
-    # unchanged, and id families sharing a residue class (all-even user
-    # ids, snowflake ids with fixed low bits) would otherwise pile every
-    # row onto one device.
-    from pipelinedp_tpu.ops.segment import fmix32
-    shard_of_row = (fmix32(pid.astype(np.uint32)) % np.uint32(n_dev)
-                    ).astype(np.int32)
-    order = np.argsort(shard_of_row, kind="stable")
-    counts = np.bincount(shard_of_row, minlength=n_dev)
-    per_shard = jax_engine._pad_rows(int(counts.max()) if len(pid) else 1)
+    with tracer.span("engine.reshard", cat="engine"):
+        # Hash before the modulo: raw ids pass through the encode step
+        # unchanged, and id families sharing a residue class (all-even
+        # user ids, snowflake ids with fixed low bits) would otherwise
+        # pile every row onto one device.
+        from pipelinedp_tpu.ops.segment import fmix32
+        shard_of_row = (fmix32(pid.astype(np.uint32)) % np.uint32(n_dev)
+                        ).astype(np.int32)
+        order = np.argsort(shard_of_row, kind="stable")
+        counts = np.bincount(shard_of_row, minlength=n_dev)
+        per_shard = jax_engine._pad_rows(int(counts.max()) if len(pid)
+                                         else 1)
 
-    def shard_array(arr, fill=0):
-        shape = (n_dev * per_shard,) + arr.shape[1:]
-        out = np.full(shape, fill, dtype=arr.dtype)
-        offset = 0
-        for d in range(n_dev):
-            rows = order[offset:offset + counts[d]]
-            out[d * per_shard:d * per_shard + counts[d]] = arr[rows]
-            offset += counts[d]
-        return out
+        def shard_array(arr, fill=0):
+            shape = (n_dev * per_shard,) + arr.shape[1:]
+            out = np.full(shape, fill, dtype=arr.dtype)
+            offset = 0
+            for d in range(n_dev):
+                rows = order[offset:offset + counts[d]]
+                out[d * per_shard:d * per_shard + counts[d]] = arr[rows]
+                offset += counts[d]
+            return out
 
-    pid_s = shard_array(pid)
-    pk_s = shard_array(pk)
-    valid_s = shard_array(valid, fill=False)
-
-    sharding = NamedSharding(mesh, PSpec(mesh.axis_names[0]))
-    dev = functools.partial(put_global, sharding=sharding)
-    if values is None:
+        pid_s = shard_array(pid)
+        pk_s = shard_array(pk)
+        valid_s = shard_array(valid, fill=False)
         # Config never reads values (COUNT-style / select_partitions):
-        # materialize the zeros on device instead of shipping them over
-        # the host link.
-        values_dev = put_global(
-            np.zeros(n_dev * per_shard, np.float32), sharding)
-    else:
-        values_dev = dev(shard_array(values))
-    return _sharded_kernel(
-        config, num_partitions, mesh, dev(pid_s), dev(pk_s),
-        values_dev, dev(valid_s), jnp.asarray(noise_scales),
-        jnp.asarray(keep_table), jnp.float32(sel_threshold),
-        jnp.float32(sel_scale), jnp.float32(sel_min_count),
-        jnp.float32(sel_rows_per_uid), key, fx_bits=fx_bits,
-        kernel_backend=kernel_backend)
+        # the value column is zeros, with nothing to re-shard.
+        values_s = None if values is None else shard_array(values)
+
+    with tracer.span("engine.h2d", cat="engine"):
+        sharding = NamedSharding(mesh, PSpec(mesh.axis_names[0]))
+        placed = put_global(
+            (pid_s, pk_s,
+             np.zeros(n_dev * per_shard, np.float32) if values_s is None
+             else values_s, valid_s), sharding)
+        scalars = (jnp.asarray(noise_scales), jnp.asarray(keep_table),
+                   jnp.float32(sel_threshold), jnp.float32(sel_scale),
+                   jnp.float32(sel_min_count), jnp.float32(sel_rows_per_uid))
+        # The span ends when the arrays have landed, not when the
+        # transfer is enqueued; the kernel waits for them anyway.
+        jax.block_until_ready((placed, scalars))
+    return _sharded_kernel(config, num_partitions, mesh, *placed, *scalars,
+                           key, fx_bits=fx_bits,
+                           kernel_backend=kernel_backend)
