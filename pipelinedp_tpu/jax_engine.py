@@ -2815,7 +2815,7 @@ def _run_fused_kernel(config: FusedConfig, encoded: EncodedData, scales,
             keep_pk, raw = sharded_fused_aggregate(
                 mesh, config, P_pad, encoded.pid, encoded.pk,
                 encoded.values if config.needs_values else None,
-                np.ones(encoded.n_rows, bool), scales, keep_table, thr,
+                scales, keep_table, thr,
                 s_scale, min_count, rows_per_uid, key, fx_bits,
                 kernel_backend=kernel_backend, tracer=tracer)
         return keep_pk, raw, fx_bits

@@ -28,6 +28,11 @@ toolchain's ``g++`` into a cached shared library next to this file (or
 Environments without a compiler get ``NativeUnavailableError`` and
 callers fall back to the NumPy path — ``ops/noise.py`` documents the
 resulting security posture.
+
+Two host hot paths have libraries of their own, built the same way:
+``factorize_i64`` (``encode.cc``, the ingest encoder) and
+``shard_rows`` (``shard.cc``, the mesh path's re-shard of rows by
+privacy id).
 """
 
 from __future__ import annotations
@@ -42,10 +47,7 @@ from typing import Optional
 
 import numpy as np
 
-_SRC = os.path.join(os.path.dirname(__file__), "secure_noise.cc")
 _LOCK = threading.Lock()
-_LIB: Optional[ctypes.CDLL] = None
-_LOAD_ERROR: Optional[str] = None
 
 
 class NativeUnavailableError(RuntimeError):
@@ -85,45 +87,65 @@ def _build_shared_lib(src: str, out_name: str) -> str:
     return out
 
 
-def _build() -> str:
-    return _build_shared_lib(_SRC, "_secure_noise.so")
+class _Loader:
+    """Builds and loads one ``.cc`` of this package on first call, then
+    returns the same library; a failed build is remembered and raised
+    again as ``NativeUnavailableError``. ``declare`` sets the
+    functions' ctypes signatures."""
+
+    def __init__(self, src_name: str, lib_name: str, declare):
+        self._src = os.path.join(os.path.dirname(__file__), src_name)
+        self._lib_name = lib_name
+        self._declare = declare
+        self._lib: Optional[ctypes.CDLL] = None
+        self._error: Optional[str] = None
+
+    @property
+    def loaded(self) -> bool:
+        """True iff the library is loaded; never triggers a build."""
+        return self._lib is not None
+
+    def __call__(self) -> ctypes.CDLL:
+        if self._lib is not None:
+            return self._lib
+        with _LOCK:
+            if self._error is not None:
+                raise NativeUnavailableError(self._error)
+            if self._lib is None:
+                try:
+                    lib = ctypes.CDLL(_build_shared_lib(self._src,
+                                                        self._lib_name))
+                except (OSError, NativeUnavailableError) as e:
+                    self._error = str(e)
+                    raise NativeUnavailableError(self._error) from e
+                self._declare(lib)
+                self._lib = lib
+            return self._lib
 
 
-def _lib() -> ctypes.CDLL:
-    global _LIB, _LOAD_ERROR
-    if _LIB is not None:
-        return _LIB
-    if _LOAD_ERROR is not None:
-        raise NativeUnavailableError(_LOAD_ERROR)
-    with _LOCK:
-        if _LIB is not None:
-            return _LIB
-        try:
-            lib = ctypes.CDLL(_build())
-        except (OSError, NativeUnavailableError) as e:
-            _LOAD_ERROR = str(e)
-            raise NativeUnavailableError(_LOAD_ERROR) from e
-        lib.sn_seed.argtypes = [ctypes.c_uint64]
-        lib.sn_seed_from_os.argtypes = []
-        lib.sn_snapping_laplace.argtypes = [
-            ctypes.POINTER(ctypes.c_double), ctypes.POINTER(ctypes.c_double),
-            ctypes.c_int64, ctypes.c_double, ctypes.c_double]
-        lib.sn_snapping_laplace.restype = ctypes.c_double
-        lib.sn_uniform.argtypes = [ctypes.POINTER(ctypes.c_double),
-                                   ctypes.c_int64]
-        lib.sn_discrete_laplace.argtypes = [
-            ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
-            ctypes.c_int64, ctypes.c_double]
-        lib.sn_discrete_gaussian.argtypes = [
-            ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
-            ctypes.c_int64, ctypes.c_double]
-        lib.sn_discrete_gaussian.restype = ctypes.c_int32
-        lib.sn_secure_gaussian.argtypes = [
-            ctypes.POINTER(ctypes.c_double), ctypes.POINTER(ctypes.c_double),
-            ctypes.c_int64, ctypes.c_double, ctypes.c_double]
-        lib.sn_secure_gaussian.restype = ctypes.c_double
-        _LIB = lib
-        return _LIB
+def _declare_noise(lib: ctypes.CDLL) -> None:
+    lib.sn_seed.argtypes = [ctypes.c_uint64]
+    lib.sn_seed_from_os.argtypes = []
+    lib.sn_snapping_laplace.argtypes = [
+        ctypes.POINTER(ctypes.c_double), ctypes.POINTER(ctypes.c_double),
+        ctypes.c_int64, ctypes.c_double, ctypes.c_double]
+    lib.sn_snapping_laplace.restype = ctypes.c_double
+    lib.sn_uniform.argtypes = [ctypes.POINTER(ctypes.c_double),
+                               ctypes.c_int64]
+    lib.sn_discrete_laplace.argtypes = [
+        ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
+        ctypes.c_int64, ctypes.c_double]
+    lib.sn_discrete_gaussian.argtypes = [
+        ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
+        ctypes.c_int64, ctypes.c_double]
+    lib.sn_discrete_gaussian.restype = ctypes.c_int32
+    lib.sn_secure_gaussian.argtypes = [
+        ctypes.POINTER(ctypes.c_double), ctypes.POINTER(ctypes.c_double),
+        ctypes.c_int64, ctypes.c_double, ctypes.c_double]
+    lib.sn_secure_gaussian.restype = ctypes.c_double
+
+
+_lib = _Loader("secure_noise.cc", "_secure_noise.so", _declare_noise)
 
 
 def available() -> bool:
@@ -140,7 +162,7 @@ def available() -> bool:
 def is_loaded() -> bool:
     """True iff the library is already loaded in this process. Never
     triggers a build."""
-    return _LIB is not None
+    return _lib.loaded
 
 
 def seed(n: int) -> None:
@@ -277,31 +299,14 @@ def uniform(n: int) -> np.ndarray:
 # Ingest acceleration: hash-based integer factorization (encode.cc)
 # ---------------------------------------------------------------------------
 
-_ENC_SRC = os.path.join(os.path.dirname(__file__), "encode.cc")
-_ENC_LIB: Optional[ctypes.CDLL] = None
-_ENC_ERROR: Optional[str] = None
+def _declare_encode(lib: ctypes.CDLL) -> None:
+    lib.pdp_factorize_i64.argtypes = [
+        ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int64)]
+    lib.pdp_factorize_i64.restype = ctypes.c_int64
 
 
-def _enc_lib() -> ctypes.CDLL:
-    global _ENC_LIB, _ENC_ERROR
-    if _ENC_LIB is not None:
-        return _ENC_LIB
-    if _ENC_ERROR is not None:
-        raise NativeUnavailableError(_ENC_ERROR)
-    with _LOCK:
-        if _ENC_LIB is not None:
-            return _ENC_LIB
-        try:
-            lib = ctypes.CDLL(_build_shared_lib(_ENC_SRC, "_encode.so"))
-        except (OSError, NativeUnavailableError) as e:
-            _ENC_ERROR = str(e)
-            raise NativeUnavailableError(_ENC_ERROR) from e
-        lib.pdp_factorize_i64.argtypes = [
-            ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int64)]
-        lib.pdp_factorize_i64.restype = ctypes.c_int64
-        _ENC_LIB = lib
-        return _ENC_LIB
+_enc_lib = _Loader("encode.cc", "_encode.so", _declare_encode)
 
 
 def encode_available() -> bool:
@@ -344,3 +349,81 @@ def factorize_i64(arr: np.ndarray):
         raise NativeUnavailableError(
             "pdp_factorize_i64 failed (allocation or id overflow)")
     return uniq[:u].copy(), inverse
+
+
+# ---------------------------------------------------------------------------
+# Mesh re-shard: stable counting scatter of rows by shard (shard.cc)
+# ---------------------------------------------------------------------------
+
+#: Shard ids are one byte on the native side.
+MAX_SHARDS = 256
+
+
+def _declare_shard(lib: ctypes.CDLL) -> None:
+    lib.pdp_shard_assign.argtypes = [
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32, ctypes.c_void_p,
+        ctypes.c_void_p]
+    lib.pdp_shard_assign.restype = ctypes.c_int32
+    lib.pdp_shard_scatter.argtypes = [
+        ctypes.POINTER(ctypes.c_void_p), ctypes.POINTER(ctypes.c_void_p),
+        ctypes.c_void_p, ctypes.c_int32, ctypes.c_int64, ctypes.c_void_p,
+        ctypes.c_int32, ctypes.c_void_p]
+    lib.pdp_shard_scatter.restype = None
+
+
+_shard_lib = _Loader("shard.cc", "_shard.so", _declare_shard)
+
+
+def shard_rows(pid: np.ndarray, columns, n_dev: int, pad_rows):
+    """Groups rows by shard ``fmix32(pid) % n_dev`` and pads each shard
+    to one length, in O(n): one pass hashes and counts, one pass copies
+    each row of every column to the next free row of its shard.
+
+    Returns ``(sharded, counts, per_shard)``. ``per_shard`` is
+    ``pad_rows(counts.max())`` (``pad_rows(1)`` with no rows);
+    ``sharded[k]`` is ``columns[k]`` laid out as ``n_dev`` blocks of
+    ``per_shard`` rows, block ``d`` holding shard ``d``'s rows in input
+    order (what a stable argsort of the shard ids gives) and then zeros
+    (``False`` for bool columns). ``pid`` is read as uint32, as
+    ``fmix32(pid.astype(np.uint32))`` reads it. Columns may have any
+    trailing shape. Raises ``NativeUnavailableError`` when the library
+    cannot be built or ``n_dev`` is outside ``[1, MAX_SHARDS]``."""
+    if not 1 <= n_dev <= MAX_SHARDS:
+        raise NativeUnavailableError(
+            f"shard ids are one byte: {n_dev} shards is outside "
+            f"[1, {MAX_SHARDS}]")
+    lib = _shard_lib()
+    pid = np.asarray(pid)
+    if pid.ndim != 1:
+        raise ValueError(f"shard_rows: pid must be 1-D, not {pid.shape}")
+    n = pid.shape[0]
+    if pid.dtype.kind in "iu" and pid.dtype.itemsize == 4:
+        keys = np.ascontiguousarray(pid).view(np.uint32)
+    else:
+        keys = np.ascontiguousarray(pid.astype(np.uint32))
+    shard = np.empty(n, np.uint8)
+    counts = np.zeros(n_dev, np.int64)
+    lib.pdp_shard_assign(keys.ctypes.data, n, n_dev, shard.ctypes.data,
+                         counts.ctypes.data)
+    per_shard = pad_rows(int(counts.max()) if n else 1)
+    start = np.arange(n_dev, dtype=np.int64) * per_shard
+    columns = [np.ascontiguousarray(col) for col in columns]
+    for col in columns:
+        if col.shape[0] != n or col.dtype.hasobject:
+            raise ValueError(
+                f"shard_rows: a column of shape {col.shape} and dtype "
+                f"{col.dtype} does not hold {n} plain rows")
+    sharded = [np.empty((n_dev * per_shard,) + col.shape[1:], col.dtype)
+               for col in columns]
+    k = len(columns)
+    width = np.array([col.itemsize * int(np.prod(col.shape[1:]))
+                      for col in columns], np.int64)
+    lib.pdp_shard_scatter(
+        (ctypes.c_void_p * k)(*[col.ctypes.data for col in columns]),
+        (ctypes.c_void_p * k)(*[out.ctypes.data for out in sharded]),
+        width.ctypes.data, k, n, shard.ctypes.data, n_dev,
+        start.ctypes.data)
+    for out in sharded:
+        for d in range(n_dev):
+            out[start[d] + counts[d]:start[d] + per_shard] = 0
+    return sharded, counts, per_shard

@@ -532,9 +532,50 @@ def _sharded_kernel(config, num_partitions, mesh, pid, pk, values, valid,
                   sel_rows_per_uid, key)
 
 
+def reshard_rows(pid: np.ndarray, columns, n_dev: int):
+    """Rows of every column grouped by shard ``fmix32(pid) % n_dev``,
+    input order kept inside a shard, each shard zero-padded to
+    ``per_shard = _pad_rows(largest shard)`` rows. Returns (the columns
+    as ``n_dev * per_shard`` rows, rows per shard, per_shard).
+
+    Hash before the modulo: raw ids pass through the encode step
+    unchanged, and id families sharing a residue class (all-even user
+    ids, snowflake ids with fixed low bits) would otherwise pile every
+    row onto one device. The native counting scatter does it in O(n);
+    where its library cannot load, or the mesh has more than 256
+    devices, numpy's radix grouping gives the same arrays. The ledger
+    counts each call as ``reshard.native`` or ``reshard.fallback``."""
+    from pipelinedp_tpu import native, obs
+    pad_rows = jax_engine._pad_rows
+    try:
+        out = native.shard_rows(pid, columns, n_dev, pad_rows)
+    except native.NativeUnavailableError as e:
+        obs.inc("reshard.fallback")
+        obs.event("reshard.fallback", reason=str(e), n_dev=n_dev)
+    else:
+        obs.inc("reshard.native")
+        return out
+
+    from pipelinedp_tpu.ingest.assign import group_rows_by_cell
+    from pipelinedp_tpu.ops.segment import fmix32
+    shard_of_row = fmix32(pid.astype(np.uint32)) % np.uint32(n_dev)
+    order, counts = group_rows_by_cell(shard_of_row, n_dev)
+    per_shard = pad_rows(int(counts.max()) if len(pid) else 1)
+    ends = np.cumsum(counts)
+    sharded = []
+    for col in columns:
+        out = np.empty((n_dev * per_shard,) + col.shape[1:], col.dtype)
+        for d in range(n_dev):
+            lo = d * per_shard
+            out[lo:lo + counts[d]] = col[order[ends[d] - counts[d]:ends[d]]]
+            out[lo + counts[d]:lo + per_shard] = 0
+        sharded.append(out)
+    return sharded, counts, per_shard
+
+
 def sharded_fused_aggregate(mesh: Mesh, config, num_partitions: int,
                             pid: np.ndarray, pk: np.ndarray,
-                            values: np.ndarray, valid: np.ndarray,
+                            values: Optional[np.ndarray],
                             noise_scales, keep_table, sel_threshold,
                             sel_scale, sel_min_count, sel_rows_per_uid,
                             key, fx_bits: int = 7,
@@ -543,9 +584,10 @@ def sharded_fused_aggregate(mesh: Mesh, config, num_partitions: int,
     common length, places arrays over the mesh and runs the sharded
     kernel. Returns (keep_pk[P], accumulator dict) with the partition
     axis sharded over the mesh (device d owns block d); the scalar
-    release happens downstream on host. ``tracer`` (an ``obs`` tracer)
-    times the host re-shard as ``engine.reshard`` and the placement on
-    the mesh, until the arrays have landed, as ``engine.h2d``."""
+    release happens downstream on host. Every input row is valid; the
+    pad rows are not. ``tracer`` (an ``obs`` tracer) times the host
+    re-shard as ``engine.reshard`` and the placement on the mesh, until
+    the arrays have landed, as ``engine.h2d``."""
     from pipelinedp_tpu import obs
     tracer = tracer if tracer is not None else obs.NOOP_TRACER
     n_dev = mesh.devices.size
@@ -556,34 +598,14 @@ def sharded_fused_aggregate(mesh: Mesh, config, num_partitions: int,
     # differ from single-chip (still valid DP, just not replay-identical).
     num_partitions = -(-num_partitions // n_dev) * n_dev
     with tracer.span("engine.reshard", cat="engine"):
-        # Hash before the modulo: raw ids pass through the encode step
-        # unchanged, and id families sharing a residue class (all-even
-        # user ids, snowflake ids with fixed low bits) would otherwise
-        # pile every row onto one device.
-        from pipelinedp_tpu.ops.segment import fmix32
-        shard_of_row = (fmix32(pid.astype(np.uint32)) % np.uint32(n_dev)
-                        ).astype(np.int32)
-        order = np.argsort(shard_of_row, kind="stable")
-        counts = np.bincount(shard_of_row, minlength=n_dev)
-        per_shard = jax_engine._pad_rows(int(counts.max()) if len(pid)
-                                         else 1)
-
-        def shard_array(arr, fill=0):
-            shape = (n_dev * per_shard,) + arr.shape[1:]
-            out = np.full(shape, fill, dtype=arr.dtype)
-            offset = 0
-            for d in range(n_dev):
-                rows = order[offset:offset + counts[d]]
-                out[d * per_shard:d * per_shard + counts[d]] = arr[rows]
-                offset += counts[d]
-            return out
-
-        pid_s = shard_array(pid)
-        pk_s = shard_array(pk)
-        valid_s = shard_array(valid, fill=False)
         # Config never reads values (COUNT-style / select_partitions):
         # the value column is zeros, with nothing to re-shard.
-        values_s = None if values is None else shard_array(values)
+        sharded, counts, per_shard = reshard_rows(
+            pid, [c for c in (pid, pk, values) if c is not None], n_dev)
+        pid_s, pk_s = sharded[:2]
+        values_s = sharded[2] if values is not None else None
+        # Each shard's rows are valid, its pad rows are not.
+        valid_s = (np.arange(per_shard) < counts[:, None]).ravel()
 
     with tracer.span("engine.h2d", cat="engine"):
         sharding = NamedSharding(mesh, PSpec(mesh.axis_names[0]))

@@ -879,9 +879,9 @@ class TestPartitionAxisSharding:
         keep_table, thr, s_scale, min_count = je.selection_inputs(
             config, 1.0, 1e-6, None)
         keep, out = sharded_fused_aggregate(
-            mesh, config, P, pid, pk, None, np.ones(n, bool),
-            np.zeros(0, np.float32), keep_table, thr, s_scale, min_count,
-            1.0, jax.random.PRNGKey(0))
+            mesh, config, P, pid, pk, None, np.zeros(0, np.float32),
+            keep_table, thr, s_scale, min_count, 1.0,
+            jax.random.PRNGKey(0))
         for arr in [keep] + list(out.values()):
             shard_shapes = {s.data.shape for s in arr.addressable_shards}
             assert shard_shapes == {(P // 8,)}, (
@@ -923,14 +923,73 @@ class TestPartitionAxisSharding:
         keep_table, thr, s_scale, min_count = je.selection_inputs(
             config, BIG_EPS, 1e-6, None)
         keep, out = sharded_fused_aggregate(
-            mesh, config, P, pid, pk, None, np.ones(n, bool),
-            np.zeros(0, np.float32), keep_table, thr, s_scale, min_count,
-            1.0, jax.random.PRNGKey(5))
+            mesh, config, P, pid, pk, None, np.zeros(0, np.float32),
+            keep_table, thr, s_scale, min_count, 1.0,
+            jax.random.PRNGKey(5))
         assert {s.data.shape for s in out["count"].addressable_shards
                 } == {(P // 8,)}
         counts = np.asarray(out["count"])
         expected = np.bincount(pk, minlength=P)
         np.testing.assert_array_equal(counts, expected)
+
+    def test_reshard_fallback_is_bit_identical(self, monkeypatch):
+        # The host re-shard runs as a native counting scatter, or as
+        # numpy's radix grouping where the library cannot load: the
+        # same inputs give the same selection and accumulators both
+        # ways, and the ledger counts which path ran.
+        import jax
+        from pipelinedp_tpu import jax_engine as je
+        from pipelinedp_tpu import native, obs
+        from pipelinedp_tpu.parallel import sharded_fused_aggregate
+
+        mesh = self._mesh()
+        P = 1 << 10
+        rng = np.random.default_rng(4)
+        n = 20_000
+        pid = rng.integers(0, 3000, n).astype(np.int32)
+        pk = rng.integers(0, P, n).astype(np.int32)
+        values = rng.uniform(0, 5, n).astype(np.float32)
+        config = je.FusedConfig.from_params(
+            pdp.AggregateParams(metrics=[pdp.Metrics.COUNT, pdp.Metrics.SUM],
+                                max_partitions_contributed=4,
+                                max_contributions_per_partition=2,
+                                min_value=0.0, max_value=5.0),
+            public=False)
+        keep_table, thr, s_scale, min_count = je.selection_inputs(
+            config, 3.0, 1e-6, None)
+
+        def release():
+            keep, out = sharded_fused_aggregate(
+                mesh, config, P, pid, pk, values, np.zeros(0, np.float32),
+                keep_table, thr, s_scale, min_count, 1.0,
+                jax.random.PRNGKey(9))
+            return np.asarray(keep), {k: np.asarray(v)
+                                      for k, v in out.items()}
+
+        def counters():
+            c = obs.ledger().snapshot()["counters"]
+            return c.get("reshard.native", 0), c.get("reshard.fallback", 0)
+
+        def unavailable():
+            raise native.NativeUnavailableError("no toolchain")
+
+        # Without g++ the first release falls back too.
+        try:
+            native._shard_lib()
+            first = (1, 0)
+        except native.NativeUnavailableError:
+            first = (0, 1)
+        n0, f0 = counters()
+        keep_first, out_first = release()
+        assert counters() == (n0 + first[0], f0 + first[1])
+        monkeypatch.setattr(native, "_shard_lib", unavailable)
+        keep_numpy, out_numpy = release()
+        assert counters() == (n0 + first[0], f0 + first[1] + 1)
+        assert 0 < keep_first.sum() < P
+        np.testing.assert_array_equal(keep_first, keep_numpy)
+        assert out_first.keys() == out_numpy.keys()
+        for k in out_first:
+            np.testing.assert_array_equal(out_first[k], out_numpy[k])
 
 
 class TestFixedPointAccumulation:

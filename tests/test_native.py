@@ -365,3 +365,130 @@ class TestFactorize:
             np.testing.assert_array_equal(uniq, exp_u)
             np.testing.assert_array_equal(inv, exp_i)
             assert inv.dtype == np.int32
+
+
+def _shard_available():
+    try:
+        native._shard_lib()
+        return True
+    except native.NativeUnavailableError:
+        return False
+
+
+def _shard_rows_reference(pid, columns, n_dev):
+    """What the mesh re-shard did before the counting scatter: hash,
+    stable argsort of the shard ids, then per-shard padded copies."""
+    from pipelinedp_tpu.jax_engine import _pad_rows
+    from pipelinedp_tpu.ops.segment import fmix32
+    shard = (fmix32(pid.astype(np.uint32)) % np.uint32(n_dev)).astype(
+        np.int32)
+    order = np.argsort(shard, kind="stable")
+    counts = np.bincount(shard, minlength=n_dev)
+    per_shard = _pad_rows(int(counts.max()) if len(pid) else 1)
+    out = []
+    for col in columns:
+        fill = False if col.dtype == bool else 0
+        dst = np.full((n_dev * per_shard,) + col.shape[1:], fill, col.dtype)
+        offset = 0
+        for d in range(n_dev):
+            rows = order[offset:offset + counts[d]]
+            dst[d * per_shard:d * per_shard + counts[d]] = col[rows]
+            offset += counts[d]
+        out.append(dst)
+    return out, counts, per_shard
+
+
+@pytest.mark.skipif(not _shard_available(),
+                    reason="native toolchain unavailable")
+class TestShardRows:
+    """The native counting scatter of the mesh re-shard must be
+    bit-identical to a stable argsort of the shard ids followed by
+    per-shard padded copies, pads and counts included."""
+
+    @staticmethod
+    def _columns(pid, rng):
+        n = len(pid)
+        return [pid,
+                rng.integers(0, 17770, n).astype(np.int32),
+                rng.random(n).astype(np.float32),
+                rng.random(n) < 0.7,
+                rng.random((n, 3)).astype(np.float32)]
+
+    def _check(self, pid, n_dev, rng):
+        from pipelinedp_tpu.jax_engine import _pad_rows
+        columns = self._columns(pid, rng)
+        got, counts, per_shard = native.shard_rows(pid, columns, n_dev,
+                                                   _pad_rows)
+        want, want_counts, want_per_shard = _shard_rows_reference(
+            pid, columns, n_dev)
+        assert per_shard == want_per_shard
+        np.testing.assert_array_equal(counts, want_counts)
+        assert counts.sum() == len(pid)
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            np.testing.assert_array_equal(g, w)
+        for g in got:  # the pad tail of every shard is zero / False
+            for d in range(n_dev):
+                tail = g[d * per_shard + counts[d]:(d + 1) * per_shard]
+                assert not tail.any()
+        return counts
+
+    @pytest.mark.parametrize("n", [0, 1, 8191, 8192, 8193, 100_003])
+    @pytest.mark.parametrize("n_dev", [1, 2, 4, 8])
+    def test_matches_stable_argsort(self, n_dev, n):
+        rng = np.random.default_rng(n * 10 + n_dev)
+        pid = rng.integers(0, 480_189, n).astype(np.int32)
+        self._check(pid, n_dev, rng)
+
+    @pytest.mark.parametrize("n_dev", [2, 4, 8])
+    def test_one_pid_fills_one_shard(self, n_dev):
+        rng = np.random.default_rng(n_dev)
+        pid = np.full(20_000, 12345, np.int32)
+        counts = self._check(pid, n_dev, rng)
+        assert sorted(counts.tolist())[-1] == 20_000
+
+    @pytest.mark.parametrize("n_dev", [2, 4, 8])
+    def test_shared_residue_class_spreads(self, n_dev):
+        # Ids that are all multiples of n_dev still spread: the hash
+        # runs before the modulo.
+        rng = np.random.default_rng(7)
+        pid = (rng.integers(0, 100_000, 50_000) * n_dev).astype(np.int32)
+        counts = self._check(pid, n_dev, rng)
+        assert (counts > 0).all()
+
+    def test_negative_and_wide_pids(self):
+        # Negative int32 ids read as their uint32 bits; int64 ids wrap
+        # to uint32, as the reference's astype does.
+        rng = np.random.default_rng(11)
+        self._check(rng.integers(-2**31, 2**31 - 1, 30_000,
+                                 dtype=np.int64).astype(np.int32), 4, rng)
+        self._check(rng.integers(0, 2**40, 30_000), 4, rng)
+
+    def test_more_than_256_shards_refused(self):
+        from pipelinedp_tpu.jax_engine import _pad_rows
+        pid = np.arange(10, dtype=np.int32)
+        with pytest.raises(native.NativeUnavailableError, match="256"):
+            native.shard_rows(pid, [pid], 257, _pad_rows)
+
+
+@pytest.mark.parametrize("n_dev", [1, 4, 257])
+def test_reshard_numpy_fallback_matches(n_dev, monkeypatch):
+    # The re-shard's numpy path (library unavailable, or more shards
+    # than a byte holds) gives the same arrays. It needs no toolchain.
+    from pipelinedp_tpu.parallel.sharded import reshard_rows
+
+    def unavailable():
+        raise native.NativeUnavailableError("no toolchain")
+
+    rng = np.random.default_rng(n_dev)
+    pid = rng.integers(0, 480_189, 40_000).astype(np.int32)
+    columns = TestShardRows._columns(pid, rng)
+    want, want_counts, want_per_shard = _shard_rows_reference(
+        pid, columns, n_dev)
+    monkeypatch.setattr(native, "_shard_lib", unavailable)
+    got, counts, per_shard = reshard_rows(pid, columns, n_dev)
+    assert per_shard == want_per_shard
+    np.testing.assert_array_equal(counts, want_counts)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(g, w)
