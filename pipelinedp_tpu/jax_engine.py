@@ -1222,6 +1222,7 @@ def _fold_fixedpoint(config: FusedConfig, part64, fx_bits: int) -> None:
             fx_bits) / _vector_fx_scale(config)
 
 
+@scoped("walk")
 def _qrows(config: FusedConfig, pk, values, kept):
     """Percentile row view: (pk, leaf index, kept mask) per row, in
     whatever row order the caller is in. The leaf mapping mirrors the host
@@ -1336,11 +1337,14 @@ def _selection_and_metrics(config: FusedConfig, num_partitions: int, part,
     if config.percentiles:
         # Percentile noise scale is the last _noise_scales entry; the tree
         # key is independent of the selection key stream.
-        # lint: disable=rng-purity(tree key: constant fold of the noise stream)
-        k_tree = jax.random.fold_in(k_noise, 0x7ee)
+        with scope("walk"):
+            # lint: disable=rng-purity(tree key: constant fold of the noise stream)
+            k_tree = jax.random.fold_in(k_noise, 0x7ee)
         if pk_axis is None:
-            vals = _percentile_values(config, P, qrows, noise_scales[-1],
-                                      k_tree)
+            vals, walk_stats = _percentile_values(config, P, qrows,
+                                                  noise_scales[-1], k_tree)
+            if walk_stats is not None:
+                out[WALK_STATS] = walk_stats
         else:
             vals = _percentile_values_owned(config, P, qrows,
                                             noise_scales[-1], k_tree,
@@ -1418,10 +1422,20 @@ _VECTOR_ACCUMULATOR = "f32"
 # kernel launches, and re-streaming is its only completion path.)
 _MAX_WALK_BLOCKS = 16
 
+#: The fused program's output (int32 [2]) that says how the bottom
+#: walk's compaction went: the rows that fell in some chosen subtree,
+#: and how many subtree blocks overflowed the compaction's static
+#: prefix and took the full-row scatters. Not a partition column: the
+#: release fetches it in the compact fetch's meta row.
+WALK_STATS = "walk_stats"
 
+
+@scoped("walk")
 def _percentile_values(config: FusedConfig, P, qrows, scale, key):
     """Batched DP quantile-tree descent over every partition at once
     (single-chip; the sharded twin is ``_percentile_values_owned``).
+    Returns (values [P, Q], the ``WALK_STATS`` vector, or None on the
+    paths that do not compact).
 
     Level l needs, per (partition, quantile), the noisy counts of the
     ``b`` children of the walk's current node. Rather than materializing
@@ -1475,6 +1489,7 @@ def _percentile_values(config: FusedConfig, P, qrows, scale, key):
     leaf_lo = jnp.zeros((P, Q), jnp.int32)
     done = jnp.zeros((P, Q), bool)
     level_offset = 0
+    stats = None
     # Top levels: served by the mid histogram (node width >= bucket_w —
     # levels 0 and 1 for any height >= 2).
     n_top = min(2, height) if hist is not None else 0
@@ -1511,8 +1526,8 @@ def _percentile_values(config: FusedConfig, P, qrows, scale, key):
             obs.event("walk.path", path="subhist", scope="compile",
                       P=int(P), Q=int(Q), span=int(span))
             sub_start = leaf_lo  # [P, Q] first leaf of each subtree
-            sub_hist = _build_sub_hist(qpk, leaf, kept, sub_start, P, Q,
-                                       span, b, height)
+            sub_hist, stats = _build_sub_hist(qpk, leaf, kept, sub_start,
+                                              P, Q, span, b, height)
             for level in range(n_top, height):
                 w = b**(height - 1 - level)
                 raw = _sub_level_counts(sub_hist, sub_start, leaf_lo, w, b)
@@ -1540,7 +1555,7 @@ def _percentile_values(config: FusedConfig, P, qrows, scale, key):
                 # passed via ``pk_index`` — and the per-partition
                 # histogram content is unchanged, so the chunked walk
                 # is bit-identical to the unchunked one.
-                outs = []
+                outs, stats = [], None
                 for p0 in range(0, P, blk):
                     Pb = min(blk, P - p0)
                     psl = slice(p0, p0 + Pb)
@@ -1548,8 +1563,11 @@ def _percentile_values(config: FusedConfig, P, qrows, scale, key):
                     rel_pk = qpk - p0
                     kept_b = kept & (rel_pk >= 0) & (rel_pk < Pb)
                     pk_b = jnp.clip(rel_pk, 0, Pb - 1)
-                    sub = _build_sub_hist(pk_b, leaf, kept_b, ss, Pb,
-                                          Q, span, b, height)
+                    sub, blk_stats = _build_sub_hist(pk_b, leaf, kept_b, ss,
+                                                     Pb, Q, span, b, height)
+                    if blk_stats is not None:
+                        stats = (blk_stats if stats is None
+                                 else stats + blk_stats)
                     lo_b, hi_b, tg_b = lo[psl], hi[psl], target[psl]
                     ll_b, dn_b = leaf_lo[psl], done[psl]
                     pk_idx = (p0 + jnp.arange(Pb)).astype(jnp.uint32)
@@ -1564,7 +1582,7 @@ def _percentile_values(config: FusedConfig, P, qrows, scale, key):
                         lvo += b**(level + 1)
                     outs.append(lo_b + (hi_b - lo_b) * tg_b)
                 return _monotone_in_q(jnp.concatenate(outs, axis=0),
-                                      quantiles)
+                                      quantiles), stats
             # Past _MAX_WALK_BLOCKS (or a cap below one partition's
             # [1, Q, span] block — necessarily test-shrunken):
             # per-level per-quantile row scatters, the rows being
@@ -1581,7 +1599,7 @@ def _percentile_values(config: FusedConfig, P, qrows, scale, key):
                     b, w)
                 level_offset += b**(level + 1)
     vals = lo + (hi - lo) * target  # [P, Q]
-    return _monotone_in_q(vals, quantiles)
+    return _monotone_in_q(vals, quantiles), stats
 
 
 def _build_sub_hist(qpk, leaf, kept, sub_start, P, Q, span, b, height):
@@ -1590,7 +1608,12 @@ def _build_sub_hist(qpk, leaf, kept, sub_start, P, Q, span, b, height):
     subtrees jointly cover ~Q/n_blocks of the leaf space, so typically
     ~1% of rows land in ANY sub-histogram — compact the relevant rows
     to a static n/8 prefix first so the per-quantile scatters scan 8x
-    fewer rows."""
+    fewer rows. Values on a coarse grid break that: every row of a
+    grade shares one leaf, so a subtree holds whole grades (a quarter
+    of the rows of half-star ratings) and the full-row scatters run.
+
+    Returns (histograms, ``WALK_STATS``: [relevant rows, 1 where they
+    overflowed the prefix]), the stats None where nothing compacts."""
     n_blocks = (b**height) // span
     # The descent so far only added multiples of widths >= span, so
     # every walk's subtree start is span-ALIGNED: membership is "the
@@ -1671,13 +1694,15 @@ def _build_sub_hist(qpk, leaf, kept, sub_start, P, Q, span, b, height):
         def full(_):
             return subs_over(qpk, mid, lo_bits, kept)
 
-        return jax.lax.cond(n_rel <= cap, compacted, full, None)
+        fits = n_rel <= cap
+        return (jax.lax.cond(fits, compacted, full, None),
+                jnp.stack([n_rel, 1 - fits.astype(jnp.int32)]))
     if n_blocks <= 256:
         # Exotic tree shapes whose packed row would overflow int32: no
         # compaction, full-row scatters.
-        return subs_over(qpk, mid, lo_bits, kept)
+        return subs_over(qpk, mid, lo_bits, kept), None
     # Non-default tree shapes: block ids > 8 bits.
-    return _subtree_counts(qpk, leaf, kept, sub_start, P, span)
+    return _subtree_counts(qpk, leaf, kept, sub_start, P, span), None
 
 
 def _mid_level_counts(mid, base, w, bucket_w, b):
@@ -1733,6 +1758,7 @@ def _walk_level(noise_kind, key, scale, raw, base, level_offset, lo, hi,
     return _walk_step(noisy, lo, hi, target, leaf_lo, done, b, w)
 
 
+@scoped("walk")
 def _subtree_counts(qpk, leaf, kept, sub_start, P, span, p_offset=None):
     """Leaf counts of each quantile's chosen subtree from row data:
     [P, Q, span] int32 (one masked scatter per quantile). Shared by the
@@ -1758,6 +1784,7 @@ def _subtree_counts(qpk, leaf, kept, sub_start, P, span, p_offset=None):
     return jnp.stack(subs, axis=1)
 
 
+@scoped("walk")
 def _subtree_counts_multi(qpk, leaf, kept, sub_starts, p_offsets, Pb,
                           span, kernel_backend: str = "xla"):
     """Several tiles' subtree-leaf counts from ONE pass over the rows:
@@ -1827,6 +1854,7 @@ def _monotone_in_q(vals, quantiles):
     return mono[:, np.argsort(order)]
 
 
+@scoped("walk")
 def _percentile_values_owned(config: FusedConfig, P_own, qrows, scale,
                              key, axis, n_dev, topo=None):
     """The quantile descent with the partition axis SHARDED over the
@@ -1866,8 +1894,9 @@ def _percentile_values_owned(config: FusedConfig, P_own, qrows, scale,
     for level in range(height):
         w = b**(height - 1 - level)
         base_own = leaf_lo // w  # [P_own, Q]
-        base = psh.gather_blocks(base_own, axis, dim=0,
-                                 topo=topo)  # [P, Q]
+        with scope("combine"):
+            base = psh.gather_blocks(base_own, axis, dim=0,
+                                     topo=topo)  # [P, Q]
         counts = []
         for q in range(Q):
             slot = leaf // w - base[:, q][qpk]
@@ -1876,9 +1905,10 @@ def _percentile_values_owned(config: FusedConfig, P_own, qrows, scale,
             counts.append(
                 jax.ops.segment_sum(ok.astype(jnp.int32), seg,
                                     num_segments=P * b).reshape(P, b))
-        raw = psh.scatter_to_owner(jnp.stack(counts, axis=1), axis,
-                                   dim=0,
-                                   topo=topo).astype(jnp.float32)
+        stacked = jnp.stack(counts, axis=1)
+        with scope("combine"):
+            raw = psh.scatter_to_owner(stacked, axis, dim=0, topo=topo)
+        raw = raw.astype(jnp.float32)
         lo, hi, target, leaf_lo, done = _walk_level(
             config.noise_kind, key, scale, raw, base_own, level_offset,
             lo, hi, target, leaf_lo, done, b, w, pk_index=pk_index)
@@ -2174,17 +2204,23 @@ _COMPACT_FETCH_CAP = 8192
 @instrumented_jit(phase="fetch", static_argnames=("num_partitions",
                                                   "cap"))
 @scoped("compact")
-def _compact_fetch_kernel(keep_pk, cols, num_partitions, cap):
+def _compact_fetch_kernel(keep_pk, cols, num_partitions, cap, stats=None):
     """Device-side output compaction: stable-sorts kept partitions first
     (ascending pk index), gathers the first ``cap`` of every column and
     packs [meta; kept indices; columns...] into one int32 block — the
     kept count, the index map and all metric columns cross the
-    high-latency host link in a single transfer."""
+    high-latency host link in a single transfer. ``stats`` (int32 [k],
+    the walk's ``WALK_STATS``) ride in the meta row after the kept
+    count, the block widened to 1 + k where ``cap`` is narrower."""
     keep = keep_pk[:num_partitions].astype(jnp.int32)
     order = jnp.argsort(1 - keep, stable=True)
     sel = order[:cap]
+    if stats is not None and cap < 1 + stats.shape[0]:
+        sel = jnp.pad(sel, (0, 1 + stats.shape[0] - cap))
     width = sel.shape[0]
     meta = jnp.zeros(width, jnp.int32).at[0].set(jnp.sum(keep))
+    if stats is not None:
+        meta = meta.at[1:1 + stats.shape[0]].set(stats)
     gathered = [c[:num_partitions][sel] for c in cols]
     return jnp.stack([meta, sel.astype(jnp.int32)] + gathered)
 
@@ -2281,6 +2317,19 @@ def _audit_expected_errors(config: FusedConfig, specs, metric_arrays,
             obs.audit.record_metric_error(rec)
     except Exception:
         pass  # an error estimate must never take the release down
+
+
+def _record_walk_stats(timings, stats) -> None:
+    """One release's ``WALK_STATS`` (host copy): the ``obs`` counter
+    ``walk.compacted`` or ``walk.full_scatter``, and
+    ``timings["walk_full_scatter"]`` (0 or 1: whether any subtree block
+    took the full-row scatters) and ``timings["walk_relevant_rows"]``."""
+    from pipelinedp_tpu import obs
+    relevant, overflowed = (int(x) for x in stats)
+    full = int(overflowed > 0)
+    obs.inc("walk.full_scatter" if full else "walk.compacted")
+    timings["walk_full_scatter"] = full
+    timings["walk_relevant_rows"] = relevant
 
 
 def _maybe_append_run_ledger(name: str = "engine.aggregate",
@@ -2557,6 +2606,7 @@ class LazyFusedResult:
                 # whereas small ints bitcast to float32 become subnormals
                 # that TPUs flush to zero (and a float32 CAST would corrupt
                 # counts above 2^24).
+                walk_stats = raw.pop(WALK_STATS, None)
                 flat = sorted(k for k, v in raw.items() if v.ndim == 1)
                 cols = []
                 for name in flat:
@@ -2577,8 +2627,10 @@ class LazyFusedResult:
                 if self._public is None:
                     cap = min(P, _COMPACT_FETCH_CAP)
                     packed = np.asarray(_compact_fetch_kernel(
-                        keep_pk, tuple(cols), P, cap))
+                        keep_pk, tuple(cols), P, cap, walk_stats))
                     n_keep = int(packed[0, 0])
+                    if walk_stats is not None:
+                        walk_stats = packed[0, 1:1 + walk_stats.shape[0]]
                     if n_keep <= cap:
                         stacked = packed[1:, :n_keep]
                     else:
@@ -2592,9 +2644,17 @@ class LazyFusedResult:
                     vocab_idx = kept_idx
                     kept_order = jnp.asarray(kept_idx)  # rank-2 gathers
                 else:
-                    stacked = np.asarray(
-                        jnp.stack([keep_pk.astype(jnp.int32)] +
-                                  cols))[:, :P]
+                    lines = [keep_pk.astype(jnp.int32)] + cols
+                    if walk_stats is not None:
+                        # A last row, as wide as the padded axis (at
+                        # least 8), carries the stats in the same fetch.
+                        lines.append(jnp.zeros_like(lines[0]).at[
+                            :walk_stats.shape[0]].set(walk_stats))
+                    block = np.asarray(jnp.stack(lines))
+                    if walk_stats is not None:
+                        walk_stats = block[-1, :walk_stats.shape[0]]
+                        block = block[:-1]
+                    stacked = block[:, :P]
                     kept_idx = np.flatnonzero(stacked[0] > 0)
                     rel_sel = vocab_idx = np.arange(P)
                     kept_order = None
@@ -2613,6 +2673,8 @@ class LazyFusedResult:
         self.timings["fetch_s"] = tr.total("engine.fetch")
         if self._mesh is not None:
             self.timings["reshard_s"] = tr.total("engine.reshard")
+        if walk_stats is not None:
+            _record_walk_stats(self.timings, walk_stats)
         if config.selection is not None:
             # The selection seam: every vocab entry is a populated
             # partition, so P is the pre-selection count and the kept
@@ -2737,6 +2799,10 @@ class LazyFusedResult:
         it without dispatching a solo program)."""
         config = self._config
         P = prep.P
+        raw_np = dict(raw_np)
+        walk_stats = raw_np.pop(WALK_STATS, None)
+        if walk_stats is not None and self.timings is not None:
+            _record_walk_stats(self.timings, np.asarray(walk_stats))
         keep = np.asarray(keep_np)[:P]
         kept_idx = np.flatnonzero(keep > 0)
         if self._public is not None:

@@ -35,7 +35,8 @@ This package is the one place that knowledge accumulates:
   span records in the ledger and the Chrome-trace export.
 * :data:`SCOPES` / :func:`program_scopes` — the named phases of the
   fused aggregation programs (``jax.named_scope``: bounding sort,
-  permutation, caps, segment sum, selection, noise, combine, compact)
+  permutation, caps, segment sum, selection, noise, combine, compact,
+  the percentile walk)
   and, on request, the table from each compiled program's HLO
   instruction names to those phases, which turns a profiler trace's
   device operations into per-phase device time.

@@ -82,10 +82,11 @@ ENV_VAR = "PIPELINEDP_TPU_COSTS"
 #: ``jax.named_scope`` around the code that does it: the bounding sort
 #: (and the total-cap sort), the gathers by its order, the per-row
 #: rank/ordinal/clip masks, the per-partition segment sum, partition
-#: selection, on-device noise draws, the cross-chip combine, and the
-#: compact fetch's output compaction.
+#: selection, on-device noise draws, the cross-chip combine, the
+#: compact fetch's output compaction, and the percentile walk's
+#: counting (leaf mapping, histograms, the descent).
 SCOPES = ("bound_sort", "bound_permute", "bound_caps", "segsum", "select",
-          "noise", "combine", "compact")
+          "noise", "combine", "compact", "walk")
 
 
 def scope(name: str):
@@ -699,6 +700,7 @@ _HLO_OPERAND = re.compile(r"%([\w.\-]+)")
 _HLO_COLLECTIVE = re.compile(
     r"\s(?:all-reduce|reduce-scatter|all-gather|all-to-all|"
     r"collective-permute)(?:-start|-done)?\(")
+_HLO_CONTROL = re.compile(r"\s(?:conditional|while)\(")
 
 
 def _op_scope(op_name: str) -> Optional[str]:
@@ -721,12 +723,15 @@ def hlo_scope_table(hlo_text: str) -> Tuple[str, Dict[str, str]]:
     combine's collectives into one and drops their metadata); any other
     instruction without one (a layout copy, a scan XLA emits without
     metadata) takes its first scoped operand's. Instructions under no
-    declared scope are left out."""
+    declared scope are left out, and so are ``conditional`` and
+    ``while``: on a trace their interval encloses the operations of
+    their branches and body, which are mapped themselves, so mapping
+    both would count those seconds twice."""
     m = _HLO_MODULE.search(hlo_text)
     module = m.group(1) if m else ""
     comps: Dict[str, List[Tuple[str, Optional[str], Optional[str],
                                 bool, bool, List[str]]]] = {}
-    inner = set()
+    inner, control = set(), set()
     current: Optional[List] = None
     for line in hlo_text.splitlines():
         if not line.startswith(" "):
@@ -736,6 +741,8 @@ def hlo_scope_table(hlo_text: str) -> Tuple[str, Dict[str, str]]:
         i = _HLO_INSTRUCTION.match(line)
         if current is None or i is None:
             continue
+        if _HLO_CONTROL.search(line):
+            control.add(i.group(2))
         op = _HLO_OP_NAME.search(line)
         calls = _HLO_CALLS.search(line)
         inner.update(x.group(1) for x in (calls, _HLO_APPLIES.search(line))
@@ -764,7 +771,7 @@ def hlo_scope_table(hlo_text: str) -> Tuple[str, Dict[str, str]]:
             got = (own or (comp_scope(calls) if calls else None) or
                    ("combine" if collective else None) or
                    next((table[o] for o in operands if o in table), None))
-            if got:
+            if got and instr not in control:
                 table[instr] = got
     return module, table
 

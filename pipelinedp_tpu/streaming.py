@@ -75,7 +75,7 @@ import jax.numpy as jnp
 
 from pipelinedp_tpu import jax_engine as je
 from pipelinedp_tpu import obs
-from pipelinedp_tpu.obs.costs import instrumented_jit
+from pipelinedp_tpu.obs.costs import instrumented_jit, scoped
 from pipelinedp_tpu.ops.segment import fmix32
 
 #: Rows per device batch (and the engine's streaming trigger: pipelines
@@ -213,6 +213,7 @@ def _pack_rank1(part, nseg):
     return jnp.stack([part[k] for k in names] + [nseg]), vec
 
 
+@scoped("walk")
 def _mid_histogram(config, num_partitions, qrows):
     """The chunk's [P * n_mid] mid-level quantile-tree histogram
     (additive across chunks and shards)."""
@@ -539,6 +540,7 @@ def _sharded_pct_multi_sub_kernel(config, num_partitions, mesh, planes,
 
 
 @instrumented_jit(phase="walk", static_argnames=("config", "P"))
+@scoped("walk")
 def _walk_top_kernel(config, P, mid, key, scale):
     """Walk the levels the mid histogram serves (node width >= bucket_w)
     — the streaming twin of ``jax_engine._percentile_values``' top-
@@ -570,6 +572,7 @@ def _walk_top_kernel(config, P, mid, key, scale):
 
 
 @instrumented_jit(phase="walk", static_argnames=("config", "P"))
+@scoped("walk")
 def _walk_bottom_kernel(config, P, sub, sub_start, lo, hi, target,
                         leaf_lo, done, key, scale, p_offset):
     """Finish the walk from the accumulated [P, Qc, span] subtree leaf

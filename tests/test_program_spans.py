@@ -7,7 +7,8 @@
 * ``LazyFusedResult.timings`` splits ``device_s`` into ``h2d_s``,
   ``fetch_s`` and, on a mesh, ``reshard_s``, whose sum stays inside it;
 * ``obs.program_scopes()`` maps the fused programs' HLO instructions to
-  every declared scope, from signatures that keep no array;
+  every declared scope, from signatures that keep no array; the
+  percentile walk's instructions map to ``walk`` or ``noise``;
 * the scopes and spans leave the released rows bit-identical: the
   digests below are those of the same releases before either existed.
 """
@@ -173,16 +174,19 @@ class TestProgramScopes:
 
     @pytest.mark.parametrize("where,program,expected", [
         ("one", "jit_fused_aggregate_kernel",
-         set(obs.SCOPES) - {"combine", "compact"}),
-        ("mesh", "jit__sharded_kernel", set(obs.SCOPES) - {"compact"}),
+         set(obs.SCOPES) - {"combine", "compact", "walk"}),
+        ("mesh", "jit__sharded_kernel",
+         set(obs.SCOPES) - {"compact", "walk"}),
     ])
     def test_every_declared_scope_is_mapped(self, where, program,
                                             expected):
         _release(_mesh(where))
         tables = obs.program_scopes()
-        # Other tests' signatures of the program key by fingerprint.
+        # Other tests' signatures of the program key by fingerprint; the
+        # percentile programs among them are the ones that map ``walk``.
         mapped = set().union(*(t.values() for name, t in tables.items()
-                               if name.split("(")[0] == program))
+                               if name.split("(")[0] == program
+                               and "walk" not in t.values()))
         assert mapped == expected
         compact = [t for name, t in tables.items()
                    if name.startswith("jit__compact_fetch_kernel")]
@@ -270,6 +274,105 @@ class TestProgramScopes:
         assert fn.lower(*args, **kwargs).compile() is not None
 
 
+def _pct_release():
+    """A small seeded PERCENTILE(50, 90, 99) and VARIANCE release with
+    private selection; ratings on the half-star grid."""
+    rng = np.random.default_rng(8)
+    n = 20_000
+    ds = pdp.ArrayDataset(privacy_ids=np.arange(n),
+                          partition_keys=rng.integers(0, 40, n),
+                          values=0.5 * rng.integers(1, 11, n))
+    params = pdp.AggregateParams(
+        metrics=[pdp.Metrics.COUNT, pdp.Metrics.SUM, pdp.Metrics.MEAN,
+                 pdp.Metrics.VARIANCE, pdp.Metrics.PERCENTILE(50),
+                 pdp.Metrics.PERCENTILE(90), pdp.Metrics.PERCENTILE(99)],
+        max_partitions_contributed=1, max_contributions_per_partition=1,
+        min_value=0.5, max_value=5.0)
+    acc = pdp.NaiveBudgetAccountant(total_epsilon=1e9, total_delta=1e-6)
+    res = pdp.DPEngine(acc, JaxBackend(rng_seed=5)).aggregate(
+        ds, params, pdp.DataExtractors())
+    acc.compute_budgets()
+    return sorted(res), params
+
+
+def _unnamed(hlo_text):
+    """(scope table, op_names of the instructions it leaves out that
+    carry metadata, parameters and control flow aside): what a trace
+    would show under no scope."""
+    _, table = costs.hlo_scope_table(hlo_text)
+    inner, names, current = set(), set(), None
+    bodies = {}
+    for line in hlo_text.splitlines():
+        if not line.startswith(" "):
+            c = costs._HLO_COMPUTATION.match(line)
+            current = bodies.setdefault(c.group(1), []) if c else None
+            continue
+        inner.update(x.group(1) for x in (costs._HLO_CALLS.search(line),
+                                          costs._HLO_APPLIES.search(line))
+                     if x)
+        i = costs._HLO_INSTRUCTION.match(line)
+        if current is not None and i:
+            current.append((i.group(2), line))
+    for comp, body in bodies.items():
+        if comp in inner:
+            continue
+        for instr, line in body:
+            op = costs._HLO_OP_NAME.search(line)
+            if (instr not in table and op and " parameter(" not in line
+                    and not costs._HLO_CONTROL.search(line)):
+                names.add(op.group(1).split("/", 1)[-1])
+    return table, names
+
+
+class TestWalkScope:
+    """The percentile walk's counting under its own scope."""
+
+    def test_walk_is_a_declared_scope(self):
+        assert "walk" in obs.SCOPES
+        with costs.scope("walk"):
+            pass
+
+    def test_walk_alone_maps_to_walk_or_noise(self):
+        """Leaf mapping, histograms, the compaction and both branches of
+        its fallback, the descent and the monotone pass: every
+        instruction with metadata is ``walk``, or ``noise`` for the node
+        draws (the innermost scope wins)."""
+        _, params = _pct_release()
+        config = jax_engine.FusedConfig.from_params(params, public=False)
+
+        def walk(pk, values, kept, key):
+            qrows = jax_engine._qrows(config, pk, values, kept)
+            return jax_engine._percentile_values(config, 64, qrows,
+                                                 jnp.float32(0.1), key)
+
+        n = 16_384
+        text = jax.jit(walk).lower(
+            jnp.zeros(n, jnp.int32), jnp.ones(n, jnp.float32),
+            jnp.ones(n, bool), jax.random.PRNGKey(0)).compile().as_text()
+        assert " conditional(" in text  # the compaction's fallback
+        table, unnamed = _unnamed(text)
+        assert set(table.values()) == {"walk", "noise"}
+        assert unnamed == set()
+
+    def test_percentile_program_adds_nothing_unnamed(self):
+        """The fused percentile program maps every declared phase it
+        runs, the walk included, and leaves out nothing the sum program
+        does not (the root key split)."""
+        _release(None)
+        _pct_release()
+        fn = jax_engine.fused_aggregate_kernel
+        found = {}
+        for args, kwargs in fn._signatures.values():
+            config = next(a for a in args
+                          if isinstance(a, jax_engine.FusedConfig))
+            text = fn._jitted.lower(*args, **kwargs).compile().as_text()
+            found[bool(config.percentiles)] = _unnamed(text)
+        (pct_table, pct_unnamed), (_, sum_unnamed) = found[True], found[False]
+        assert set(pct_table.values()) == (
+            set(obs.SCOPES) - {"combine", "compact"})
+        assert pct_unnamed <= sum_unnamed
+
+
 class TestHloScopeTable:
     """The parser on a compiled program of known structure."""
 
@@ -291,6 +394,24 @@ class TestHloScopeTable:
         assert "bound_caps" not in scopes
         sort_ops = [n for n, s in table.items() if n.startswith("sort")]
         assert sort_ops and all(table[n] == "bound_sort" for n in sort_ops)
+
+    def test_control_flow_is_left_to_its_branches(self):
+        """A conditional's interval on a trace encloses its branch's
+        operations: the branches' instructions carry the scope, the
+        conditional none, so no second is counted twice."""
+        def f(x, n):
+            with costs.scope("walk"):
+                return jax.lax.cond(n > 3, lambda y: jnp.sort(y) * 2.0,
+                                    lambda y: jnp.cumsum(y) + 1.0, x)
+
+        text = jax.jit(f).lower(jnp.ones(64), 5).compile().as_text()
+        _, table = costs.hlo_scope_table(text)
+        cond = [ln for ln in text.splitlines() if " conditional(" in ln]
+        assert len(cond) == 1
+        name = costs._HLO_INSTRUCTION.match(cond[0]).group(2)
+        assert name not in table
+        sorts = [n for n in table if n.startswith("sort")]
+        assert sorts and all(table[n] == "walk" for n in sorts)
 
     def test_unknown_scope_name_is_refused(self):
         with pytest.raises(ValueError):
