@@ -6,7 +6,8 @@ extract → bound contributions → combine per key → select partitions →
 noise) compiled into ONE XLA program over integer-encoded arrays:
 
     host:   extract + integer-encode (pid, pk, value); calibrate noise
-    device: lexsort by (pid, pk, rand)            [shuffle 1+2 fused]
+    device: one sort by (pid, hash(pid, pk), rand), carrying pk and the
+            values as payload                     [shuffle 1+2 fused]
             → segment boundaries per (pid, pk)
             → linf bound  = rank-in-segment < max_contributions_per_partition
             → per-segment accumulators (segment_sum)    [create_accumulator]
@@ -756,7 +757,9 @@ def _partials(config: FusedConfig, num_partitions: int, pid, pk, values,
     costs ~10x an elementwise op, so the kernel sorts ONCE by
     (pid, hash(pid, pk, salt), random) — pk itself is not a key: for a
     fixed pid the hash is injective in pk, so segments are contiguous
-    already — and then derives every per-segment quantity in row space
+    already — with pk and rank-1 values riding the sort as payload (no
+    gather applies its order), and then derives every per-segment
+    quantity in row space
     with cumsum/cummax (runs are contiguous after the sort). The hash key
     makes the within-pid segment
     order a fresh uniform permutation per run and per pid, so "ordinal
@@ -764,7 +767,7 @@ def _partials(config: FusedConfig, num_partitions: int, pid, pk, values,
     mode no second sort and no per-segment scatter are needed; the only
     scatters are the final per-pk reductions (and, for per-partition
     -bound sums, one per-segment total). Total-cap mode
-    (``max_contributions``) pays one extra lexsort + row-space scatter
+    (``max_contributions``) pays one extra sort + row-space scatter
     for its uniform per-pid row sample — see the branch below."""
     n = pid.shape[0]
     P = num_partitions
@@ -793,6 +796,7 @@ def _partials(config: FusedConfig, num_partitions: int, pid, pk, values,
         part_nseg = part["count"]
         return part, part_nseg, qrows
 
+    from pipelinedp_tpu import obs
     from pipelinedp_tpu.ops import counter_rng
 
     # Blessed seam: tie-break/salt/sample bits for contribution
@@ -823,13 +827,33 @@ def _partials(config: FusedConfig, num_partitions: int, pid, pk, values,
         # constant), so (pid, hpk) already identifies the (pid, pk)
         # segment — pk itself is redundant as a sort key, cutting one
         # operand from the sort network.
-        sort_idx = jnp.lexsort((tiebreak, hpk, big_pid))
-    with scope("bound_permute"):
-        spid = big_pid[sort_idx]
-        spk = big_pk[sort_idx]
-        # COUNT-style metrics never read the value column: skip the
-        # gather of the (all-zero) values array entirely.
-        svalues = values[sort_idx] if config.needs_values else values
+        #
+        # The columns bounding reads ride the sort as payload operands:
+        # the network moves them with the keys, where a gather by the
+        # sorted order would move one element at a time (~8x the sort
+        # on a TPU v5e). Rank-1 values ride too; VECTOR_SUM's [N, D]
+        # values cannot (``lax.sort``'s operands share one shape), so
+        # they keep a gather by the order, as the total-cap branch's
+        # keep bits do. The row index is the last key: rows tying on the
+        # three keys (one segment, equal 32 tie bits) keep their row
+        # order, as in a stable sort, which is what makes the released
+        # values independent of how far the rows are padded. With every
+        # key tuple distinct the sort need not be stable, and unstable
+        # it compiles in two thirds of the stable sort's time.
+        ride_values = config.needs_values and values.ndim == 1
+        gather_values = config.needs_values and not ride_values
+        payload = (big_pk, values) if ride_values else (big_pk,)
+        out = jax.lax.sort(
+            (big_pid, hpk, tiebreak, jax.lax.iota(jnp.int32, n), *payload),
+            num_keys=4, is_stable=False)
+        spid, sort_idx, spk = out[0], out[3], out[4]
+        svalues = out[5] if ride_values else values
+    obs.inc("bound.payload_sort")
+    if gather_values or config.max_contributions is not None:
+        obs.inc("bound.gathers")
+    if gather_values:
+        with scope("bound_permute"):
+            svalues = values[sort_idx]
     with scope("bound_caps"):
         idx = jnp.arange(n)
         # Valid rows sort before padding (PAD_ID keys): no gather needed.
@@ -847,9 +871,8 @@ def _partials(config: FusedConfig, num_partitions: int, pid, pk, values,
             # the permutations.
             with scope("bound_sort"):
                 tie_m = counter_rng.row_bits(k_m, n)
-                order_m = jnp.lexsort((tie_m, big_pid))
-            with scope("bound_permute"):
-                mpid = big_pid[order_m]
+                mpid, _, order_m = jax.lax.sort((big_pid, tie_m, idx),
+                                                num_keys=3, is_stable=False)
             new_pid_m = (idx == 0) | (mpid != jnp.roll(mpid, 1))
             keep_sorted = (seg_ops.rank_in_run(new_pid_m) <
                            config.max_contributions)

@@ -80,7 +80,8 @@ ENV_VAR = "PIPELINEDP_TPU_COSTS"
 
 #: The phases of the fused aggregation programs, each a
 #: ``jax.named_scope`` around the code that does it: the bounding sort
-#: (and the total-cap sort), the gathers by its order, the per-row
+#: (and the total-cap sort), the gathers by its order that remain (rank-2
+#: values, the total-cap keep bits), the per-row
 #: rank/ordinal/clip masks, the per-partition segment sum, partition
 #: selection, on-device noise draws, the cross-chip combine, the
 #: compact fetch's output compaction, and the percentile walk's

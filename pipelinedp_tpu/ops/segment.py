@@ -5,11 +5,11 @@ runs, ``sample_fixed_per_key`` = random-priority sort + rank-in-run,
 
 Design note: on TPU a scatter (``segment_sum``/``segment_max`` over the
 row axis) costs roughly an order of magnitude more than an elementwise op,
-so after the single lexsort every per-segment quantity is derived *in row
-space* from cumulative ops over the contiguous runs — ``run_start`` is a
-cummax, ranks are index differences, group ordinals are cumsum
-differences. The only scatters in the fused kernel are the final per-pk
-reductions.
+so after the single bounding sort every per-segment quantity is derived
+*in row space* from cumulative ops over the contiguous runs —
+``run_start`` is a cummax, ranks are index differences, group ordinals
+are cumsum differences. The only scatters in the fused kernel are the
+final per-pk reductions.
 
 Everything here is jit-compatible: static shapes, no data-dependent
 Python control flow. Padding rows carry ``PAD_ID`` keys so they sort

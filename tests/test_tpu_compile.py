@@ -6,7 +6,8 @@ the (8, 128) tiling, float iotas, grid semantics of the wrong length,
 more VMEM than a kernel may use. These tests compile the three kernels
 at their dispatch envelopes' widest shapes with ``interpret=False``,
 and the fused aggregation program at 2^20 rows and 2^16 partitions,
-for one chip of a described ``v5e:2x2`` topology.
+for one chip of a described ``v5e:2x2`` topology; and, at 2,048 rows,
+checks which columns the bounding sort carries in the chip's program.
 
 The topology is described inside a module-scoped fixture, never at
 import time: only one process may load the TPU library, and every
@@ -130,3 +131,19 @@ def test_fused_aggregation_fits_one_chip(one_chip, no_persistent_cache):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes +
             mem.temp_size_in_bytes + mem.generated_code_size_in_bytes)
     assert 0 < used < V5E_HBM_BYTES, mem
+
+
+@pytest.mark.parametrize("name", ["sum_mean", "vector_sum"])
+def test_bounding_sort_carries_its_columns(one_chip, no_persistent_cache,
+                                           name):
+    """The chip's program of a SUM/MEAN release has one sort under
+    ``bound_sort`` and nothing under ``bound_permute``: pk and the values
+    ride the sort. VECTOR_SUM's [N, D] values cannot, and keep their
+    gather there."""
+    from test_bound_sort_payload import (assert_bounding_structure,
+                                         fused_program_text)
+    text = fused_program_text(name, n=2048, sharding=one_chip)
+    permute = assert_bounding_structure(name, text)
+    # On the chip the gather's index fusion maps to the scope, as a
+    # trace reads it.
+    assert bool(permute) == (name == "vector_sum")
