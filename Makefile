@@ -95,7 +95,7 @@ veccheck: nopallas
 # unchunked = Pallas, single device + 8-device mesh — in
 # tests/test_pass_b.py), out-of-envelope + pallas-unavailable
 # fallbacks with their kernel.fallback events, kernel_backend knob
-# precedence (env > seam > plan > default), the interpret-mode CPU
+# precedence (env > seam > default), the interpret-mode CPU
 # roofline peak row, and the in-tree nopallas AST twin.
 kernelcheck: nopallas
 	$(PYTHON) -m pytest tests/test_kernels.py tests/test_pass_b.py -q
@@ -203,15 +203,11 @@ watchcheck:
 costcheck: nocost
 	$(PYTHON) -m pytest tests/test_costs.py tests/test_obs.py -q
 
-# Execution-planner acceptance suite: cold-start byte-identity to the
-# hardcoded defaults, env > seam > plan > default precedence, dp-unsafe
-# knobs never applied from a plan, stale-fingerprint plan rejection
-# (plan.stale), cost-model fit/predict/serialize + the static roofline
-# fallback, the pass-B q_chunk pin, planner on/off DP bit-parity
-# (PARITY row 32), the store's --since-run-id window, bench plan
-# provenance + the --compare plan-mismatch refusal, and the in-process
-# autotune→plan-file→plain-run acceptance flow — plus the
-# no-direct-knob-read lint.
+# Knob-registry suite: cold-start byte-identity to the hardcoded
+# defaults, env > seam > default precedence for every registered knob,
+# unknown enumerated values falling back to the default, a stale plan
+# file on disk steering nothing, the pass-B q_chunk pin, the store's
+# --since-run-id window — plus the no-direct-knob-read lint.
 plancheck: noknobs
 	$(PYTHON) -m pytest tests/test_plan.py -q
 

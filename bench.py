@@ -177,7 +177,6 @@ class _BenchLedger:
 
 _BENCH_LEDGER = None
 _RUN_RECORDS = []
-_PLAN_PROV = None
 
 
 def _bench_ledger():
@@ -191,42 +190,18 @@ def reset_run_state():
     """Fresh bench 'run' within one process (tests simulating two
     driver invocations): clears the cached tracer / fingerprint /
     ledger connection / record list and the obs process ledger."""
-    global _TRACER, _ENV_FP, _BENCH_LEDGER, _RUN_RECORDS, _PLAN_PROV
+    global _TRACER, _ENV_FP, _BENCH_LEDGER, _RUN_RECORDS
     _TRACER = None
     _ENV_FP = None
     _BENCH_LEDGER = None
     _RUN_RECORDS = []
-    _PLAN_PROV = None
     from pipelinedp_tpu import obs
     obs.reset()
 
 
-def plan_provenance():
-    """{plan_source, plan_hash} stamped on every bench record:
-    ``autotuned`` when a plan file steered any knob, ``env-override``
-    when an env var or test seam did, ``default`` otherwise — the
-    fields ``--compare`` uses to refuse gating an autotuned run
-    against a default-knob baseline (and vice versa).
-
-    Snapshotted ONCE per bench run, at the first call (main() takes it
-    right after the plan dir resolves, before any record runs): later
-    records run under bench-internal measurement scaffolding — the
-    streamed record's chunk env, the capped probe records' seam
-    injections — and labeling those as ``env-override`` would misstate
-    the regime every plain run was launched under."""
-    global _PLAN_PROV
-    if _PLAN_PROV is None:
-        from pipelinedp_tpu import plan as plan_mod
-        try:
-            _PLAN_PROV = plan_mod.source_summary()
-        except Exception:
-            _PLAN_PROV = {"plan_source": "default", "plan_hash": None}
-    return dict(_PLAN_PROV)
-
-
 def kernel_backend_in_force():
-    """The resolved ``kernel_backend`` knob (env > seam > plan >
-    default), stamped on every bench record so ``--compare`` can
+    """The resolved ``kernel_backend`` knob (env > seam > default),
+    stamped on every bench record so ``--compare`` can
     refuse to gate an ``xla`` rate against a ``pallas`` baseline (two
     different device programs — a delta there is a backend
     difference, not a regression)."""
@@ -238,8 +213,8 @@ def kernel_backend_in_force():
 
 
 def mesh_topology_in_force():
-    """The resolved ``mesh_topology`` knob (env > seam > plan >
-    default), stamped on every bench record so ``--compare`` can
+    """The resolved ``mesh_topology`` knob (env > seam > default),
+    stamped on every bench record so ``--compare`` can
     refuse to gate a flat-exchange rate against a hierarchical
     baseline (two different collective schedules — released values
     are bit-identical by PARITY row 43, but the rate delta is a
@@ -252,11 +227,10 @@ def mesh_topology_in_force():
 
 
 def emit(rec):
-    """Log one record (with the env fingerprint, the plan provenance
-    and the kernel backend merged) as JSON, and append it to the
-    durable run-ledger store keyed by the environment fingerprint."""
+    """Log one record (with the env fingerprint and the kernel backend
+    merged) as JSON, and append it to the durable run-ledger store
+    keyed by the environment fingerprint."""
     rec["env"] = env_fingerprint()
-    rec.update(plan_provenance())
     rec.setdefault("kernel_backend", kernel_backend_in_force())
     rec.setdefault("mesh_topology", mesh_topology_in_force())
     log(json.dumps(rec))
@@ -806,8 +780,7 @@ def bench_streamed_percentile(n_rows):
         # 5/8 of one [P_pad, 1, span] block, so the planner must tile
         # AND pack (sweeps strictly below tiles on this shape). The
         # injection goes through the knob registry's seam-override
-        # idiom — a mutated seam outranks any plan file, so this
-        # record measures the injected cap even on an autotuned host.
+        # idiom.
         from pipelinedp_tpu import plan as plan_mod
         _, _, _, span = streaming_mod._tree_consts()
         P_pad = je._pad_pow2(parts)
@@ -860,9 +833,7 @@ def bench_kernel_backend_compare(n_rows, smoke=False):
     roofline verdicts (the cost observatory is force-enabled for the
     record's duration), so one artifact answers "did the hand-tiled
     kernels win here, and were they still bandwidth-bound". On the
-    CPU proxy the Pallas interpret path is expected to LOSE — that is
-    exactly the measurement the autotune sweep uses to self-select
-    ``xla`` off-TPU."""
+    CPU proxy the Pallas interpret path is expected to LOSE."""
     import pipelinedp_tpu as pdp
     from pipelinedp_tpu import plan as plan_mod
     from pipelinedp_tpu import jax_engine as je
@@ -942,11 +913,8 @@ def bench_kernel_backend_compare(n_rows, smoke=False):
     try:
         for backend in ("xla", "pallas"):
             # Pin each leg via the ENV override — the top of the
-            # precedence chain. A seam set to the registry default
-            # ("xla") is indistinguishable from "no override" and
-            # would fall through to a plan file that may select
-            # pallas, running BOTH legs on the same backend (the same
-            # trap run_autotune's sweep isolation guards against).
+            # precedence chain, so an ambient env pin cannot run BOTH
+            # legs on the same backend.
             os.environ[spec.env_var] = backend
             with plan_mod.seam_override("subhist_byte_cap", cap):
                 obs_costs.TABLE.reset()
@@ -1071,8 +1039,8 @@ def bench_mesh_topology_compare(n_rows, smoke=False):
     try:
         os.environ[psh._MESH_HOSTS_ENV] = "2"
         for mode in ("flat", "hier"):
-            # ENV pin, the top of the precedence chain — a plan file
-            # must not flip one leg (run_autotune's isolation trap).
+            # ENV pin, the top of the precedence chain, so an ambient
+            # pin cannot flip one leg.
             os.environ[spec.env_var] = mode
             mesh = psh.make_mesh(8)
             topo = psh.topology_of(mesh)
@@ -1181,8 +1149,7 @@ def bench_dp_vector_sum(n_rows, smoke=False):
             for var in (streaming_mod._CHUNK_ENV, obs_costs.ENV_VAR,
                         acc_spec.env_var, kb_spec.env_var)}
     # ENV pins (the top of the knob precedence chain), same isolation
-    # rationale as the kernel-backend A/B: a seam set to a default
-    # would fall through to a loaded plan file.
+    # rationale as the kernel-backend A/B.
     os.environ[obs_costs.ENV_VAR] = "1"
     os.environ[acc_spec.env_var] = "fx"
     os.environ[kb_spec.env_var] = "pallas"
@@ -1295,8 +1262,8 @@ def bench_serve_latency(n_rows, smoke=False):
     request latency, sequential and concurrent requests/s — the
     serving-plane twin of the batch rows/s records. The headline value
     is the CONCURRENT requests/s (unit ``req/s``), so ``--compare``
-    gates it like every other rate; ``plan_source``/``kernel_backend``
-    stamps ride in through the shared emitter."""
+    gates it like every other rate; the ``kernel_backend`` stamp rides
+    in through the shared emitter."""
     import shutil
     import tempfile
 
@@ -1415,7 +1382,7 @@ def bench_serve_fused_throughput(n_rows, smoke=False):
     concurrent requests/s (unit ``req/s`` so ``--compare`` gates it);
     the record carries the solo rate and the speedup, and is stamped
     ``fusion: true`` so cross-mode gating is refused (the
-    plan_hash/kernel_backend refusals' twin)."""
+    kernel_backend refusal's twin)."""
     import shutil
     import tempfile
 
@@ -1699,7 +1666,7 @@ def bench_dp_heavy_hitters(n_rows, smoke=False):
     accumulate / select vs the exact pass), the candidate funnel
     (universe → selected buckets → candidates → released) and a
     top-50 recall diagnostic vs the true distinct-user ranking —
-    stamped with fingerprint/plan/kernel-backend like every record so
+    stamped with fingerprint/kernel-backend like every record so
     ``--compare`` gates it."""
     import pipelinedp_tpu as pdp
     from pipelinedp_tpu.backends import JaxBackend
@@ -1843,256 +1810,6 @@ def bench_dp_heavy_hitters(n_rows, smoke=False):
     return rec
 
 
-def run_autotune(args):
-    """``bench.py --autotune``: the bounded knob sweep that closes the
-    measure→decide loop. Runs the streamed-percentile workload once per
-    candidate knob vector (the default vector + one-factor deviations
-    of every dp-safe knob — ``plan.autotune_candidates``), appends each
-    trial to the run ledger as an ``autotune.trial`` entry, fits the
-    stdlib cost model from the run-windowed entries (``--since-run-id``
-    semantics: one windowed read after the sweep, never a full-ledger
-    re-read per trial), and atomically writes the plan file a
-    subsequent plain run resolves (``plan.applied`` events with
-    ``source: "plan"``). Prints ONE JSON headline on stdout."""
-    import pipelinedp_tpu as pdp
-    from pipelinedp_tpu import plan as plan_mod
-    from pipelinedp_tpu import streaming as streaming_mod
-    from pipelinedp_tpu.backends import JaxBackend
-    from pipelinedp_tpu.obs import store as obs_store
-    from pipelinedp_tpu.plan import model as plan_model
-
-    n_rows = args.rows or 120_000
-    parts = 60 if getattr(args, "smoke", False) else 3_000
-    rng = np.random.default_rng(17)
-    ds = pdp.ArrayDataset(
-        privacy_ids=rng.integers(0, 1 << 20, n_rows).astype(np.int32),
-        partition_keys=(rng.zipf(1.3, n_rows) % parts).astype(np.int32),
-        values=rng.uniform(0.0, 10.0, n_rows).astype(np.float32))
-    params = pdp.AggregateParams(
-        metrics=[pdp.Metrics.PERCENTILE(50), pdp.Metrics.PERCENTILE(90),
-                 pdp.Metrics.PERCENTILE(99), pdp.Metrics.VARIANCE],
-        noise_kind=pdp.NoiseKind.LAPLACE,
-        max_partitions_contributed=4, max_contributions_per_partition=2,
-        min_value=0.0, max_value=10.0)
-
-    # Sketch-first twin workload: the sketch_backend knob is only a
-    # MEASURED choice if the sweep actually dispatches the sketch
-    # binner — every trial runs the same small sketch-first request
-    # inside its timed span, with the trial vector's backend, so the
-    # base-vs-deviation argmin compares real matmul-vs-scatter work
-    # (not timing noise) and every other deviation pays the identical
-    # sketch cost.
-    hh_rng = np.random.default_rng(29)
-    hh_n = 8_000
-    hh_keys = np.char.add("k/",
-                          (hh_rng.zipf(1.3, hh_n) % 1000).astype("U6"))
-    hh_pids = hh_rng.integers(0, 1000, hh_n)
-    hh_params = pdp.AggregateParams(
-        metrics=[pdp.Metrics.COUNT], noise_kind=pdp.NoiseKind.LAPLACE,
-        max_partitions_contributed=4,
-        max_contributions_per_partition=2)
-
-    def sketch_probe(vec):
-        hh_acc = pdp.NaiveBudgetAccountant(total_epsilon=2.0,
-                                           total_delta=1e-6)
-        hh_engine = pdp.DPEngine(hh_acc, JaxBackend(rng_seed=0))
-        hh_res = hh_engine.aggregate(
-            pdp.ArrayDataset(privacy_ids=hh_pids,
-                             partition_keys=hh_keys, values=None),
-            hh_params, pdp.DataExtractors(),
-            sketch_first=pdp.SketchParams(
-                eps=2.0, delta=1e-7, width=2048, depth=2,
-                candidate_cap=512,
-                backend=str(vec.get("sketch_backend", "xla"))))
-        hh_acc.compute_budgets()
-        dict(hh_res)
-
-    # Megasweep twin workload: the sweep_config_batch knob is only a
-    # MEASURED choice if the trial actually dispatches the config-
-    # batched sweep kernels — every trial runs the same small
-    # utility-analysis grid inside its timed span with the trial
-    # vector's batch width in force (via the seam; the sweep phase
-    # feeds the trial's ``phases`` dict, which plan/model.py's fit
-    # consumes), so the base-vs-deviation argmin compares measured
-    # walked-vs-batched dispatch behavior and every other deviation
-    # pays the identical sweep cost.
-    from pipelinedp_tpu import analysis as analysis_mod
-    sw_rng = np.random.default_rng(31)
-    sw_n = 30_000
-    sw_ds = pdp.ArrayDataset(
-        privacy_ids=sw_rng.integers(0, 4_000, sw_n),
-        partition_keys=(sw_rng.zipf(1.3, sw_n) % 200).astype(np.int64),
-        values=sw_rng.uniform(0.0, 10.0, sw_n))
-    sw_pairs = [(a, b) for a in range(1, 5) for b in range(1, 5)]
-    sw_options = analysis_mod.UtilityAnalysisOptions(
-        epsilon=1.0, delta=1e-6,
-        aggregate_params=pdp.AggregateParams(
-            metrics=[pdp.Metrics.COUNT],
-            noise_kind=pdp.NoiseKind.LAPLACE,
-            max_partitions_contributed=4,
-            max_contributions_per_partition=2),
-        multi_param_configuration=(
-            analysis_mod.MultiParameterConfiguration(
-                max_partitions_contributed=[p[0] for p in sw_pairs],
-                max_contributions_per_partition=[p[1]
-                                                 for p in sw_pairs])))
-
-    def sweep_probe(vec):
-        with plan_mod.seam_override(
-                "sweep_config_batch",
-                int(vec.get("sweep_config_batch", 0))):
-            with tracer().span("autotune.sweep_probe",
-                               cat="autotune") as sp:
-                list(analysis_mod.perform_utility_analysis(
-                    sw_ds, JaxBackend(rng_seed=0), sw_options,
-                    pdp.DataExtractors()))
-        return sp.duration
-
-    led = _bench_ledger()
-    # Pre-sweep end offset of the ledger file: the post-sweep fit reads
-    # only the bytes appended after this point (read_from), so fitting
-    # stays O(sweep) on a long-lived service ledger instead of
-    # re-parsing the whole history every autotune.
-    sweep_offset = 0
-    if led._store is not None:
-        try:
-            sweep_offset = os.path.getsize(led._store.path)
-        except OSError:
-            sweep_offset = 0
-    # The sweep measures the TRIAL vectors: a plan file left by a prior
-    # autotune must not steer them. A seam pinned AT the registry
-    # default is indistinguishable from "no override" (the precedence
-    # falls through to the plan), so the default-vector trial and every
-    # single-knob deviation would silently execute the old plan while
-    # the ledger labels them with the trial's knobs. Disable plan
-    # loading for the sweep's duration; the write at the end needs the
-    # real directory back, so the restore sits in the same finally as
-    # the chunk env.
-    from pipelinedp_tpu.plan import planner as planner_mod
-    prev_plan_dir = os.environ.get(planner_mod.ENV_DIR)
-    os.environ[planner_mod.ENV_DIR] = "0"
-    plan_mod.reset()
-    prev = os.environ.get(streaming_mod._CHUNK_ENV)
-    did_set = False
-    if n_rows <= streaming_mod.stream_chunk_rows():
-        # The sweep must exercise the streamed path (that is where the
-        # knobs live): force a chunk below the dataset, exactly like
-        # the streamed-percentile bench record.
-        os.environ[streaming_mod._CHUNK_ENV] = str(max(n_rows // 6,
-                                                       1000))
-        did_set = True
-    shape = {"rows": n_rows, "partitions": parts, "quantiles": 3}
-    log(f"## autotune: {n_rows} rows x {parts} partitions, "
-        f"{len(plan_mod.autotune_candidates())} candidate vectors")
-    trials = []
-
-    def one_run(vec):
-        ds.invalidate_cache()
-        acc = pdp.NaiveBudgetAccountant(total_epsilon=1.0,
-                                        total_delta=1e-6)
-        engine = pdp.DPEngine(acc, JaxBackend(
-            rng_seed=0,
-            ingest_executor=bool(vec["ingest_executor"]),
-            stream_cache=int(vec["stream_cache_bytes"])))
-        result = engine.aggregate(ds, params, pdp.DataExtractors(),
-                                  public_partitions=list(range(parts)))
-        acc.compute_budgets()
-        with plan_mod.seam_override("subhist_byte_cap",
-                                    vec["subhist_byte_cap"]), \
-                plan_mod.seam_override("q_chunk", vec["q_chunk"]), \
-                plan_mod.seam_override("kernel_backend",
-                                       vec.get("kernel_backend",
-                                               "xla")):
-            with tracer().span("autotune.trial", cat="autotune") as sp:
-                dict(result)
-                sketch_probe(vec)
-                sweep_s = sweep_probe(vec)
-        return sp.duration, result.timings or {}, sweep_s
-
-    try:
-        candidates = plan_mod.autotune_candidates()
-        # Untimed warm-up of EACH candidate immediately before its
-        # timed run: different vectors select different XLA programs
-        # (a shrunken cap forces the multi-tile kernels, a q_chunk pin
-        # a different tile grid), so one default-vector warm-up would
-        # leave every deviation paying cold compile inside its timed
-        # window and bias the measured argmin toward the default.
-        for i, vec in enumerate(candidates):
-            one_run(vec)
-            dt, timings, sweep_s = one_run(vec)
-            trial = {
-                "index": i,
-                "knobs": {k: (int(v) if isinstance(v, bool) else v)
-                          for k, v in vec.items()},
-                "shape": shape,
-                "device_kind": env_fingerprint().get("device_kind"),
-                "total_s": round(dt, 4),
-                "rows_per_s": round(n_rows / dt),
-                "phases": {
-                    "pass_a": timings.get("stream_t_total"),
-                    "pass_b": timings.get("stream_pass_b_sweep_s"),
-                    "sweep": round(sweep_s, 4),
-                },
-                "pass_b_sweeps": timings.get("stream_pass_b_sweeps"),
-            }
-            trials.append(trial)
-            led.append("autotune.trial", {"trial": trial,
-                                          "env": env_fingerprint()})
-            log(f"## autotune trial {i}: {trial['knobs']} -> "
-                f"{trial['total_s']}s ({trial['rows_per_s']} rows/s)")
-    finally:
-        if did_set:
-            if prev is None:
-                os.environ.pop(streaming_mod._CHUNK_ENV, None)
-            else:
-                os.environ[streaming_mod._CHUNK_ENV] = prev
-        if prev_plan_dir is None:
-            os.environ.pop(planner_mod.ENV_DIR, None)
-        else:
-            os.environ[planner_mod.ENV_DIR] = prev_plan_dir
-        plan_mod.reset()
-
-    # ONE windowed ledger read after the sweep: only the bytes past
-    # the pre-sweep offset, then THIS run's entries only — a
-    # concurrent sweep sharing the ledger appends its own trials
-    # interleaved with ours, and a trial measured under another
-    # process's env must never win a bucket in this process's plan.
-    fresh = (led._store.read_from(sweep_offset)[0]
-             if led._store is not None else [])
-    entries = [e for e in obs_store.entries_since_run_id(fresh,
-                                                         led.run_id)
-               if e.get("run_id") == led.run_id]
-    model = plan_model.fit(entries, fingerprint=led.fingerprint)
-    best = plan_model.choose_best_trial(entries,
-                                        fingerprint=led.fingerprint)
-    headline = {"metric": "autotune", "trials": len(trials),
-                "rows": n_rows, "partitions": parts,
-                "degraded": bool(os.environ.get(
-                    "PIPELINEDP_TPU_DEGRADED")),
-                "env": env_fingerprint()}
-    if best is None:
-        # Every trial degraded or failed: refuse to write a plan from
-        # poisoned measurements — the next run keeps the defaults.
-        headline["plan_file"] = None
-        log("## autotune: no eligible (non-degraded) trials — no plan "
-            "written, defaults stay in force")
-    else:
-        plan = plan_mod.build_plan(
-            best, model,
-            device_kind=env_fingerprint().get("device_kind"),
-            trials=len(trials))
-        path = plan_mod.write_plan(plan)
-        headline["plan_file"] = path
-        headline["plan_hash"] = plan_mod.plan_hash(plan)
-        headline["best"] = {b: row["knobs"]
-                            for b, row in best.items()}
-        log(f"## autotune: plan {headline['plan_hash']} written to "
-            f"{path} from {len(trials)} trial(s)")
-    record_run_report()
-    print(json.dumps(headline))
-    return 0
-
-
 def record_run_report(snapshot=None):
     """Build this run's schema-v2 run report (env fingerprint + spans +
     counters/events + the privacy audit section) and append it to the
@@ -2120,13 +1837,11 @@ def compare_to_baseline(records=None, run_report=None, threshold=0.10):
     records = _RUN_RECORDS if records is None else records
     rates, spans, regressed = [], [], []
     skipped_degraded = 0
-    plan_mismatches = 0
     backend_mismatches = 0
     fusion_mismatches = 0
     accumulator_mismatches = 0
     sweep_batch_mismatches = 0
     topology_mismatches = 0
-    cur_plan = plan_provenance()
     cur_backend = kernel_backend_in_force()
     # One comparison per metric, at its BEST value this run — the same
     # best-sample rule the headline applies (the flagship re-sample
@@ -2167,40 +1882,8 @@ def compare_to_baseline(records=None, run_report=None, threshold=0.10):
                  "baseline": base_val,
                  "ratio": round(value / base_val, 3),
                  "baseline_ts": base.get("ts")}
-        # Plan-provenance gate: a run under a different knob REGIME
-        # than its baseline — a plan-hash change, or an env/seam
-        # override vs a default baseline (both hash None, so the
-        # source label is the only tell: the env fingerprint's stable
-        # fields exclude the PIPELINEDP_TPU_* flags) — measures two
-        # different knob vectors, and a rate delta there is a plan
-        # difference, not a regression. Refuse to gate instead of
-        # crying wolf; the mismatch is recorded and the verdict line
-        # says so. Absent fields on old records read as "no plan"
-        # (pre-planner), so default-vs-default keeps gating exactly as
-        # before.
         base_rec = (base.get("payload") or {}).get("record") or {}
-        base_plan = {"plan_source": base_rec.get("plan_source",
-                                                 "default"),
-                     "plan_hash": base_rec.get("plan_hash")}
-        cur_hash = rec.get("plan_hash", cur_plan["plan_hash"])
-        cur_source = rec.get("plan_source", cur_plan["plan_source"])
-        if (base_plan["plan_hash"] != cur_hash
-                or base_plan["plan_source"] != cur_source):
-            plan_mismatches += 1
-            entry["plan_mismatch"] = True
-            entry["baseline_plan"] = base_plan
-            obs.inc("bench.compare_plan_mismatch")
-            obs.event("bench.compare_plan_mismatch",
-                      metric=rec["metric"],
-                      baseline_source=base_plan["plan_source"],
-                      current_source=cur_source)
-            log(f"## compare: plan mismatch on {rec['metric']} "
-                f"(baseline {base_plan['plan_source']}/"
-                f"{base_plan['plan_hash']}, this run "
-                f"{cur_source}/{cur_hash}) — not gated")
-            rates.append(entry)
-            continue
-        # Kernel-backend gate (the plan_hash refusal's twin): an xla
+        # Kernel-backend gate: an xla
         # rate gated against a pallas baseline (or vice versa)
         # compares two different device programs. Absent fields on
         # old records read as "xla" (the pre-knob behavior), so
@@ -2338,14 +2021,12 @@ def compare_to_baseline(records=None, run_report=None, threshold=0.10):
     return {"fingerprint": led.fingerprint, "threshold": threshold,
             "rates": rates, "spans": spans,
             "skipped_degraded_baselines": skipped_degraded,
-            "plan_mismatches": plan_mismatches,
             "kernel_backend_mismatches": backend_mismatches,
             "vector_accumulator_mismatches": accumulator_mismatches,
             "fusion_mismatches": fusion_mismatches,
             "sweep_config_batch_mismatches": sweep_batch_mismatches,
             "mesh_topology_mismatches": topology_mismatches,
             "kernel_backend": cur_backend,
-            "plan": cur_plan,
             "regressed": regressed}
 
 
@@ -2358,14 +2039,6 @@ def compare_verdict_line(regressions):
                 f"{', '.join(regressions['regressed'])} dropped "
                 f">{regressions['threshold']:.0%} vs last-known-good "
                 f"(fingerprint {regressions['fingerprint']})")
-    if regressions.get("plan_mismatches"):
-        plan = regressions.get("plan") or {}
-        return (f"COMPARE: plan mismatch — "
-                f"{regressions['plan_mismatches']} rate(s) not gated: "
-                f"this run ran {plan.get('plan_source', 'default')} "
-                f"knobs (plan {plan.get('plan_hash')}) against a "
-                "baseline from a different knob plan; re-baseline "
-                "with matching plans before gating")
     if regressions.get("kernel_backend_mismatches"):
         return (f"COMPARE: kernel-backend mismatch — "
                 f"{regressions['kernel_backend_mismatches']} rate(s) "
@@ -2402,7 +2075,6 @@ def compare_verdict_line(regressions):
                 "re-baseline with matching topologies before gating")
     n_based = sum(1 for r in regressions["rates"]
                   if r.get("baseline") is not None and
-                  not r.get("plan_mismatch") and
                   not r.get("kernel_backend_mismatch") and
                   not r.get("fusion_mismatch") and
                   not r.get("sweep_config_batch_mismatch") and
@@ -2467,12 +2139,6 @@ def main():
         help="streaming-ingest benchmark row count (default: 150M full "
         "runs / 200k smoke; 0 disables)")
     parser.add_argument(
-        "--autotune", action="store_true",
-        help="run the bounded execution-planner knob sweep on the "
-        "streamed-percentile workload, append every trial to the run "
-        "ledger, fit the cost model and write the plan file a "
-        "subsequent plain run loads (pipelinedp_tpu/plan)")
-    parser.add_argument(
         "--compare", action="store_true",
         help="diff this run's rates and span totals against the run "
         "ledger's last-known-good for the same environment fingerprint "
@@ -2510,24 +2176,6 @@ def main():
     cache_dir = maybe_enable_compile_cache(default_dir=os.path.join(
         os.path.dirname(os.path.abspath(__file__)), ".jax_cache"))
     log(f"## persistent compile cache: {cache_dir}")
-
-    # The execution planner's plan file: like the run ledger, the
-    # bench falls back to a cwd-local directory when neither
-    # PIPELINEDP_TPU_PLAN_DIR nor the compile cache names one — so
-    # `bench.py --autotune` followed by a plain `bench.py` in the same
-    # directory closes the loop without any env setup.
-    from pipelinedp_tpu import plan as plan_mod
-    plan_mod.set_default_dir(os.path.join(os.getcwd(), ".pdp_plan"))
-    # Snapshot the plan provenance NOW — before any record injects its
-    # measurement scaffolding (chunk env, cap seams) — so every record
-    # and the headline carry the regime the run was launched under.
-    plan_provenance()
-
-    if args.autotune:
-        rc = run_autotune(args)
-        if monitor is not None:
-            obs_monitor.stop()
-        sys.exit(rc)
 
     import pipelinedp_tpu as pdp
 
@@ -2705,10 +2353,6 @@ def main():
                 ("metric", "value", "unit", "vs_baseline",
                  "host_s", "device_s", "kernel_backend")
                 if k in flagship}
-    # Plan provenance on the artifact of record: which knob plan
-    # produced this rate (autotuned / env-override / default + the
-    # plan-file hash).
-    headline.update(plan_provenance())
     headline["env"] = env_fingerprint()
     # ONE ledger snapshot feeds every exporter, so the trace file, the
     # report and the stored ledger entry agree span-for-span; the

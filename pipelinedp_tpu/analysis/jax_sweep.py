@@ -82,12 +82,6 @@ _CHUNK_ROW_BUDGET = 1 << 26
 # through knobs.value().
 _SWEEP_CONFIG_BATCH = 0
 
-#: HBM byte budget the model-fitted chunk sizing targets — the f32
-#: byte equivalent of the static element budgets above (2^28 elements
-#: x 4 bytes). The fitted sweep-phase HBM peak scales the static width
-#: against this, never against a made-up capacity number.
-_SWEEP_HBM_BUDGET = 1 << 30
-
 
 def _lane_align(chunk: int) -> int:
     """Round a config-axis width to the TPU lane grid: large chunks
@@ -98,34 +92,6 @@ def _lane_align(chunk: int) -> int:
     if chunk > 1:
         return 1 << (chunk.bit_length() - 1)
     return 1
-
-
-def _plan_chunk(static_chunk: int, rows: int, partitions: int
-                ) -> Tuple[int, str]:
-    """(chunk, source) for ``sweep_config_batch=0``: when the current
-    plan carries a fitted sweep-phase HBM-peak sample for this shape
-    bucket (plan/model.py — measured at the STATIC width, so the
-    budget/peak ratio rescales that width directly), size the chunk as
-    ``static * budget/peak``; otherwise keep the static
-    widest-in-HBM-budget formula exactly (source "static" — cold start
-    and poisoned-history ledgers stay byte-identical to the pre-model
-    sizing, because an empty/foreign-fingerprint fit predicts None).
-    The sweep's bucket varies on (rows, partitions) only; quantiles=0
-    matches how autotune trials record sweep shapes."""
-    from pipelinedp_tpu.plan import planner as _planner
-    model = _planner.current_cost_model()
-    if model is None:
-        return static_chunk, "static"
-    try:
-        dk = jax.devices()[0].device_kind
-    except Exception:
-        dk = None
-    peak = model.predict_hbm_peak(dk, "sweep", rows, partitions, 0)
-    if not peak or peak <= 0:
-        return static_chunk, "static"
-    scaled = int(static_chunk * (_SWEEP_HBM_BUDGET / float(peak)))
-    chunk = _lane_align(int(np.clip(scaled, 1, _CHUNK_CAP)))
-    return chunk, "model"
 
 
 def sweep_is_supported(options: data_structures.UtilityAnalysisOptions,
@@ -701,6 +667,35 @@ _sweep_chunk_kernel = instrumented_jit(
     static_argnames=("metric_names", "strategy", "noise_kind", "P",
                      "public", "chunk", "per_partition"))(_sweep_chunk_body)
 
+
+@instrumented_jit(phase="sweep", static_argnames=("axis",))
+def _concat_flat(chunks, axis):
+    """Every field of every chunk (``chunks[c][f]``), concatenated over
+    the chunks along ``axis`` and raveled into ONE flat vector: one
+    program, so one d2h transfer and, on a mesh (config axis sharded),
+    one collective launch. Separate eager concatenates each launch a
+    collective program; with several in flight, a device thread can
+    miss a rendezvous until XLA's CPU runtime aborts the process at
+    its termination timeout."""
+    return jnp.concatenate([jnp.concatenate(field, axis).ravel()
+                            for field in zip(*chunks)])
+
+
+def _fetch_concat(chunks, axis):
+    """Host twin of :func:`_concat_flat`: the per-field arrays
+    (concatenated over ``chunks`` along ``axis``), fetched in one
+    transfer."""
+    flat = np.asarray(_concat_flat(chunks, axis))
+    out, off = [], 0
+    for field in zip(*chunks):
+        shape = list(field[0].shape)
+        shape[axis] = sum(int(x.shape[axis]) for x in field)
+        size = int(np.prod(shape))
+        out.append(flat[off:off + size].reshape(shape))
+        off += size
+    return out
+
+
 #: The [P, Cc] per-partition blocks _metric_chunk emits (plus the
 #: metric-independent "_pp_keep") — ONE list for the emission, the
 #: single-device extraction and the mesh extraction.
@@ -1090,8 +1085,7 @@ class LazySweepResult:
         # Config chunking: bound both the [n, Cc] broadcast and the
         # [P, Cc, 2·WINDOW+1] selection-window footprints. The
         # sweep_config_batch knob (0 = this auto sizing) pins the width
-        # explicitly — every width is bit-identical per config, so the
-        # planner may sweep it.
+        # explicitly — every width is bit-identical per config.
         from pipelinedp_tpu.plan import knobs as _knobs
         n_dev = self._mesh.devices.size if self._mesh is not None else 1
         pinned = int(_knobs.value("sweep_config_batch"))
@@ -1110,15 +1104,9 @@ class LazySweepResult:
             # operand carries Cc in the TPU lane dimension, which tiles
             # in units of 128 (measured 6x on the 10k-config sweep).
             chunk = _lane_align(chunk)
-            # Measured-peak refinement: a fitted plan model resolves
-            # chunk=0 through its sweep-phase HBM-peak sample instead
-            # of the static guess; no usable model keeps the static
-            # width bit-for-bit.
-            chunk, chunk_source = _plan_chunk(chunk, n_pad, P_pad)
             from pipelinedp_tpu import obs as _obs
             _obs.event("sweep.chunk_planned", chunk=int(chunk),
-                       source=chunk_source, rows=int(n_pad),
-                       partitions=int(P_pad))
+                       rows=int(n_pad), partitions=int(P_pad))
         if n_dev > 1:
             # Sharded over the mesh: every device takes an equal slice of
             # the chunk's configuration axis.
@@ -1327,41 +1315,22 @@ class LazySweepResult:
                     sel_cat[k[2:]] = v[:C]
             sel_cat = sel_cat or None
         else:
-            out_cat = jax.tree.map(lambda *xs: jnp.concatenate(xs, 0),
-                                   *[o for o, _ in chunk_outs])
-            sel_cat = None
-            if chunk_outs[0][1] is not None:
-                sel_cat = jax.tree.map(
-                    lambda *xs: jnp.concatenate(xs, 0),
-                    *[s for _, s in chunk_outs])
             # ONE flat d2h transfer for every output field of every
             # chunk.
-            leaves, treedef = jax.tree.flatten((out_cat, sel_cat))
-            shapes = [l.shape for l in leaves]
-            flat = np.asarray(jnp.concatenate([l.ravel()
-                                               for l in leaves]))
-            split, off = [], 0
-            for s in shapes:
-                size = int(np.prod(s))
-                split.append(flat[off:off + size].reshape(s)[:C])
-                off += size
-            out_cat, sel_cat = jax.tree.unflatten(treedef, split)
+            treedef = jax.tree.structure(chunk_outs[0])
+            fields_cat = _fetch_concat(
+                [jax.tree.leaves(c) for c in chunk_outs], 0)
+            out_cat, sel_cat = jax.tree.unflatten(
+                treedef, [f[:C] for f in fields_cat])
         fields = {nm: out_cat[nm] for nm in metric_names}
         sel_fields = sel_cat
 
         if per_partition:
             keys = sorted(pp_chunks[0])
-            cat = {k: jnp.concatenate([c[k] for c in pp_chunks], axis=1)
-                   for k in keys}
             # One flat d2h transfer for all [P_pad, C] blocks.
-            flat_pp = np.asarray(
-                jnp.concatenate([cat[k].ravel() for k in keys]))
-            blocks, off = {}, 0
-            for k in keys:
-                size = int(np.prod(cat[k].shape))
-                blocks[k] = flat_pp[off:off + size].reshape(
-                    cat[k].shape)[:P, :C]
-                off += size
+            cat = _fetch_concat([[c[k] for k in keys] for c in pp_chunks],
+                                1)
+            blocks = {k: b[:P, :C] for k, b in zip(keys, cat)}
             users_np = np.asarray(users_in)[:P]
             mask_np = (users_np > 0) | (public & (users_np == 0))
             self._pp_rows = self._assemble_pp(

@@ -69,7 +69,7 @@ class DPEngine:
                                  reset_reports: bool = True) -> None:
         """Resident-service seam: swap in a fresh per-request budget
         accountant so a warm engine (and with it the backend's jitted
-        programs and the planner's resolved knob vector) serves many
+        programs) serves many
         requests instead of one. Batch mode never calls this — an
         engine built the classic way keeps its one accountant for life.
 

@@ -1424,12 +1424,12 @@ def _node_noise(noise_kind: NoiseKind, key, node_ids, pk_index=None):
 # block-by-block (bit-identical to the unchunked walk — node noise is a
 # pure function of (partition, node id)). Registered as the
 # ``subhist_byte_cap`` knob; reads flow through ``plan.knobs`` (env >
-# this seam when test-mutated > plan file > this default) and the
-# module name survives as the test seam (``make noknobs``).
+# this seam when test-mutated > this default) and the module name
+# survives as the test seam (``make noknobs``).
 _SUBHIST_BYTE_CAP = 600 << 20
 
 #: The ``vector_accumulator`` knob's module seam (plan/knobs.py
-#: registers it, dp-UNSAFE — never planned): VECTOR_SUM's 'f32' vs
+#: registers it, dp-unsafe): VECTOR_SUM's 'f32' vs
 #: 'fx' accumulator discipline, resolved onto FusedConfig at
 #: from_params time.
 _VECTOR_ACCUMULATOR = "f32"
@@ -1538,11 +1538,10 @@ def _percentile_values(config: FusedConfig, P, qrows, scale, key):
         # the recorded choice with it.
         from pipelinedp_tpu import obs
         from pipelinedp_tpu import plan as plan_mod
-        # The execution planner's resolution of the subhist byte cap
-        # (env > test seam > plan file > default; the module constant
-        # survives as the seam). Host code at jit-trace time, so the
-        # choice is recorded once per compiled shape like the walk
-        # path itself.
+        # The registry's resolution of the subhist byte cap (env > test
+        # seam > default; the module constant survives as the seam).
+        # Host code at jit-trace time, so the choice is recorded once
+        # per compiled shape like the walk path itself.
         subhist_cap = int(plan_mod.knob_value("subhist_byte_cap"))
         if P * Q * span * 4 <= subhist_cap:
             obs.inc("walk.path_subhist")
@@ -2599,18 +2598,6 @@ class LazyFusedResult:
             _maybe_append_run_ledger(mesh=self._mesh)
             return out
 
-        # The execution planner's resolution for THIS single-batch
-        # request (streamed requests resolve inside
-        # stream_partials_and_select): the plan.applied events and the
-        # run report's plan section exist for every request, and the
-        # walk's mid-request cap read (knob_value at jit-trace time)
-        # buckets at this request's shape instead of a stale previous
-        # request's.
-        from pipelinedp_tpu import plan as _plan_mod
-        _plan_mod.resolve(
-            shape={"rows": int(encoded.n_rows), "partitions": int(P),
-                   "quantiles": len(config.percentiles or ())},
-            mesh=self._mesh)
         with tr.span("engine.device", cat="engine", path="single_batch"):
             keep_pk, raw, fx_bits = _run_fused_kernel(
                 config, encoded, scales, keep_table, thr, s_scale,
@@ -2880,8 +2867,8 @@ def _run_fused_kernel(config: FusedConfig, encoded: EncodedData, scales,
     else:
         fx_bits = 12
     # The kernel-backend knob resolves HERE, outside jit, and rides in
-    # as a static argument: jit caches by signature, so an env/seam/
-    # plan switch between calls re-traces instead of silently reusing
+    # as a static argument: jit caches by signature, so an env or seam
+    # switch between calls re-traces instead of silently reusing
     # the other backend's program (and the cost observatory's table
     # keys the two signatures apart for before/after verdicts).
     from pipelinedp_tpu import plan as plan_mod

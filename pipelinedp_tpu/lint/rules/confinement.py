@@ -194,16 +194,15 @@ class NoPerfRule(Rule):
 
 
 class NoArtifactsRule(Rule):
-    """No ad-hoc ``json.dump`` file writes outside obs/ and plan/."""
+    """No ad-hoc ``json.dump`` file writes outside obs/."""
 
     id = "noartifacts"
     legacy_target = "noartifacts"
     invariant = ("run knowledge lands in the schema-versioned "
-                 "report/store/plan, never scattered one-off JSON "
+                 "report/store, never scattered one-off JSON "
                  "files (bench.py is the one blessed artifact emitter)")
-    fix_hint = ("route through pipelinedp_tpu.obs (report/store) or "
-                "pipelinedp_tpu.plan (the atomic plan file)")
-    blessed = ("pipelinedp_tpu/obs/", "pipelinedp_tpu/plan/")
+    fix_hint = "route through pipelinedp_tpu.obs (report/store)"
+    blessed = ("pipelinedp_tpu/obs/",)
     scans_bench = False  # bench.py is the blessed emitter
 
     def check(self, ctx):
@@ -245,11 +244,11 @@ class NoKnobsRule(Rule):
     id = "noknobs"
     legacy_target = "noknobs"
     invariant = ("every knob consumer resolves through plan.knobs "
-                 "(env > seam > plan file > default) so an autotuned "
-                 "plan can steer the value and the resolution lands in "
-                 "the run report's plan section")
-    fix_hint = ("resolve through pipelinedp_tpu.plan (knobs.value / "
-                "resolve / seam_override)")
+                 "(env > seam > default) so an env override or a test "
+                 "seam reaches every reader and the resolution lands "
+                 "in the run report's plan section")
+    fix_hint = ("resolve through pipelinedp_tpu.plan (knob_value / "
+                "knobs.resolve_all / seam_override)")
     blessed = ("pipelinedp_tpu/plan/",)
     KNOB_CONSTANTS = frozenset({"_SUBHIST_BYTE_CAP",
                                 "_SELECT_UNITS_CAP",

@@ -4,7 +4,7 @@ A function dispatched through ``instrumented_jit``/``jax.jit`` runs
 its Python body ONCE per program signature; everything it reads from
 the environment — ``os.environ``, wall-clock ``time.*``, a knob
 constant — freezes into the compiled program and silently stops
-responding to the planner, the env, or the clock (the shape-blind
+responding to the env, a test seam or the clock (the shape-blind
 knob-read bug PR 9 fixed is this rule's seed fixture).  Values that
 must vary pass as (possibly static) arguments; values that must not
 vary don't belong in a traced body at all.
@@ -55,7 +55,7 @@ class JitStaticnessRule(Rule):
     legacy_target = None
     invariant = ("traced bodies read nothing ambient: os.environ, "
                  "time.*, and registered knob constants freeze at "
-                 "trace time and stop responding to the planner/env — "
+                 "trace time and stop responding to the env/seam — "
                  "pass them in as (static) arguments instead")
     fix_hint = ("hoist the read to the call site and pass it as an "
                 "argument (static_argnames if it shapes the program)")
@@ -109,7 +109,7 @@ class JitStaticnessRule(Rule):
                     # batch recompiles.
                     yield (node.lineno,
                            f"knobs.value read inside traced "
-                           f"'{fn.name}' freezes the planner's value "
+                           f"'{fn.name}' freezes the knob's value "
                            "at trace time")
             name = None
             if isinstance(node, ast.Name):
@@ -121,4 +121,4 @@ class JitStaticnessRule(Rule):
                                    ast.Load)):
                 yield (node.lineno,
                        f"knob constant {name} read inside traced "
-                       f"'{fn.name}' freezes the planner's value")
+                       f"'{fn.name}' freezes the knob's value")

@@ -168,16 +168,13 @@ def sample(name: str, value: float) -> None:
 
 def reset() -> None:
     """Start a fresh ledger AND audit registry AND device-cost table
-    AND planner applied-state (tests; bench run boundaries)."""
+    (tests; bench run boundaries)."""
     _LEDGER.reset()
     audit.reset()
     costs.reset()
     metrics.reset()
     store.reset_run_report_cursor()
     monitor.reset_requests()
-    # Lazy: plan imports obs, so a module-level import would cycle.
-    from pipelinedp_tpu import plan as _plan
-    _plan.reset()
 
 
 def build_run_report(mesh=None, extra: Optional[Dict[str, Any]] = None,

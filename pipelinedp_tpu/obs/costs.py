@@ -129,8 +129,7 @@ _FRESH_COMPILE = {"xla_gpu_enable_fast_min_max": False}
 #: are datasheet numbers (dense bf16 FLOP/s; HBM bandwidth); the CPU
 #: row is an order-of-magnitude PROXY (one desktop core's vector units
 #: and DDR channel) — good enough to rank programs against each other,
-#: NOT a calibrated machine model; verdicts carry ``proxy: true`` so
-#: downstream consumers (the autotune planner) can weight them.
+#: NOT a calibrated machine model; verdicts carry ``proxy: true``.
 DEVICE_PEAKS: Tuple[Dict[str, Any], ...] = (
     {"match": ("v5 lite", "v5lite", "v5e"),
      "kind": "tpu_v5e", "flops_per_s": 197e12,

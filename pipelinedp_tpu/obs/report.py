@@ -37,11 +37,11 @@ from pipelinedp_tpu.obs import costs as _costs
 #: compile wall/cache verdict, flops/bytes, memory stats, per-phase
 #: roofline verdicts — ``obs.costs``); absent in v1/v2 reports, which
 #: readers treat as "device costs not captured".
-#: v4 (execution-planner PR): adds the ``plan`` section (the resolved
-#: knob vector with per-knob source env/seam/plan/default, the plan
-#: file hash, predicted vs observed seconds — ``pipelinedp_tpu.plan``);
-#: absent in v1–v3 reports AND in v4 runs that resolved no knobs,
-#: which readers treat as "default knobs, no plan in force".
+#: v4 (execution-knob PR): adds the ``plan`` section — ``{"knobs":
+#: {name: {"value", "source"}}}``, every registered knob with the
+#: source it resolved from (env / seam / default —
+#: ``pipelinedp_tpu.plan.knobs``); absent in v1–v3 reports, which
+#: readers treat as "default knobs".
 #: v5 (sketch-first PR): adds the ``sketch`` section (per sketch-first
 #: phase-1 run: width/depth/cap/backend, selection budget + threshold,
 #: bucket pre/post and candidate counts — ``obs.audit.record_sketch``);
@@ -183,17 +183,13 @@ def build_run_report(snapshot: Dict[str, Any], mesh=None,
     device_costs = _costs.TABLE.snapshot()
     if device_costs["programs"]:
         report["device_costs"] = device_costs
-    # v4: the execution planner's resolved knob vector — included
-    # whenever a request resolved knobs this run (absent = default
-    # knobs / no plan, the v1–v3-compatible reading). Lazy import:
-    # ``plan`` imports obs, so a module-level import here would cycle.
-    try:
-        from pipelinedp_tpu import plan as _plan
-        plan_section = _plan.snapshot()
-    except Exception:
-        plan_section = None
-    if plan_section:
-        report["plan"] = plan_section
+    # v4: the resolved knob vector and each knob's source. Lazy import:
+    # the knobs' seam modules import obs, so a module-level import
+    # here would cycle.
+    from pipelinedp_tpu.plan import knobs as _knobs
+    report["plan"] = {"knobs": {
+        name: {"value": v, "source": src}
+        for name, (v, src) in sorted(_knobs.resolve_all().items())}}
     # v6: raw span dicts for every span a request trace context stamped
     # (absent = no request-scoped tracing, the v1–v5-compatible
     # reading). The ``spans`` section above is a per-NAME summary;

@@ -1,9 +1,9 @@
 """Durable cross-run ledger store: append-only JSONL of run reports.
 
 PR 4 made one run self-describing; this store makes that knowledge
-**outlive the process** — the substrate the self-tuning planner ("fit
-from accumulated run reports, persisted next to the compile cache") and
-the long-lived DP service's per-request audit records both build on.
+**outlive the process** — the substrate the long-lived DP service's
+per-request audit records and the ``--summarize`` trend tables build
+on.
 
 One entry per line::
 
@@ -307,12 +307,11 @@ def _mesh_env_key(mesh) -> Any:
 def entries_since_run_id(entries: List[Dict[str, Any]],
                          run_id: str) -> List[Dict[str, Any]]:
     """The suffix of ``entries`` starting at the FIRST entry tagged
-    with ``run_id`` — the ``--since-run-id`` window. The autotune
-    fitter uses it (and :meth:`LedgerStore.read_from`) to fit from
-    post-sweep entries instead of the whole history: a long-lived
-    service ledger grows linearly, and fitting must not go quadratic.
-    An unknown run id windows to nothing (an honest empty answer
-    beats silently fitting the full ledger)."""
+    with ``run_id`` — the ``--since-run-id`` window: a long-lived
+    service ledger grows linearly, so a reader summarizes the entries
+    after one run instead of the whole history. An unknown run id
+    windows to nothing (an honest empty answer beats silently
+    summarizing the full ledger)."""
     for i, e in enumerate(entries):
         if e.get("run_id") == run_id:
             return entries[i:]
@@ -374,9 +373,8 @@ def _trend(samples: List[float]) -> Optional[float]:
 def summarize_entries(entries: List[Dict[str, Any]]
                       ) -> Dict[str, Dict[str, Any]]:
     """Aggregate accumulated ledger entries into per-(fingerprint,
-    phase) cost tables with trend deltas — the raw material the future
-    autotune planner consumes (ROADMAP: "fit from accumulated run
-    reports"). Two tables per fingerprint:
+    phase) cost tables with trend deltas. Three tables per
+    fingerprint:
 
     * ``phases`` — from every ``run_report`` entry's span summary:
       per span name, how many reports carried it, summed/mean/latest
@@ -388,9 +386,8 @@ def summarize_entries(entries: List[Dict[str, Any]]
     * ``programs`` — from every schema-v3 report's ``device_costs``
       section: per (program, abstract-shape signature), compile-wall
       samples with trend, the latest flops/bytes/intensity/roofline
-      verdict and cache verdict — the cost/roofline columns the
-      planner fits against (v1/v2 entries simply contribute no rows
-      here).
+      verdict and cache verdict (v1/v2 entries simply contribute no
+      rows here).
     """
     out: Dict[str, Dict[str, Any]] = {}
     for e in entries:
@@ -492,8 +489,7 @@ def _fmt_trend(trend: Optional[float]) -> str:
 
 #: The flat CSV schema ``--csv`` emits: one row per (fingerprint, kind,
 #: name) where kind is phase / metric / program; columns that don't
-#: apply to a kind stay empty. One parse-free table for spreadsheets
-#: and planner fitting.
+#: apply to a kind stay empty. One parse-free table for spreadsheets.
 CSV_COLUMNS = ("fingerprint", "kind", "name", "samples", "total_s",
                "mean_s", "latest_s", "best", "latest", "trend",
                "compile_s_mean", "compile_s_latest", "compile_cache",
@@ -692,11 +688,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     parser.add_argument("--since-run-id", default=None,
                         dest="since_run_id",
                         help="window to entries at/after the first "
-                        "one tagged with this run id (the autotune "
-                        "fitter's post-sweep window)")
+                        "one tagged with this run id")
     parser.add_argument("--json", action="store_true", dest="as_json",
-                        help="machine-readable output (the autotune "
-                        "planner's input shape)")
+                        help="machine-readable output")
     parser.add_argument("--csv", action="store_true", dest="as_csv",
                         help="flat CSV table (phases, metrics, program "
                         "cost/roofline columns) for spreadsheets")

@@ -30,7 +30,7 @@ hand-tiled alternatives:
   Same exactness bound, same bit-identity (PARITY row 39).
 
 Dispatch is the ``kernel_backend`` knob (``plan/knobs.py``: env >
-seam > plan file > default, default ``xla`` — cold start is
+seam > default, default ``xla`` — cold start is
 byte-identical to the XLA path). The knob is dp-safe because both
 kernels produce bit-identical integers (PARITY row 33); shapes outside
 the tiled envelope, or a host without Pallas, fall back to XLA with a

@@ -67,7 +67,7 @@ _D_BLOCKS = (512, 256, 128)
 
 #: The ``segsum_wide_d_block`` knob's module seam (plan/knobs.py
 #: registers it): 0 means "envelope picks the widest tile"; a nonzero
-#: in-envelope value pins the D tile (the autotune sweep's axis).
+#: in-envelope value pins the D tile.
 _WIDE_D_BLOCK = 0
 
 #: Test seam: force ``pallas_available()`` to answer False, exercising

@@ -1,54 +1,22 @@
-"""Self-tuning execution planner: the measure→decide loop, closed.
+"""The execution-knob registry.
 
-The obs stack measures (spans, device costs, the durable run ledger);
-this package decides: the knob forest that used to be hand-set per
-device — ``_SUBHIST_BYTE_CAP``, pass-B tile packing, stream cache
-bytes, ``q_chunk``, the ingest-executor switch — resolves through one
-registry, optionally steered by a ledger-fit cost model persisted as
-a plan file next to the compile cache.
-
-* :mod:`~pipelinedp_tpu.plan.knobs` — the registry: every tunable's
-  unit, hardcoded default, env override, module seam and dp-safety,
-  plus the ONE resolution precedence (env > seam > plan > default).
-* :mod:`~pipelinedp_tpu.plan.model` — a stdlib-only cost model fit
-  from accumulated ledger entries: per (device kind, phase,
-  shape-signature bucket), predicted device seconds and HBM peak from
-  (rows, partitions, quantiles), falling back to the static roofline
-  peak table; empty/degraded/foreign-fingerprint history predicts
-  nothing and leaves the defaults in force.
-* :mod:`~pipelinedp_tpu.plan.planner` — the plan file (atomic JSON
-  next to the compile cache, keyed by the stable env-fingerprint
-  hash; stale fingerprints ignored with a ``plan.stale`` event) and
-  per-request :func:`resolve` (one ``plan.applied`` event per knob,
-  the run report's schema-v4 ``plan`` section).
-
-``bench.py --autotune`` runs the bounded sweep that writes the plan;
-a subsequent plain run loads it. Planner on vs off is DP-bit-identical
-(PARITY row 32): plans only select among parity-tested paths.
+Every execution choice that used to be a hand-set module constant or
+env var — ``_SUBHIST_BYTE_CAP``, pass-B tile packing, stream cache
+bytes, ``q_chunk``, the ingest-executor switch, ... — resolves through
+one registry, :mod:`~pipelinedp_tpu.plan.knobs`: per knob its unit,
+hardcoded default, env override, module seam and dp-safety, plus the
+ONE resolution precedence (env > seam > default).
 """
 
 from __future__ import annotations
 
-from pipelinedp_tpu.plan import knobs, model, planner
+from pipelinedp_tpu.plan import knobs
 from pipelinedp_tpu.plan.knobs import (KnobSpec, REGISTRY, defaults,
                                        resolve_all, seam_override)
 from pipelinedp_tpu.plan.knobs import value as knob_value
-from pipelinedp_tpu.plan.model import CostModel, bucket_key, fit
-from pipelinedp_tpu.plan.planner import (Resolved, autotune_candidates,
-                                         build_plan, load_plan,
-                                         note_observed, plan_dir,
-                                         plan_hash, plan_path, reset,
-                                         resolve, set_default_dir,
-                                         snapshot, source_summary,
-                                         write_plan)
 
 __all__ = [
-    "knobs", "model", "planner",
+    "knobs",
     "KnobSpec", "REGISTRY", "defaults", "resolve_all", "seam_override",
     "knob_value",
-    "CostModel", "bucket_key", "fit",
-    "Resolved", "autotune_candidates", "build_plan", "load_plan",
-    "note_observed", "plan_dir", "plan_hash", "plan_path", "reset",
-    "resolve", "set_default_dir", "snapshot", "source_summary",
-    "write_plan",
 ]

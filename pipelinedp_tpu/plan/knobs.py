@@ -1,39 +1,29 @@
-"""The knob registry: every planner-visible tunable, in ONE place.
+"""The knob registry: every execution knob, in ONE place.
 
 The stack grew a forest of hand-set execution knobs — HBM byte caps,
 stream batch sizing, cache budgets, the ingest-executor switch — each
 living as a module constant or an env var at its point of use. This
 module is the single registry over them: one :class:`KnobSpec` per
 knob recording its unit, hardcoded default, env-override name, module
-seam (the test-injectable constant) and whether a plan file may change
-it, plus the one resolution function every consumer goes through.
+seam (the test-injectable constant) and whether it only chooses
+between bit-identical execution paths, plus the one resolution
+function every consumer goes through.
 
 Resolution precedence (most explicit wins)::
 
-    explicit env override  >  test-seam mutation  >  plan file  >  default
+    explicit env override  >  test-seam mutation  >  default
 
 * **env** — the knob's ``PIPELINEDP_TPU_*`` variable is set (any
   value, including the default: setting it is the explicit act).
 * **seam** — the module constant (``je._SUBHIST_BYTE_CAP``,
   ``streaming._SELECT_UNITS_CAP``, ...) differs from the registered
   default. Tests and bench inject caps by mutating these (via
-  :func:`seam_override`); a mutated seam must outrank any plan file or
-  existing suites would silently run planned values.
-* **plan** — the loaded plan file carries the knob AND the knob is
-  ``dp_safe``: a plan may only select among execution paths that are
-  bit-parity-tested (PARITY row 32). ``stream_chunk_rows`` is NOT
-  dp-safe — batch membership decides which rows a unit's bounding
-  subsample sees, so replanning it would change DP outputs — and the
-  int32 guard caps are refusal thresholds, not performance choices;
-  plan values for non-dp-safe knobs are ignored with a
-  ``plan.skipped_dp_unsafe`` event.
-* **default** — today's hardcoded value, byte-for-byte: cold start
-  (empty ledger, no plan file, no env) resolves to exactly the
-  pre-planner behavior.
+  :func:`seam_override`).
+* **default** — the hardcoded value.
 
 Direct reads of the registered constants outside this package are
 banned (``make noknobs`` + the AST twin in ``tests/test_plan.py``);
-consumers call :func:`value` / ``plan.resolve()`` instead, and the
+consumers call :func:`value` / :func:`resolve_all` instead, and the
 module-level names survive purely as test seams.
 """
 
@@ -56,7 +46,10 @@ class KnobSpec:
     default: Any                    #: today's hardcoded default
     env_var: Optional[str]          #: explicit-override env name
     seam: Optional[Tuple[str, str]]  #: (module, attr) test seam
-    dp_safe: bool                   #: may a plan file change it?
+    #: True when every value selects among bit-identical execution
+    #: paths (PARITY rows 3, 11, 33, 35, 36, 39, 41, 43): a pure
+    #: performance choice, so a fork behind it may be deleted outright.
+    dp_safe: bool
     kind: type                      #: int, bool or str (enumerated)
     doc: str
     choices: Tuple[str, ...] = ()   #: legal values for str knobs
@@ -68,9 +61,8 @@ class KnobSpec:
             return bool(raw)
         if self.kind is str:
             # Enumerated string knobs (kernel_backend): an unknown
-            # value — a typo'd env var, a plan from a future schema —
-            # resolves to the default rather than crashing a request
-            # over a performance choice.
+            # value — a typo'd env var — resolves to the default
+            # rather than crashing a request over a performance choice.
             v = str(raw).strip().lower()
             return v if v in self.choices else self.default
         return int(raw)
@@ -78,13 +70,9 @@ class KnobSpec:
 
 _I32_MAX = int(np.iinfo(np.int32).max)
 
-#: (knob, value) pairs whose plan.skipped_dp_unsafe event already
-#: fired — cleared by planner.reset() at run boundaries.
-_dp_unsafe_seen: set = set()
-
 #: The registry. Units and defaults are the documentation of record
-#: (mirrored in README "Execution planner"); ``seam`` names the
-#: module constant kept alive as the test seam.
+#: (mirrored in README "Execution knobs"); ``seam`` names the module
+#: constant kept alive as the test seam.
 REGISTRY: Tuple[KnobSpec, ...] = (
     KnobSpec(
         "subhist_byte_cap", "bytes", 600 << 20,
@@ -101,14 +89,13 @@ REGISTRY: Tuple[KnobSpec, ...] = (
         "PIPELINEDP_TPU_STREAM_CHUNK", None, False, int,
         "Rows per streamed device batch (and the engine's streaming "
         "trigger). NOT dp-safe: batch membership decides which rows a "
-        "privacy unit's bounding subsample sees, so a plan never "
-        "changes it — env override and default only."),
+        "privacy unit's bounding subsample sees."),
     KnobSpec(
         "stream_cache_bytes", "bytes", 4 << 30,
         "PIPELINEDP_TPU_STREAM_CACHE", None, True, int,
         "Per-device HBM budget for the pass-B prefix cache (0 "
         "disables). device_cache / hybrid / reship are bit-identical "
-        "(PARITY row 3), so the plan may trade HBM for link traffic."),
+        "(PARITY row 3): a trade of HBM for link traffic only."),
     KnobSpec(
         "ingest_executor", "bool", True,
         "PIPELINEDP_TPU_INGEST_EXECUTOR", None, True, bool,
@@ -160,8 +147,8 @@ REGISTRY: Tuple[KnobSpec, ...] = (
         "runtime inputs. 0 lets the driver pick the widest chunk inside "
         "the HBM row-broadcast and selection-window budgets. dp-safe: "
         "every batch width is bit-identical per config (PARITY row 41 — "
-        "padding-invariant, walked == batched), so --autotune may sweep "
-        "it. Note the sweep checkpoint fingerprint covers the width: a "
+        "padding-invariant, walked == batched). Note the sweep "
+        "checkpoint fingerprint covers the width: a "
         "resume must run the same batch width it was killed at."),
     KnobSpec(
         "vector_accumulator", "f32 | fx", "f32",
@@ -175,8 +162,7 @@ REGISTRY: Tuple[KnobSpec, ...] = (
         "lane sums, float64 host reassembly — exact, backend- and "
         "mesh-bit-identical, the wide-D Pallas kernel's operand). NOT "
         "dp-safe: the two accumulators release different floats (fx "
-        "quantizes at the clip bound), so a plan never flips it — env "
-        "override, test seam and default only.",
+        "quantizes at the clip bound).",
         choices=("f32", "fx")),
     KnobSpec(
         "serve_fusion", "bool", False,
@@ -218,8 +204,8 @@ REGISTRY: Tuple[KnobSpec, ...] = (
         "PIPELINEDP_TPU_SKETCH_WIDTH", None, False, int,
         "Buckets per counting-sketch row in the sketch-first path "
         "(sketch/). NOT dp-safe: the bucket grid decides which keys "
-        "become candidates, so a plan never changes it — env override, "
-        "explicit SketchParams and default only. Rounded up to a "
+        "become candidates. Explicit SketchParams outrank the "
+        "registry. Rounded up to a "
         "multiple of 256 on device (the matmul binner's radix width)."),
     KnobSpec(
         "sketch_depth", "sketch rows (hash remixes)", 2,
@@ -240,8 +226,8 @@ REGISTRY: Tuple[KnobSpec, ...] = (
         "Device formulation of the sketch binner: 'matmul' (radix "
         "one-hot MXU contraction, sketch/device.py — the default) or "
         "'xla' (the scatter-add reference). dp-safe: both are exact "
-        "integer arithmetic and bit-identical (PARITY row 36), so the "
-        "autotune sweep may measure either. Like the serve knobs, no "
+        "integer arithmetic and bit-identical (PARITY row 36). Like the "
+        "serve knobs, no "
         "module seam — SketchParams.backend is the injection point, so "
         "resolving the registry never imports sketch/ into non-sketch "
         "runs.", choices=("matmul", "xla")),
@@ -270,15 +256,15 @@ REGISTRY: Tuple[KnobSpec, ...] = (
         False, int,
         "int32 guard cap: privacy units per partition at streamed "
         "selection time. A refusal threshold, not a performance "
-        "choice — never planned; the seam exists so boundary tests "
+        "choice; the seam exists so boundary tests "
         "can pin the exact cliff."),
     KnobSpec(
         "tree_rows_cap", "kept rows per partition", _I32_MAX,
         None, ("pipelinedp_tpu.streaming", "_TREE_ROWS_CAP"),
         False, int,
         "int32 guard cap: kept rows per partition in the streamed "
-        "percentile tree histograms. A refusal threshold — never "
-        "planned; seam for boundary tests."),
+        "percentile tree histograms. A refusal threshold; seam for "
+        "boundary tests."),
 )
 
 BY_NAME: Dict[str, KnobSpec] = {spec.name: spec for spec in REGISTRY}
@@ -289,13 +275,9 @@ def _seam_value(spec: KnobSpec) -> Any:
     return getattr(mod, spec.seam[1])
 
 
-def resolve_value(spec: KnobSpec,
-                  plan_knobs: Optional[Dict[str, Any]] = None
-                  ) -> Tuple[Any, str]:
-    """(value, source) for one knob under the registry precedence.
-    ``plan_knobs`` is the knob dict of an already-validated plan file
-    (None: no plan in force). Source is one of ``env`` / ``seam`` /
-    ``plan`` / ``default``."""
+def resolve_value(spec: KnobSpec) -> Tuple[Any, str]:
+    """(value, source) for one knob under the registry precedence;
+    source is one of ``env`` / ``seam`` / ``default``."""
     if spec.env_var is not None:
         raw = os.environ.get(spec.env_var)
         if raw is not None and raw != "":
@@ -304,34 +286,12 @@ def resolve_value(spec: KnobSpec,
         current = _seam_value(spec)
         if current != spec.default:
             return current, "seam"
-    if plan_knobs is not None and spec.name in plan_knobs:
-        if spec.dp_safe:
-            return spec.parse(plan_knobs[spec.name]), "plan"
-        # Once per (knob, offending value) observation — resolution
-        # runs on every knob read, and re-emitting per read would
-        # flood the bounded obs event ring (same dedup contract as
-        # plan.stale).
-        skip_key = (spec.name, repr(plan_knobs[spec.name]))
-        if skip_key not in _dp_unsafe_seen:
-            _dp_unsafe_seen.add(skip_key)
-            from pipelinedp_tpu import obs
-            obs.event("plan.skipped_dp_unsafe", knob=spec.name,
-                      plan_value=plan_knobs[spec.name])
     return spec.default, "default"
 
 
-def value(name: str, plan_knobs: Optional[Dict[str, Any]] = None) -> Any:
-    """The resolved value of one knob (see :func:`resolve_value`).
-    With ``plan_knobs`` omitted the current plan file (if any) is
-    consulted through the planner's cached load, bucketed at the last
-    resolved request shape — so a mid-request read (the walk's cap at
-    jit-trace time) sees the same vector the request resolved."""
-    spec = BY_NAME[name]
-    if plan_knobs is None:
-        from pipelinedp_tpu.plan import planner
-        plan_knobs = planner.current_plan_knobs(
-            planner.last_resolved_shape())
-    return resolve_value(spec, plan_knobs)[0]
+def value(name: str) -> Any:
+    """The resolved value of one knob (see :func:`resolve_value`)."""
+    return resolve_value(BY_NAME[name])[0]
 
 
 def defaults() -> Dict[str, Any]:
@@ -339,18 +299,15 @@ def defaults() -> Dict[str, Any]:
     return {spec.name: spec.default for spec in REGISTRY}
 
 
-def resolve_all(plan_knobs: Optional[Dict[str, Any]] = None
-                ) -> Dict[str, Tuple[Any, str]]:
+def resolve_all() -> Dict[str, Tuple[Any, str]]:
     """{name: (value, source)} for every registered knob."""
-    return {spec.name: resolve_value(spec, plan_knobs)
-            for spec in REGISTRY}
+    return {spec.name: resolve_value(spec) for spec in REGISTRY}
 
 
 @contextlib.contextmanager
 def seam_override(name: str, value: Any):
     """Temporarily set a knob's module seam (the blessed injection
-    idiom for tests and bench probe records — a mutated seam outranks
-    any plan file, so injected-cap records measure the injected cap)."""
+    idiom for tests and bench probe records)."""
     spec = BY_NAME[name]
     if spec.seam is None:
         raise ValueError(f"knob {name!r} has no module seam")

@@ -558,14 +558,6 @@ class Fuser:
                             ctx.pending.lease.request_id):
                         results_by_ctx = {id(ctx): list(ctx.lazy)}
             else:
-                # The planner resolution for this fused batch: one
-                # resolve at the bucket shape (plan.applied events and
-                # the walk's trace-time cap reads bucket here).
-                plan_mod.resolve(
-                    shape={"rows": int(key.rows),
-                           "partitions": int(key.partitions),
-                           "quantiles": len(config.percentiles or ())},
-                    mesh=None)
                 kernel_backend = str(
                     plan_mod.knob_value("kernel_backend"))
                 if kernel_backend == "pallas" and config.percentiles:

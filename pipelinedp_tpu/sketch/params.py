@@ -7,16 +7,16 @@ The fields split into two tiers:
   ``NaiveBudgetAccountant`` and audited like every other mechanism),
   ``width``/``depth``/``candidate_cap``/``max_buckets_contributed``
   (they change which buckets are selected and therefore which keys the
-  exact pass can release — the planner treats the corresponding knobs
-  as dp-UNSAFE, same class as ``stream_chunk_rows``).
+  exact pass can release — the registry marks the corresponding knobs
+  dp-unsafe, same class as ``stream_chunk_rows``).
 * **Execution choices** — ``backend`` (the one-hot-matmul binner vs
   the XLA scatter reference, bit-identical by construction: PARITY
   row 36) and ``chunk_rows`` (device batch sizing of the bounded-pair
   stream; the sketch is a sum, so chunking is associativity-exact).
 
-Fields left ``None`` resolve through the planner registry
+Fields left ``None`` resolve through the knob registry
 (``plan/knobs.py``: ``sketch_width`` / ``sketch_depth`` /
-``sketch_candidate_cap`` / ``sketch_backend``, env > plan > default).
+``sketch_candidate_cap`` / ``sketch_backend``, env > default).
 Like the serve knobs, the sketch knobs carry no module seam —
 ``SketchParams`` itself is the injection point — so resolving the
 registry never imports this package into non-sketch runs.
@@ -106,7 +106,7 @@ class SketchParams:
         if self.chunk_rows <= 0:
             raise ValueError("SketchParams.chunk_rows must be positive")
 
-    # --- knob resolution (explicit param > planner registry) ---
+    # --- knob resolution (explicit param > knob registry) ---
 
     def _knob(self, explicit, knob_name: str):
         if explicit is not None:

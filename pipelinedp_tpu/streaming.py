@@ -81,8 +81,7 @@ from pipelinedp_tpu.ops.segment import fmix32
 #: Rows per device batch (and the engine's streaming trigger: pipelines
 #: with more rows than one chunk stream). Overridable for tests and for
 #: hosts with small HBM. Registered as the ``stream_chunk_rows`` knob
-#: (NOT dp-safe: batch membership decides a unit's bounding subsample,
-#: so a plan file never changes it — env override and default only).
+#: (NOT dp-safe: batch membership decides a unit's bounding subsample).
 _CHUNK_ENV = "PIPELINEDP_TPU_STREAM_CHUNK"
 
 
@@ -95,7 +94,7 @@ def stream_chunk_rows() -> int:
 #: pass B re-reads them from HBM instead of re-shipping every byte over
 #: the host link. 0 disables the cache. Registered as the
 #: ``stream_cache_bytes`` knob (dp-safe: all three pass-B sources are
-#: bit-identical, so a plan may trade HBM for link traffic).
+#: bit-identical, so the budget trades HBM for link traffic only).
 _CACHE_ENV = "PIPELINEDP_TPU_STREAM_CACHE"
 
 
@@ -112,8 +111,8 @@ def stream_cache_bytes() -> int:
 #: selection time; ``_TREE_ROWS_CAP``: kept rows per partition in the
 #: streamed percentile tree histograms. Registered knobs
 #: (``select_units_cap`` / ``tree_rows_cap``); refusal thresholds, not
-#: performance choices — a plan file never changes them. Reads flow
-#: through ``plan.knobs`` (``make noknobs``); the names stay as seams.
+#: performance choices. Reads flow through ``plan.knobs``
+#: (``make noknobs``); the names stay as seams.
 _SELECT_UNITS_CAP = int(np.iinfo(np.int32).max)
 _TREE_ROWS_CAP = int(np.iinfo(np.int32).max)
 
@@ -330,7 +329,7 @@ def plan_pass_b_sweeps(P_pad, Q, span, cap, q_chunk=0) -> PassBPlan:
     tie-breaking toward fewer tiles (fewer scatters + walk launches),
     then larger partition blocks (the historical per-tile shapes, so
     the non-packable regimes keep their exact old round structure).
-    A positive ``q_chunk`` (the execution planner's knob) pins the
+    A positive ``q_chunk`` (the registry's knob) pins the
     quantile-group width instead of searching it — every tiling is
     bit-identical, so the pin is a pure performance choice; an
     infeasible pin falls back to the full search. Past the cap,
@@ -800,27 +799,20 @@ def _stream_impl(config, encoded, scales, keep_table,
     # no-op and the spans below cost exactly what they did before.
     obs.monitor.maybe_start()
 
-    # The execution planner: resolve the full knob vector for THIS
-    # request shape (env > seam > plan file > default — plan/knobs.py)
-    # and record it (one plan.applied event per knob; the run report's
-    # schema-v4 "plan" section). Cold start — no plan file, no env —
-    # resolves byte-identically to the former hardcoded defaults, and
-    # a plan can only move dp-safe knobs (every one selects among
-    # bit-parity-tested paths: PARITY row 32).
-    from pipelinedp_tpu import plan as plan_mod
-    knob_plan = plan_mod.resolve(
-        shape={"rows": int(encoded.n_rows),
-               "partitions": len(encoded.pk_vocab),
-               "quantiles": len(config.percentiles or ())},
-        mesh=mesh)
+    # One knob vector for the whole run (env > seam > default —
+    # plan/knobs.py), taken once so pass A and pass B read the same
+    # values even if an env var or seam moves mid-run.
+    from pipelinedp_tpu.plan import knobs as knobs_mod
+    knob_vec = {name: v for name, (v, _)
+                in knobs_mod.resolve_all().items()}
 
-    use_executor = (bool(knob_plan.values["ingest_executor"])
+    use_executor = (bool(knob_vec["ingest_executor"])
                     if executor is None else bool(executor))
     # Resolved OUTSIDE jit and passed as a static argument to the
     # chunk kernels: jit caches by signature, so a backend switch
     # between requests re-traces instead of silently reusing the
     # other backend's compiled program.
-    kernel_backend = str(knob_plan.values["kernel_backend"])
+    kernel_backend = str(knob_vec["kernel_backend"])
     if mesh is not None and mesh.is_multi_process:
         # Multi-PROCESS meshes run the serial path: every process must
         # enqueue the same device work in the same order, and the
@@ -877,12 +869,12 @@ def _stream_impl(config, encoded, scales, keep_table,
         # the unchunked descent. Only a cap below a single [1, 1, span]
         # block (necessarily test-shrunken) is refused.
         _, _, _, span = _tree_consts()
-        subhist_cap = int(knob_plan.values["subhist_byte_cap"])
+        subhist_cap = int(knob_vec["subhist_byte_cap"])
         try:
             plan = plan_pass_b_sweeps(P_pad, len(config.percentiles),
                                       span, subhist_cap,
                                       q_chunk=int(
-                                          knob_plan.values["q_chunk"]))
+                                          knob_vec["q_chunk"]))
         except NotImplementedError:
             obs.inc("walk.path_streamed_refusal")
             obs.event("walk.fallback", path="streamed_refusal",
@@ -1064,7 +1056,7 @@ def _stream_impl(config, encoded, scales, keep_table,
     # hybrid source). A RESUMED run never caches: the skipped batch
     # prefix is absent, so a partial cache would silently drop those
     # rows from pass B.
-    cache_cap = (int(knob_plan.values["stream_cache_bytes"])
+    cache_cap = (int(knob_vec["stream_cache_bytes"])
                  if cache_bytes is None else int(cache_bytes))
     cache: Optional[list] = ([] if config.percentiles and
                              start_batch == 0 and cache_cap > 0
@@ -1454,7 +1446,7 @@ def _stream_impl(config, encoded, scales, keep_table,
     else:
         nseg = acc["privacy_id_count_raw"]
         if nseg.max(initial=0) >= int(
-                knob_plan.values["select_units_cap"]):
+                knob_vec["select_units_cap"]):
             raise NotImplementedError(
                 "more than 2^31 privacy units in one partition")
         # Selection never touches the percentile walk (that runs in
@@ -1497,7 +1489,7 @@ def _stream_impl(config, encoded, scales, keep_table,
         # a partition with >= 2^31 kept rows would wrap a bucket, so
         # guard on the exact host-side per-partition counts.
         if int(acc["count"].max(initial=0)) >= int(
-                knob_plan.values["tree_rows_cap"]):
+                knob_vec["tree_rows_cap"]):
             raise NotImplementedError(
                 "streamed percentiles: a partition holds >= 2^31 kept "
                 "rows — beyond the int32 tree-histogram capacity")
@@ -1681,8 +1673,8 @@ def _stream_impl(config, encoded, scales, keep_table,
                 obs.inc("stream.pass_b_tiles", len(sweep))
         stats["pass_b_rounds"] = plan.n_sweeps
         stats["pass_b_sweeps"] = plan.n_sweeps
-        # Pass-B wall seconds (sweep spans): the cost-model feature the
-        # autotune trials record alongside the pass-A breakdown.
+        # Pass-B wall seconds (sweep spans), beside the pass-A
+        # breakdown in the request's timings.
         stats["pass_b_sweep_s"] = tr.total("ingest.pass_b_sweep")
         stats["pass_b_tiles"] = plan.n_tiles
         stats["pass_b_tiles_per_sweep"] = plan.tiles_per_sweep
@@ -1699,16 +1691,6 @@ def _stream_impl(config, encoded, scales, keep_table,
     # through the re-ship rounds) — the same window the former
     # accumulator covered.
     stats["stage_s"] = tr.total("ingest.stage")
-    # Close the planner's predicted-vs-observed loop: the run report's
-    # "plan" section shows these next to the model's predictions —
-    # SAME phase keys as predicted.seconds, so readers zip them
-    # without an out-of-band mapping.
-    plan_mod.note_observed("pass_a", t_loop)
-    if config.percentiles:
-        plan_mod.note_observed("pass_b",
-                               tr.total("ingest.pass_b_sweep"))
-        plan_mod.note_observed("walk", tr.total("walk.top") +
-                               tr.total("walk.bottom"))
     if ckpt_store is not None:
         # The run released its outputs: the checkpoint must not survive
         # (resuming a FINISHED run into a fresh aggregation would skip

@@ -561,17 +561,23 @@ class TestHeartbeatOccupancy:
         mon = obs_monitor.Monitor(
             clock=clock, interval_s=1.0, stall_s=60.0,
             heartbeat_path=str(tmp_path / "hb.json")).start_inline()
-        obs_monitor.update_fusion(
-            {"window_ms": 8, "max_batch": 8, "queued": 3,
-             "buckets": {"abc@r8192p64": {
-                 "queued": 3, "rows": 8192, "partitions": 64,
-                 "window_remaining_s": 0.004}}})
-        hb = mon.poll_once()
-        assert hb["serve"]["fusion"]["queued"] == 3
-        bucket = hb["serve"]["fusion"]["buckets"]["abc@r8192p64"]
-        assert bucket["window_remaining_s"] == 0.004
-        obs_monitor.update_fusion(None)
-        assert "serve" not in mon.poll_once()
+        try:
+            obs_monitor.update_fusion(
+                {"window_ms": 8, "max_batch": 8, "queued": 3,
+                 "buckets": {"abc@r8192p64": {
+                     "queued": 3, "rows": 8192, "partitions": 64,
+                     "window_remaining_s": 0.004}}})
+            hb = mon.poll_once()
+            assert hb["serve"]["fusion"]["queued"] == 3
+            bucket = hb["serve"]["fusion"]["buckets"]["abc@r8192p64"]
+            assert bucket["window_remaining_s"] == 0.004
+            obs_monitor.update_fusion(None)
+            assert "serve" not in mon.poll_once()
+        finally:
+            # Disarm activity tracking: an armed monitor would hand
+            # every later test in this process the measuring tracer.
+            obs_monitor.update_fusion(None)
+            mon.stop()
 
     def test_live_fuser_pushes_bucket_occupancy(self, tmp_path):
         with serve.Service(str(tmp_path / "svc"),
@@ -623,18 +629,13 @@ class TestCompareRefusal:
                 if metric == "serve_fused_throughput":
                     return ({"ts": 1.0, "payload": {"record": {
                         "value": 100.0, "fusion": False,
-                        "plan_source": "default", "plan_hash": None,
                         "kernel_backend": "xla"}}}, False)
                 return (None, False)
 
         monkeypatch.setattr(bench, "_bench_ledger",
                             lambda: _StubLedger())
-        monkeypatch.setattr(bench, "plan_provenance",
-                            lambda: {"plan_source": "default",
-                                     "plan_hash": None})
         rec = {"metric": "serve_fused_throughput", "value": 10.0,
                "unit": "req/s", "fusion": True,
-               "plan_source": "default", "plan_hash": None,
                "kernel_backend": "xla"}
         regressions = bench.compare_to_baseline(records=[rec])
         assert regressions["fusion_mismatches"] == 1

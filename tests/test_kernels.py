@@ -17,7 +17,7 @@ every assertion here runs on the CPU proxy. Covered:
   backends;
 * the out-of-envelope and pallas-unavailable fallbacks: XLA results
   plus a ``kernel.fallback`` obs event — never a silent path change;
-* ``kernel_backend`` knob precedence (env > seam > plan > default)
+* ``kernel_backend`` knob precedence (env > seam > default)
   and unknown-value hardening;
 * the interpret-mode CPU row in the cost observatory's peak table
   (Pallas-path programs on an interpreter backend get a roofline
@@ -149,7 +149,7 @@ class TestWideSegsumKernelParity:
         np.testing.assert_array_equal(got, ref)
 
     def test_every_d_block_is_bit_identical(self):
-        """The D tile width (the ``segsum_wide_d_block`` autotune axis)
+        """The D tile width (the ``segsum_wide_d_block`` knob)
         is a performance hint only: every candidate reduces to the
         same bits, so the sweep can never change released values."""
         rng = np.random.default_rng(40)
@@ -507,7 +507,7 @@ class TestEnvelopeFallback:
 
 class TestKernelBackendKnob:
     """``kernel_backend`` resolves through the registry precedence
-    (env > seam > plan > default) like every other knob."""
+    (env > seam > default) like every other knob."""
 
     def test_registered_dp_safe_str(self):
         assert SPEC.dp_safe
@@ -518,30 +518,25 @@ class TestKernelBackendKnob:
 
     def test_default(self, monkeypatch):
         monkeypatch.delenv(SPEC.env_var, raising=False)
-        assert knobs_mod.resolve_value(SPEC, None) == ("xla", "default")
-
-    def test_plan_applies(self, monkeypatch):
-        monkeypatch.delenv(SPEC.env_var, raising=False)
-        got = knobs_mod.resolve_value(
-            SPEC, {"kernel_backend": "pallas"})
-        assert got == ("pallas", "plan")
+        assert knobs_mod.resolve_value(SPEC) == ("xla", "default")
 
     def test_seam_beats_plan(self, monkeypatch):
+        # The seam outranks the default (no plan layer remains).
         monkeypatch.delenv(SPEC.env_var, raising=False)
         with plan_mod.seam_override("kernel_backend", "pallas"):
-            got = knobs_mod.resolve_value(
-                SPEC, {"kernel_backend": "xla"})
+            got = knobs_mod.resolve_value(SPEC)
         assert got == ("pallas", "seam")
+        assert knobs_mod.resolve_value(SPEC) == ("xla", "default")
 
     def test_env_beats_seam(self, monkeypatch):
         monkeypatch.setenv(SPEC.env_var, "xla")
         with plan_mod.seam_override("kernel_backend", "pallas"):
-            got = knobs_mod.resolve_value(SPEC, None)
+            got = knobs_mod.resolve_value(SPEC)
         assert got == ("xla", "env")
 
     def test_unknown_value_hardens_to_default(self, monkeypatch):
         monkeypatch.setenv(SPEC.env_var, "cuda")
-        value, source = knobs_mod.resolve_value(SPEC, None)
+        value, source = knobs_mod.resolve_value(SPEC)
         assert (value, source) == ("xla", "env")
 
     def test_env_dispatches_pallas_end_to_end(self, monkeypatch):
@@ -567,12 +562,6 @@ class TestKernelBackendKnob:
         assert len(dict(res)) > 0
         counters = obs.ledger().snapshot()["counters"]
         assert counters.get("kernel.pallas_dispatches", 0) >= 1
-
-    def test_autotune_candidates_sweep_the_backend(self):
-        cands = plan_mod.autotune_candidates()
-        assert all("kernel_backend" in vec for vec in cands)
-        assert any(vec["kernel_backend"] == "pallas" for vec in cands)
-        assert cands[0]["kernel_backend"] == "xla"  # default vector
 
 
 class TestInterpretPeakRow:

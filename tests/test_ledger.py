@@ -433,11 +433,7 @@ class TestBenchCompareAcceptance:
                                       "unit": "rows/s"}}, env=env,
                      degraded=True)
         bench.reset_run_state()  # re-snapshot baselines incl. the above
-        # Synthetic records carry an explicit plan_source: the ambient
-        # chunk-env override the fixture sets would otherwise read as a
-        # knob-regime change and refuse the gate (tested in test_plan).
-        current = [{"metric": "m", "value": 500, "unit": "rows/s",
-                    "plan_source": "default"}]
+        current = [{"metric": "m", "value": 500, "unit": "rows/s"}]
         regressions = bench.compare_to_baseline(records=current)
         # The degraded 10-rows/s capture neither became the baseline
         # (masking the regression) nor poisoned the ratio.
@@ -450,8 +446,7 @@ class TestBenchCompareAcceptance:
         assert events and events[0]["metric"] == "m"
         # Within tolerance: no regression flagged.
         ok = bench.compare_to_baseline(
-            records=[{"metric": "m", "value": 950, "unit": "rows/s",
-                      "plan_source": "default"}])
+            records=[{"metric": "m", "value": 950, "unit": "rows/s"}])
         assert ok["regressed"] == []
 
     def test_baseline_is_best_sample_of_last_run(self, monkeypatch):
@@ -468,8 +463,7 @@ class TestBenchCompareAcceptance:
                          run_id="runA")
         bench.reset_run_state()
         reg = bench.compare_to_baseline(
-            records=[{"metric": "m", "value": 500, "unit": "rows/s",
-                      "plan_source": "default"}])
+            records=[{"metric": "m", "value": 500, "unit": "rows/s"}])
         rate = reg["rates"][0]
         assert rate["baseline"] == 1000
         assert reg["regressed"] == ["m"]
@@ -492,8 +486,7 @@ class TestBenchCompareAcceptance:
                      run_id="bad")
         bench.reset_run_state()
         reg = bench.compare_to_baseline(
-            records=[{"metric": "m", "value": 500, "unit": "rows/s",
-                      "plan_source": "default"}])
+            records=[{"metric": "m", "value": 500, "unit": "rows/s"}])
         rate = reg["rates"][0]
         assert rate["baseline"] == 1000
         assert reg["regressed"] == ["m"]
@@ -544,7 +537,7 @@ class TestBenchCompareAcceptance:
         bench.reset_run_state()
         reg = bench.compare_to_baseline(records=[
             {"metric": "v", "value": 500, "unit": "coord-bytes/s",
-             "plan_source": "default", "kernel_backend": "xla",
+             "kernel_backend": "xla",
              "vector_accumulator": "fx"}])
         rate = reg["rates"][0]
         assert rate.get("vector_accumulator_mismatch") is True
@@ -561,7 +554,7 @@ class TestBenchCompareAcceptance:
         # gates exactly like rows/s — a >10% drop is a regression.
         reg2 = bench.compare_to_baseline(records=[
             {"metric": "v", "value": 500, "unit": "coord-bytes/s",
-             "plan_source": "default", "kernel_backend": "xla",
+             "kernel_backend": "xla",
              "vector_accumulator": "f32"}])
         assert reg2["rates"][0].get("regressed") is True
         assert reg2["regressed"] == ["v"]
@@ -586,7 +579,7 @@ class TestBenchCompareAcceptance:
         reg = bench.compare_to_baseline(records=[
             {"metric": "utility_megasweep_configs_per_sec",
              "value": 200, "unit": "configs/s",
-             "plan_source": "default", "kernel_backend": "xla",
+             "kernel_backend": "xla",
              "sweep_config_batch": 256}])
         rate = reg["rates"][0]
         assert rate.get("sweep_config_batch_mismatch") is True
@@ -605,7 +598,7 @@ class TestBenchCompareAcceptance:
         reg2 = bench.compare_to_baseline(records=[
             {"metric": "utility_megasweep_configs_per_sec",
              "value": 200, "unit": "configs/s",
-             "plan_source": "default", "kernel_backend": "xla",
+             "kernel_backend": "xla",
              "sweep_config_batch": 16}])
         assert reg2["rates"][0].get("regressed") is True
         assert reg2["regressed"] == [
@@ -629,7 +622,7 @@ class TestBenchCompareAcceptance:
         bench.reset_run_state()
         reg = bench.compare_to_baseline(records=[
             {"metric": "m", "value": 500, "unit": "rows/s",
-             "plan_source": "default", "kernel_backend": "xla",
+             "kernel_backend": "xla",
              "mesh_topology": "hier"}])
         rate = reg["rates"][0]
         assert rate.get("mesh_topology_mismatch") is True
@@ -645,17 +638,15 @@ class TestBenchCompareAcceptance:
         # pre-knob record shape still gates (and still regresses).
         reg2 = bench.compare_to_baseline(records=[
             {"metric": "m", "value": 500, "unit": "rows/s",
-             "plan_source": "default", "kernel_backend": "xla"}])
+             "kernel_backend": "xla"}])
         assert reg2["rates"][0].get("regressed") is True
         assert reg2["regressed"] == ["m"]
 
 
 class TestNoAdHocArtifactWrites:
     """AST-precise twin of ``make noartifacts``: ``json.dump(`` file
-    writes are banned outside ``pipelinedp_tpu/obs/`` and
-    ``pipelinedp_tpu/plan/`` (the planner's atomically-replaced plan
-    file is the second blessed durable artifact) — run artifacts must
-    flow through the schema-versioned report/store/plan (bench.py, the
+    writes are banned outside ``pipelinedp_tpu/obs/`` — run artifacts
+    must flow through the schema-versioned report/store (bench.py, the
     one artifact emitter, is outside the scanned tree)."""
 
     def test_json_dump_only_under_obs(self):

@@ -1,42 +1,25 @@
-"""Execution-planner tests (``pipelinedp_tpu/plan``, ``make plancheck``).
+"""Knob-registry tests (``pipelinedp_tpu/plan``, ``make plancheck``).
 
 Coverage contract:
 
-* knob registry — cold-start resolution (no plan file, no env, no
-  ledger history) is BYTE-IDENTICAL to the hardcoded defaults; env
-  overrides outrank test seams outrank plan files outrank defaults;
-  dp-unsafe knobs (``stream_chunk_rows``, the int32 guard caps) are
-  never applied from a plan (``plan.skipped_dp_unsafe``);
-* poisoned history — an empty ledger, a degraded-only ledger and a
-  mixed-fingerprint ledger all fit an EMPTY model (predict None) and
-  resolve to the defaults byte-for-byte;
-* plan file — atomic write/load round-trip; a plan written under a
-  DIFFERENT fingerprint hash is ignored with a ``plan.stale`` event;
-  ``PIPELINEDP_TPU_PLAN_DIR=0`` disables loading entirely;
-* cost model — least-squares fit from synthetic trials predicts
-  through the samples, serializes through the plan file, and the
-  roofline fallback floors at bytes over the static peak bandwidth;
+* knob registry — cold-start resolution (no env, no seam) is
+  BYTE-IDENTICAL to the hardcoded defaults; for every registered knob
+  an env override wins, every module seam wins under
+  ``seam_override`` and restores on exit, and an unknown value of an
+  enumerated knob resolves to its default;
+* a stale plan file — one written by the retired autotune loop, left
+  on disk in the two places that loop used to look — steers nothing:
+  every knob still resolves to its default, and a release is
+  bit-identical to one without the files;
 * pass-B q_chunk pin — a pinned quantile-group width constrains the
   sweep planner's tiling; an infeasible pin falls back to the search;
-* PARITY row 32 — planner on (a plan file moving every dp-safe knob)
-  vs off (no plan): DP outputs bit-identical, because plans only
-  select among already-parity-tested execution paths;
 * ``--since-run-id`` — the store's run-windowed reads (module helper,
   incremental ``read_from`` offsets, and the CLI flag);
-* bench provenance — every bench record carries ``plan_source`` /
-  ``plan_hash``, and ``--compare`` refuses to gate a rate against a
-  baseline recorded under a different plan (``COMPARE: plan
-  mismatch``, never a false regression);
-* the autotune acceptance flow — ``run_autotune`` writes a plan file
-  a subsequent plain streamed run resolves (``plan.applied`` events
-  with ``source: "plan"``);
 * lint twin — AST-precise ban on direct reads of the registered knob
   constants outside ``pipelinedp_tpu/plan/`` (``make noknobs`` runs
   the grep twin).
 """
 
-import argparse
-import ast
 import json
 import os
 
@@ -44,18 +27,12 @@ import numpy as np
 import pytest
 
 import pipelinedp_tpu as pdp
-from pipelinedp_tpu import jax_engine as je
 from pipelinedp_tpu import obs
 from pipelinedp_tpu import plan as plan_pkg
 from pipelinedp_tpu import streaming
 from pipelinedp_tpu.backends import JaxBackend
 from pipelinedp_tpu.obs import store as obs_store
 from pipelinedp_tpu.plan import knobs as plan_knobs
-from pipelinedp_tpu.plan import model as plan_model
-from pipelinedp_tpu.plan import planner as plan_planner
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BIG_EPS = 1e12
 
 #: Today's hardcoded defaults, restated literally: the cold-start
 #: acceptance criterion is byte-identity against THESE values, so the
@@ -83,29 +60,56 @@ HARDCODED_DEFAULTS = {
     "tree_rows_cap": int(np.iinfo(np.int32).max),
 }
 
+#: One legal non-default env value per knob that has an env override,
+#: with the value it parses to.
+ENV_OVERRIDES = {
+    "subhist_byte_cap": ("1048576", 1 << 20),
+    "stream_chunk_rows": ("997", 997),
+    "stream_cache_bytes": ("0", 0),
+    "ingest_executor": ("off", False),
+    "q_chunk": ("2", 2),
+    "kernel_backend": ("pallas", "pallas"),
+    "segsum_wide_d_block": ("128", 128),
+    "sweep_config_batch": ("64", 64),
+    "vector_accumulator": ("fx", "fx"),
+    "serve_fusion": ("1", True),
+    "serve_fuse_window_ms": ("20", 20),
+    "serve_fuse_batch": ("4", 4),
+    "serve_fuse_rows_floor": ("16384", 16384),
+    "sketch_width": ("1024", 1024),
+    "sketch_depth": ("3", 3),
+    "sketch_candidate_cap": ("512", 512),
+    "sketch_backend": ("xla", "xla"),
+    "mesh_topology": ("hier", "hier"),
+}
+
+#: One non-default value per knob that has a module seam.
+SEAM_VALUES = {
+    "subhist_byte_cap": 4096,
+    "q_chunk": 3,
+    "kernel_backend": "pallas",
+    "segsum_wide_d_block": 256,
+    "sweep_config_batch": 8,
+    "vector_accumulator": "fx",
+    "mesh_topology": "hier",
+    "select_units_cap": 7,
+    "tree_rows_cap": 9,
+}
+
+ENUMERATED = ("kernel_backend", "vector_accumulator", "sketch_backend",
+              "mesh_topology")
+
 
 @pytest.fixture(autouse=True)
-def fresh_plan_state(monkeypatch):
-    """Isolate every test: no ambient plan file/env, fresh applied
-    state, and a fresh obs ledger so event assertions see only this
-    test's emissions."""
-    for var in (plan_planner.ENV_DIR, "PIPELINEDP_TPU_SUBHIST_CAP",
-                "PIPELINEDP_TPU_Q_CHUNK", "PIPELINEDP_TPU_STREAM_CHUNK",
-                "PIPELINEDP_TPU_STREAM_CACHE",
-                "PIPELINEDP_TPU_INGEST_EXECUTOR",
-                "PIPELINEDP_TPU_SERVE_FUSION",
-                "PIPELINEDP_TPU_SERVE_FUSE_WINDOW_MS",
-                "PIPELINEDP_TPU_SERVE_FUSE_BATCH",
-                "PIPELINEDP_TPU_SERVE_FUSE_ROWS_FLOOR",
-                "PIPELINEDP_TPU_SEGSUM_WIDE_D_BLOCK",
-                "PIPELINEDP_TPU_VECTOR_ACCUMULATOR",
-                "PIPELINEDP_TPU_COMPILE_CACHE"):
-        monkeypatch.delenv(var, raising=False)
+def fresh_knob_state(monkeypatch):
+    """Isolate every test: no ambient knob env, and a fresh obs ledger
+    so event assertions see only this test's emissions."""
+    for spec in plan_knobs.REGISTRY:
+        if spec.env_var is not None:
+            monkeypatch.delenv(spec.env_var, raising=False)
     obs.reset()
-    plan_pkg.set_default_dir(None)
     yield
     obs.reset()
-    plan_pkg.set_default_dir(None)
 
 
 def _events(name):
@@ -113,346 +117,149 @@ def _events(name):
             if e["name"] == name]
 
 
-def _write_plan_file(directory, knobs, fingerprint=None, model=None):
-    plan = {"schema_version": plan_planner.PLAN_SCHEMA,
-            "fingerprint": (plan_planner.fingerprint()
-                            if fingerprint is None else fingerprint),
-            "device_kind": "cpu", "created_by": "test", "trials": 1,
-            "knobs": {"default": dict(knobs)},
-            "model": (model or plan_model.CostModel()).to_dict()}
-    plan_planner.write_plan(plan, str(directory))
-    return plan
-
-
 class TestKnobRegistry:
     """Resolution precedence and the cold-start contract."""
 
     def test_cold_start_is_byte_identical_to_defaults(self):
-        resolved = plan_knobs.resolve_all(None)
+        resolved = plan_knobs.resolve_all()
         assert {k: v for k, (v, _) in resolved.items()} == (
             HARDCODED_DEFAULTS)
         assert {s for _, (_, s) in resolved.items()} == {"default"}
         assert plan_knobs.defaults() == HARDCODED_DEFAULTS
 
-    def test_env_outranks_plan_and_default(self, monkeypatch):
-        monkeypatch.setenv("PIPELINEDP_TPU_SUBHIST_CAP", "1048576")
-        v, s = plan_knobs.resolve_value(
-            plan_knobs.BY_NAME["subhist_byte_cap"],
-            {"subhist_byte_cap": 2048})
-        assert (v, s) == (1 << 20, "env")
+    def test_tables_cover_the_registry(self):
+        with_env = {s.name for s in plan_knobs.REGISTRY if s.env_var}
+        with_seam = {s.name for s in plan_knobs.REGISTRY if s.seam}
+        enumerated = {s.name for s in plan_knobs.REGISTRY
+                      if s.kind is str}
+        assert set(ENV_OVERRIDES) == with_env
+        assert set(SEAM_VALUES) == with_seam
+        assert set(ENUMERATED) == enumerated
 
-    def test_seam_outranks_plan(self, monkeypatch):
-        monkeypatch.setattr(je, "_SUBHIST_BYTE_CAP", 4096)
-        v, s = plan_knobs.resolve_value(
-            plan_knobs.BY_NAME["subhist_byte_cap"],
-            {"subhist_byte_cap": 2048})
-        assert (v, s) == (4096, "seam")
+    @pytest.mark.parametrize("name", sorted(ENV_OVERRIDES))
+    def test_env_override_wins(self, name, monkeypatch):
+        spec = plan_knobs.BY_NAME[name]
+        raw, parsed = ENV_OVERRIDES[name]
+        assert parsed != spec.default
+        monkeypatch.setenv(spec.env_var, raw)
+        assert plan_knobs.resolve_value(spec) == (parsed, "env")
+        assert plan_pkg.knob_value(name) == parsed
 
-    def test_plan_outranks_default_for_dp_safe(self):
-        v, s = plan_knobs.resolve_value(
-            plan_knobs.BY_NAME["stream_cache_bytes"],
-            {"stream_cache_bytes": 0})
-        assert (v, s) == (0, "plan")
+    @pytest.mark.parametrize("name", sorted(SEAM_VALUES))
+    def test_seam_wins_and_restores(self, name):
+        spec = plan_knobs.BY_NAME[name]
+        mod = __import__(spec.seam[0], fromlist=[spec.seam[1]])
+        before = getattr(mod, spec.seam[1])
+        value = SEAM_VALUES[name]
+        assert value != spec.default
+        with plan_pkg.seam_override(name, value):
+            assert getattr(mod, spec.seam[1]) == value
+            assert plan_knobs.resolve_value(spec) == (value, "seam")
+            assert plan_pkg.knob_value(name) == value
+        assert getattr(mod, spec.seam[1]) == before
+        assert plan_knobs.resolve_value(spec) == (spec.default,
+                                                  "default")
 
-    def test_dp_unsafe_knob_never_applied_from_plan(self):
-        v, s = plan_knobs.resolve_value(
-            plan_knobs.BY_NAME["stream_chunk_rows"],
-            {"stream_chunk_rows": 1234})
-        assert (v, s) == (1 << 26, "default")
-        ev = _events("plan.skipped_dp_unsafe")
-        assert ev and ev[-1]["knob"] == "stream_chunk_rows"
-        for guard in ("select_units_cap", "tree_rows_cap"):
-            v, s = plan_knobs.resolve_value(plan_knobs.BY_NAME[guard],
-                                            {guard: 7})
-            assert (v, s) == (HARDCODED_DEFAULTS[guard], "default")
-
-    def test_seam_override_restores(self):
-        before = streaming._Q_CHUNK
-        with plan_pkg.seam_override("q_chunk", 3):
-            assert streaming._Q_CHUNK == 3
-            assert plan_knobs.resolve_value(
-                plan_knobs.BY_NAME["q_chunk"], None) == (3, "seam")
-        assert streaming._Q_CHUNK == before
+    @pytest.mark.parametrize("name", ENUMERATED)
+    def test_unknown_choice_resolves_default(self, name, monkeypatch):
+        spec = plan_knobs.BY_NAME[name]
+        monkeypatch.setenv(spec.env_var, "no-such-choice")
+        assert plan_knobs.resolve_value(spec) == (spec.default, "env")
 
     def test_bool_parsing(self, monkeypatch):
         monkeypatch.setenv("PIPELINEDP_TPU_INGEST_EXECUTOR", "off")
         v, s = plan_knobs.resolve_value(
-            plan_knobs.BY_NAME["ingest_executor"], None)
+            plan_knobs.BY_NAME["ingest_executor"])
         assert (v, s) == (False, "env")
 
 
-class TestPlanFile:
-    """Atomic persistence, fingerprint keying, stale rejection."""
+#: A value off the default for every dp-safe knob: what a plan file of
+#: the retired autotune loop could carry.
+_STALE_PLAN_KNOBS = {
+    "subhist_byte_cap": 64 << 10,
+    "stream_cache_bytes": 0,
+    "ingest_executor": 0,
+    "q_chunk": 1,
+    "kernel_backend": "pallas",
+    "segsum_wide_d_block": 128,
+    "sweep_config_batch": 4,
+    "serve_fusion": 1,
+    "serve_fuse_window_ms": 1,
+    "serve_fuse_batch": 2,
+    "serve_fuse_rows_floor": 16384,
+    "sketch_backend": "xla",
+    "mesh_topology": "hier",
+}
 
-    def test_round_trip_and_resolution(self, tmp_path, monkeypatch):
-        d = tmp_path / "plan"
-        monkeypatch.setenv(plan_planner.ENV_DIR, str(d))
-        _write_plan_file(d, {"subhist_byte_cap": 12345678,
-                             "ingest_executor": 0})
-        resolved = plan_pkg.resolve(emit=True)
-        assert resolved.values["subhist_byte_cap"] == 12345678
-        assert resolved.sources["subhist_byte_cap"] == "plan"
-        assert resolved.values["ingest_executor"] is False
-        assert resolved.plan_source == "autotuned"
-        assert resolved.plan_hash
-        # plan.applied events carry (knob, value, source).
-        applied = {e["knob"]: e for e in _events("plan.applied")}
-        assert applied["subhist_byte_cap"]["source"] == "plan"
-        assert applied["subhist_byte_cap"]["value"] == 12345678
-        assert applied["stream_chunk_rows"]["source"] == "default"
-        # ... and the run report grows the schema-v4 plan section.
+
+def _release(backend):
+    rng = np.random.default_rng(88)
+    n = 3_000
+    ds = pdp.ArrayDataset(privacy_ids=rng.integers(0, 600, n),
+                          partition_keys=rng.integers(0, 6, n),
+                          values=rng.uniform(0.0, 20.0, n))
+    params = pdp.AggregateParams(
+        metrics=[pdp.Metrics.COUNT, pdp.Metrics.SUM,
+                 pdp.Metrics.PERCENTILE(50)],
+        noise_kind=pdp.NoiseKind.LAPLACE,
+        max_partitions_contributed=3,
+        max_contributions_per_partition=4,
+        min_value=0.0, max_value=20.0)
+    acc = pdp.NaiveBudgetAccountant(total_epsilon=1.0, total_delta=1e-6)
+    res = pdp.DPEngine(acc, backend).aggregate(ds, params,
+                                               pdp.DataExtractors())
+    acc.compute_budgets()
+    return dict(res)
+
+
+class TestStalePlanFile:
+    """Deployments that once ran ``bench.py --autotune`` may still hold
+    its ``plan.json``. Nothing reads it any more."""
+
+    def test_stale_plan_file_steers_nothing(self, tmp_path,
+                                            monkeypatch):
+        assert set(_STALE_PLAN_KNOBS) == {
+            s.name for s in plan_knobs.REGISTRY if s.dp_safe}
+        # Built before the env points at a compile cache: constructing
+        # a backend would otherwise switch the persistent cache on for
+        # the rest of this process.
+        off_backend = JaxBackend(rng_seed=7)
+        on_backend = JaxBackend(rng_seed=7)
+        off = _release(off_backend)
+        plan = {"schema_version": 1,
+                "fingerprint": obs_store.fingerprint_key(
+                    obs.environment_fingerprint()),
+                "device_kind": "cpu", "created_by": "bench --autotune",
+                "trials": 1, "knobs": {"default": _STALE_PLAN_KNOBS},
+                "model": {}}
+        stale_dir = tmp_path / "plan"
+        cache = tmp_path / "cache" / "xla"
+        for d in (stale_dir, cache.parent / "pdp_plan"):
+            d.mkdir(parents=True)
+            (d / "plan.json").write_text(json.dumps(plan), "utf-8")
+        monkeypatch.setenv("PIPELINEDP_TPU_PLAN_DIR", str(stale_dir))
+        monkeypatch.setenv("PIPELINEDP_TPU_COMPILE_CACHE", str(cache))
+
+        resolved = plan_knobs.resolve_all()
+        assert {k: v for k, (v, _) in resolved.items()} == (
+            plan_knobs.defaults())
+        assert {s for _, (_, s) in resolved.items()} == {"default"}
+        for name, default in plan_knobs.defaults().items():
+            assert plan_pkg.knob_value(name) == default, name
+
+        on = _release(on_backend)
+        assert set(on) == set(off)
+        for pk in off:
+            for field in off[pk]._fields:
+                assert getattr(on[pk], field) == getattr(off[pk], field), (
+                    f"stale plan file moved {pk}.{field}")
         report = obs.build_run_report()
-        assert report["schema_version"] == 6
-        assert report["plan"]["knobs"]["subhist_byte_cap"] == {
-            "value": 12345678, "source": "plan"}
-        assert report["plan"]["plan_hash"] == resolved.plan_hash
-
-    def test_stale_fingerprint_ignored_with_event(self, tmp_path,
-                                                  monkeypatch):
-        d = tmp_path / "plan"
-        monkeypatch.setenv(plan_planner.ENV_DIR, str(d))
-        _write_plan_file(d, {"subhist_byte_cap": 999},
-                         fingerprint="deadbeefdeadbeef")
-        resolved = plan_pkg.resolve()
-        assert resolved.values == {
-            k: v for k, v in HARDCODED_DEFAULTS.items()}
-        assert resolved.plan_hash is None
-        ev = _events("plan.stale")
-        assert ev and ev[-1]["plan_fingerprint"] == "deadbeefdeadbeef"
-
-    def test_stale_event_emitted_once_per_observation(self, tmp_path,
-                                                      monkeypatch):
-        # load_plan runs on EVERY knob read; a stale plan must not
-        # flood the bounded obs event ring with one plan.stale per
-        # read. A rewrite of the file is a new observation.
-        d = tmp_path / "plan"
-        monkeypatch.setenv(plan_planner.ENV_DIR, str(d))
-        _write_plan_file(d, {"subhist_byte_cap": 999},
-                         fingerprint="deadbeefdeadbeef")
-        for _ in range(4):
-            plan_pkg.resolve(emit=False)
-            plan_pkg.knob_value("subhist_byte_cap")
-        assert len(_events("plan.stale")) == 1
-        _write_plan_file(d, {"subhist_byte_cap": 998},
-                         fingerprint="feedfacefeedface")
-        plan_pkg.resolve(emit=False)
-        assert len(_events("plan.stale")) == 2
-
-    def test_single_batch_request_resolves_plan(self, tmp_path,
-                                                monkeypatch):
-        # Non-streamed requests never reach streaming's resolve; the
-        # single-batch path must resolve too, so its plan.applied
-        # events and run-report plan section exist and mid-request
-        # knob reads bucket at THIS request's shape, not a stale one.
-        d = tmp_path / "plan"
-        monkeypatch.setenv(plan_planner.ENV_DIR, str(d))
-        _write_plan_file(d, {"stream_cache_bytes": 0})
-        ds = _dataset(n=2_000, parts=4)
-        acc = pdp.NaiveBudgetAccountant(total_epsilon=BIG_EPS,
-                                        total_delta=1e-2)
-        engine = pdp.DPEngine(acc, JaxBackend(rng_seed=7))
-        params = pdp.AggregateParams(
-            metrics=[pdp.Metrics.COUNT],
-            noise_kind=pdp.NoiseKind.LAPLACE,
-            max_partitions_contributed=2,
-            max_contributions_per_partition=3)
-        res = engine.aggregate(ds, params, pdp.DataExtractors())
-        acc.compute_budgets()
-        dict(res)
-        assert "stream_batches" not in res.timings  # single batch
-        applied = [e for e in _events("plan.applied")
-                   if e["source"] == "plan"]
-        assert applied, "single-batch request resolved no plan"
-        assert plan_planner.last_resolved_shape() == {
-            "rows": 2_000, "partitions": 4, "quantiles": 0}
-
-    def test_disabled_dir_loads_nothing(self, tmp_path, monkeypatch):
-        monkeypatch.setenv(plan_planner.ENV_DIR, "0")
-        assert plan_planner.plan_dir() is None
-        assert plan_planner.load_plan() is None
-
-    def test_atomic_replace_no_torn_read(self, tmp_path, monkeypatch):
-        d = tmp_path / "plan"
-        monkeypatch.setenv(plan_planner.ENV_DIR, str(d))
-        _write_plan_file(d, {"subhist_byte_cap": 1})
-        _write_plan_file(d, {"subhist_byte_cap": 2})
-        plan = plan_planner.load_plan()
-        assert plan["knobs"]["default"]["subhist_byte_cap"] == 2
-        # Only the one file: tmp files never survive the replace.
-        assert os.listdir(d) == [plan_planner.PLAN_FILENAME]
-
-    def test_corrupt_plan_file_resolves_defaults(self, tmp_path,
-                                                 monkeypatch):
-        d = tmp_path / "plan"
-        d.mkdir()
-        (d / plan_planner.PLAN_FILENAME).write_text("{torn", "utf-8")
-        monkeypatch.setenv(plan_planner.ENV_DIR, str(d))
-        resolved = plan_pkg.resolve()
-        assert resolved.values == HARDCODED_DEFAULTS
-        assert resolved.plan_source == "default"
-
-    def test_plan_hash_keys_on_knobs_only(self):
-        # A re-autotune that lands on the SAME knob vector must keep
-        # the same identity: the write timestamp and the re-fit model
-        # blob change every sweep, and hashing them would trip the
-        # --compare plan-mismatch refusal forever after the first
-        # rewrite.
-        base = {"schema_version": plan_planner.PLAN_SCHEMA,
-                "fingerprint": "f" * 16, "device_kind": "cpu",
-                "created_by": "test", "ts": 1.0, "trials": 5,
-                "knobs": {"default": {"q_chunk": 2}},
-                "model": plan_model.CostModel().to_dict()}
-        rewrite = dict(base, ts=999.0, trials=7,
-                       model={"schema": 1, "tables": {"x": [1, 2]}})
-        assert plan_planner.plan_hash(base) == (
-            plan_planner.plan_hash(rewrite))
-        moved = dict(base, knobs={"default": {"q_chunk": 4}})
-        assert plan_planner.plan_hash(moved) != (
-            plan_planner.plan_hash(base))
-
-    def test_mid_request_knob_read_uses_resolved_shape_bucket(
-            self, tmp_path, monkeypatch):
-        # The walk resolves subhist_byte_cap shape-blind at jit-trace
-        # time (plan.knob_value with no shape argument); it must
-        # bucket against the vector the REQUEST resolved, not
-        # whichever vector the 'default' bucket happens to carry.
-        d = tmp_path / "plan"
-        monkeypatch.setenv(plan_planner.ENV_DIR, str(d))
-        bucket = plan_model.bucket_key(1_000, 10, 3)
-        plan = {"schema_version": plan_planner.PLAN_SCHEMA,
-                "fingerprint": plan_planner.fingerprint(),
-                "device_kind": "cpu", "created_by": "test",
-                "trials": 1,
-                "knobs": {bucket: {"subhist_byte_cap": 111 << 20},
-                          "default": {"subhist_byte_cap": 222 << 20}},
-                "model": plan_model.CostModel().to_dict()}
-        plan_planner.write_plan(plan, str(d))
-        resolved = plan_pkg.resolve(
-            shape={"rows": 1_000, "partitions": 10, "quantiles": 3})
-        assert resolved.values["subhist_byte_cap"] == 111 << 20
-        # The shape-blind read now follows the request's bucket...
-        assert plan_pkg.knob_value("subhist_byte_cap") == 111 << 20
-        # ...and falls back to the default bucket with no resolution
-        # in force.
-        plan_planner.reset()
-        assert plan_pkg.knob_value("subhist_byte_cap") == 222 << 20
-
-
-class TestPoisonedHistory:
-    """Cold start and bad ledgers must leave the defaults in force."""
-
-    FP = "aaaaaaaaaaaaaaaa"
-
-    def _entry(self, name, payload, degraded=False, fp=None):
-        return {"schema_version": 4, "name": name, "degraded": degraded,
-                "fingerprint": fp or self.FP, "ts": 0.0,
-                "payload": payload}
-
-    def _trial(self, total_s, degraded=False, fp=None, rows=1000):
-        return self._entry("autotune.trial", {"trial": {
-            "knobs": {"subhist_byte_cap": 1}, "total_s": total_s,
-            "shape": {"rows": rows, "partitions": 8, "quantiles": 3},
-            "device_kind": "cpu",
-            "phases": {"pass_a": total_s}}}, degraded, fp)
-
-    def test_empty_ledger_fits_empty_model(self):
-        model = plan_model.fit([], fingerprint=self.FP)
-        assert model.samples == 0
-        assert model.predict_seconds("cpu", "pass_a", 1000) is None
-        assert plan_model.choose_best_trial([], self.FP) is None
-
-    def test_degraded_only_entries_are_ignored(self):
-        entries = [self._trial(1.0, degraded=True) for _ in range(4)]
-        model = plan_model.fit(entries, fingerprint=self.FP)
-        assert model.samples == 0
-        assert plan_model.choose_best_trial(entries, self.FP) is None
-
-    def test_mixed_fingerprints_do_not_cross_pollute(self):
-        entries = [self._trial(1.0, fp="bbbbbbbbbbbbbbbb"),
-                   self._trial(2.0)]
-        model = plan_model.fit(entries, fingerprint=self.FP)
-        assert model.samples == 1  # only the matching-fingerprint row
-        best = plan_model.choose_best_trial(entries, self.FP)
-        assert best[plan_model.bucket_key(1000, 8, 3)]["total_s"] == 2.0
-
-    def test_poisoned_history_resolves_hardcoded_defaults(self):
-        # No plan file was (or could be) written from the histories
-        # above — resolution must be the identity on the defaults.
-        resolved = plan_pkg.resolve()
-        assert resolved.values == HARDCODED_DEFAULTS
-        assert set(resolved.sources.values()) == {"default"}
-
-
-class TestCostModel:
-    """Fit/predict/serialize + the static roofline fallback."""
-
-    def test_run_report_fits_request_shape_and_hbm_peak(self):
-        # Report-derived samples must bucket at the REQUEST's shape
-        # (the v4 plan section) so predictions hit the cell directly,
-        # and the observatory's program memory stats must feed
-        # predict_hbm_peak — not stay permanently None.
-        rr = {"schema_version": 4,
-              "env": {"device_kind": "cpu"},
-              "counters": {"ingest.rows_ingested": 4096},
-              "spans": {"ingest.pass_a": {"total_s": 2.0},
-                        "ingest.pass_b_sweep": {"total_s": 1.0}},
-              "plan": {"shape": {"rows": 4096, "partitions": 32,
-                                 "quantiles": 3}},
-              "device_costs": {"programs": {
-                  "k1": {"phase": "pass_a",
-                         "memory": {"peak_bytes": 5_000_000}},
-                  "k2": {"phase": "pass_b",
-                         "memory": {"peak_bytes": 9_000_000}},
-                  "k3": {"phase": "pass_b",
-                         "memory": {"peak_bytes": 7_000_000}}}}}
-        entry = {"schema_version": 4, "name": "run_report",
-                 "degraded": False, "fingerprint": "f", "ts": 0.0,
-                 "payload": {"run_report": rr}}
-        model = plan_model.fit([entry], fingerprint="f")
-        bucket = plan_model.bucket_key(4096, 32, 3)
-        assert ("cpu", "pass_a", bucket) in model.cells
-        assert model.predict_seconds(
-            "cpu", "pass_a", 4096, 32, 3) == pytest.approx(2.0)
-        assert model.predict_hbm_peak(
-            "cpu", "pass_b", 4096, 32, 3) == 9_000_000
-
-    def test_least_squares_prediction(self):
-        entries = []
-        for rows, secs in ((1000, 1.0), (2000, 2.0), (4000, 4.0)):
-            entries.append({
-                "schema_version": 4, "name": "autotune.trial",
-                "degraded": False, "fingerprint": "f", "ts": 0.0,
-                "payload": {"trial": {
-                    "knobs": {"q_chunk": 0}, "total_s": secs,
-                    "shape": {"rows": rows, "partitions": 8,
-                              "quantiles": 3},
-                    "device_kind": "cpu",
-                    "phases": {"pass_a": secs}}}})
-        model = plan_model.fit(entries, fingerprint="f")
-        # Same bucket (log2(rows) equal for 1000..1024? no — 1000 and
-        # 2000 land in different buckets), so prediction goes through
-        # the phase-wide pooled ratio: seconds/rows == 1e-3.
-        pred = model.predict_seconds("cpu", "pass_a", 8000, 8, 3)
-        assert pred == pytest.approx(8.0, rel=0.3)
-        # Round trip through the plan-file serialization.
-        again = plan_model.CostModel.from_dict(model.to_dict())
-        assert again.predict_seconds("cpu", "pass_a", 8000, 8, 3) == (
-            pytest.approx(pred))
-
-    def test_roofline_fallback_uses_static_peaks(self):
-        model = plan_model.CostModel()
-        model.bytes_per_unit[("cpu", "pass_a")] = 16.0
-        # cpu proxy peak bandwidth is 5e10 B/s (obs.costs.DEVICE_PEAKS)
-        floor = model.roofline_floor("cpu", "pass_a", 1_000_000)
-        assert floor == pytest.approx(16.0 * 1_000_000 / 5e10)
-        assert model.predict_seconds("cpu", "pass_a",
-                                     1_000_000) == pytest.approx(floor)
-        # Unknown device kind: an honest None, never a made-up floor.
-        assert model.roofline_floor("quantum9", "pass_a", 10) is None
+        assert {v["source"] for v in report["plan"]["knobs"].values()} == (
+            {"default"})
 
 
 class TestQChunkPin:
-    """The planner's q_chunk knob constrains the pass-B tiling."""
+    """The q_chunk knob constrains the pass-B sweep planner's tiling."""
 
     def test_pin_constrains_tiling(self):
         _, _, _, span = streaming._tree_consts()
@@ -473,71 +280,6 @@ class TestQChunkPin:
         free = streaming.plan_pass_b_sweeps(8, 4, span, 2 * unit)
         assert pinned == free
         assert _events("plan.q_chunk_infeasible")
-
-
-def _pct_params():
-    return pdp.AggregateParams(
-        metrics=[pdp.Metrics.PERCENTILE(p) for p in (25, 50, 75, 95)] +
-        [pdp.Metrics.COUNT],
-        noise_kind=pdp.NoiseKind.LAPLACE,
-        max_partitions_contributed=5,
-        max_contributions_per_partition=50,
-        min_value=0.0, max_value=20.0)
-
-
-def _dataset(seed=88, n=6_000, parts=5):
-    rng = np.random.default_rng(seed)
-    return pdp.ArrayDataset(privacy_ids=rng.integers(0, 1_500, n),
-                            partition_keys=rng.integers(0, parts, n),
-                            values=rng.uniform(0.0, 20.0, n))
-
-
-def _run_streamed(ds, params, monkeypatch, chunk=997):
-    monkeypatch.setenv("PIPELINEDP_TPU_STREAM_CHUNK", str(chunk))
-    ds.invalidate_cache()
-    acc = pdp.NaiveBudgetAccountant(total_epsilon=BIG_EPS,
-                                    total_delta=1e-2)
-    engine = pdp.DPEngine(acc, JaxBackend(rng_seed=7))
-    res = engine.aggregate(ds, params, pdp.DataExtractors())
-    acc.compute_budgets()
-    got = dict(res)
-    assert res.timings["stream_batches"] > 1
-    return got
-
-
-class TestParityPlannerOnOff:
-    """PARITY row 32: a plan file moving EVERY dp-safe knob produces
-    bit-identical DP outputs to the no-plan defaults — plans only
-    select among already-parity-tested execution paths (multi-tile =
-    per-tile = unchunked; hybrid = device_cache = reship; overlapped =
-    serial)."""
-
-    def test_planner_on_off_outputs_bit_identical(self, tmp_path,
-                                                  monkeypatch):
-        _, _, _, span = streaming._tree_consts()
-        ds, params = _dataset(), _pct_params()
-        off = _run_streamed(ds, params, monkeypatch)
-        d = tmp_path / "plan"
-        monkeypatch.setenv(plan_planner.ENV_DIR, str(d))
-        # Move every dp-safe knob off its default: shrunken subhist
-        # cap (forces the multi-tile sweep), pinned q_chunk, serial
-        # executor, cache off.
-        _write_plan_file(d, {"subhist_byte_cap": 5 * span * 4,
-                             "q_chunk": 1,
-                             "ingest_executor": 0,
-                             "stream_cache_bytes": 0})
-        on = _run_streamed(ds, params, monkeypatch)
-        assert set(on) == set(off)
-        applied = {e["knob"]: e["source"]
-                   for e in _events("plan.applied")}
-        assert applied["subhist_byte_cap"] == "plan"
-        assert applied["q_chunk"] == "plan"
-        fields = [f for f in off[next(iter(off))]._fields
-                  if f.startswith("percentile_") or f == "count"]
-        for pk in off:
-            for f in fields:
-                assert getattr(on[pk], f) == getattr(off[pk], f), (
-                    f"planner on/off diverged at {pk}.{f}")
 
 
 class TestSinceRunId:
@@ -576,163 +318,6 @@ class TestSinceRunId:
         assert rc == 0
         out = json.loads(capsys.readouterr().out)
         assert out["entries"] == 2
-
-
-def _import_bench(monkeypatch):
-    monkeypatch.syspath_prepend(REPO)
-    import bench
-    return bench
-
-
-class TestBenchPlanProvenance:
-    """Bench records carry the plan identity; --compare refuses to
-    gate across plan changes."""
-
-    def _one_rate(self, bench, name="plan_rate"):
-        ds = bench.zipf_dataset(8_000, 1_000, 50, seed=3)
-        params = pdp.AggregateParams(
-            metrics=[pdp.Metrics.COUNT, pdp.Metrics.SUM,
-                     pdp.Metrics.MEAN],
-            noise_kind=pdp.NoiseKind.LAPLACE,
-            max_partitions_contributed=4,
-            max_contributions_per_partition=2,
-            min_value=0.0, max_value=10.0)
-        return bench.bench_config(name, params, ds, 4_000, repeats=1)
-
-    def test_records_and_compare_mismatch(self, tmp_path, monkeypatch):
-        monkeypatch.setenv(obs_store.ENV_VAR, str(tmp_path / "ledger"))
-        bench = _import_bench(monkeypatch)
-        # Run 1: default knobs -> baseline.
-        bench.reset_run_state()
-        rec1 = self._one_rate(bench)
-        assert rec1["plan_source"] == "default"
-        assert rec1["plan_hash"] is None
-        bench.record_run_report()
-        # Run 2: a plan file is in force -> provenance changes, and
-        # --compare must refuse the gate instead of crying regression.
-        bench.reset_run_state()
-        d = tmp_path / "plan"
-        monkeypatch.setenv(plan_planner.ENV_DIR, str(d))
-        plan = _write_plan_file(d, {"stream_cache_bytes": 0})
-        rec2 = self._one_rate(bench)
-        assert rec2["plan_source"] == "autotuned"
-        assert rec2["plan_hash"] == plan_planner.plan_hash(plan)
-        regressions = bench.compare_to_baseline()
-        assert regressions["plan_mismatches"] == 1
-        assert regressions["regressed"] == []
-        entry = [r for r in regressions["rates"]
-                 if r["metric"] == "plan_rate"][0]
-        assert entry["plan_mismatch"] is True
-        assert entry["baseline_plan"]["plan_source"] == "default"
-        line = bench.compare_verdict_line(regressions)
-        assert line.startswith("COMPARE: plan mismatch")
-
-    def test_provenance_snapshot_ignores_bench_internal_env(
-            self, tmp_path, monkeypatch):
-        # Bench's own records inject measurement scaffolding (the
-        # streamed record's chunk env, the capped probes' seams) AFTER
-        # the provenance snapshot; a plain default-knob run must stay
-        # labeled 'default', not 'env-override'.
-        monkeypatch.setenv(obs_store.ENV_VAR, str(tmp_path / "ledger"))
-        bench = _import_bench(monkeypatch)
-        bench.reset_run_state()
-        assert bench.plan_provenance()["plan_source"] == "default"
-        monkeypatch.setenv("PIPELINEDP_TPU_STREAM_CHUNK", "997")
-        rec = self._one_rate(bench)
-        assert rec["plan_source"] == "default"
-        # A fresh run that LAUNCHES under the override is labeled so.
-        bench.reset_run_state()
-        rec2 = self._one_rate(bench)
-        assert rec2["plan_source"] == "env-override"
-        # ...and --compare refuses to gate the env-override run
-        # against the default-knob baseline (both plan hashes are
-        # None, so the SOURCE label is the only tell).
-        regressions = bench.compare_to_baseline()
-        assert regressions["plan_mismatches"] >= 1
-        assert regressions["regressed"] == []
-        entry = [r for r in regressions["rates"]
-                 if r["metric"] == "plan_rate"][0]
-        assert entry["plan_mismatch"] is True
-        assert entry["baseline_plan"]["plan_source"] == "default"
-
-    def test_matching_plans_still_gate(self, tmp_path, monkeypatch):
-        monkeypatch.setenv(obs_store.ENV_VAR, str(tmp_path / "ledger"))
-        d = tmp_path / "plan"
-        monkeypatch.setenv(plan_planner.ENV_DIR, str(d))
-        _write_plan_file(d, {"stream_cache_bytes": 0})
-        bench = _import_bench(monkeypatch)
-        bench.reset_run_state()
-        self._one_rate(bench)
-        bench.reset_run_state()
-        self._one_rate(bench)
-        regressions = bench.compare_to_baseline()
-        assert regressions["plan_mismatches"] == 0
-        entry = [r for r in regressions["rates"]
-                 if r["metric"] == "plan_rate"][0]
-        assert entry["baseline"] is not None
-
-
-class TestAutotuneAcceptance:
-    """The measure→decide→apply loop, in process: ``--autotune``
-    writes a plan file; a subsequent plain streamed run loads it,
-    witnessed by ``plan.applied`` events with ``source: "plan"``."""
-
-    def test_autotune_writes_plan_and_next_run_loads_it(
-            self, tmp_path, monkeypatch):
-        monkeypatch.setenv(obs_store.ENV_VAR, str(tmp_path / "ledger"))
-        monkeypatch.setenv(plan_planner.ENV_DIR, str(tmp_path / "plan"))
-        bench = _import_bench(monkeypatch)
-        bench.reset_run_state()
-        args = argparse.Namespace(rows=3_000, smoke=True)
-        rc = bench.run_autotune(args)
-        assert rc == 0
-        path = plan_planner.plan_path(str(tmp_path / "plan"))
-        assert os.path.exists(path)
-        with open(path, encoding="utf-8") as f:
-            plan = json.load(f)
-        assert plan["fingerprint"] == plan_planner.fingerprint()
-        assert plan["knobs"]["default"]
-        # Trials landed in the ledger for future (windowed) fits.
-        store = obs_store.LedgerStore(str(tmp_path / "ledger"))
-        trials = [e for e in store.entries()
-                  if e["name"] == "autotune.trial"]
-        assert len(trials) == len(plan_pkg.autotune_candidates())
-        # The follow-up plain run resolves the plan (source: "plan").
-        obs.reset()
-        _run_streamed(_dataset(n=3_000, parts=50), _pct_params(),
-                      monkeypatch)
-        applied = [e for e in _events("plan.applied")
-                   if e["source"] == "plan"]
-        assert applied, "plain run after --autotune resolved no plan"
-
-    def test_sweep_trials_never_steered_by_preexisting_plan(
-            self, tmp_path, monkeypatch):
-        # A prior autotune's plan file must not steer this sweep's
-        # trials: a seam pinned AT the registry default falls through
-        # the precedence, so without isolation the plan would silently
-        # win while the ledger labels the trial with its own knobs.
-        monkeypatch.setenv(obs_store.ENV_VAR, str(tmp_path / "ledger"))
-        d = tmp_path / "plan"
-        monkeypatch.setenv(plan_planner.ENV_DIR, str(d))
-        _write_plan_file(d, {"q_chunk": 1, "subhist_byte_cap": 1 << 20,
-                             "ingest_executor": 0})
-        bench = _import_bench(monkeypatch)
-        bench.reset_run_state()
-        obs.reset()
-        rc = bench.run_autotune(
-            argparse.Namespace(rows=3_000, smoke=True))
-        assert rc == 0
-        steered = [e for e in _events("plan.applied")
-                   if e["source"] == "plan"]
-        assert steered == [], (
-            "autotune trials resolved the pre-existing plan file")
-        # The sweep still wrote a fresh plan over the old one.
-        with open(plan_planner.plan_path(str(d)),
-                  encoding="utf-8") as f:
-            plan = json.load(f)
-        assert plan["created_by"] == "bench --autotune"
-        # ...and the plan-dir env survived for the follow-up run.
-        assert os.environ[plan_planner.ENV_DIR] == str(d)
 
 
 class TestNoDirectKnobReads:

@@ -405,7 +405,7 @@ class TestKnobs:
     def test_env_override_resolves(self, monkeypatch):
         from pipelinedp_tpu.plan import knobs
         monkeypatch.setenv("PIPELINEDP_TPU_SKETCH_WIDTH", "1000")
-        v, src = knobs.resolve_value(knobs.BY_NAME["sketch_width"], None)
+        v, src = knobs.resolve_value(knobs.BY_NAME["sketch_width"])
         assert (v, src) == (1000, "env")
         # SketchParams rounds the resolved width to the radix multiple
         assert SketchParams(eps=1.0, delta=0.0).resolved_width() == 1024
@@ -417,12 +417,6 @@ class TestKnobs:
         monkeypatch.setenv("PIPELINEDP_TPU_SKETCH_DEPTH", "7")
         assert SketchParams(eps=1.0, delta=0.0,
                             depth=3).resolved_depth() == 3
-
-    def test_autotune_sweeps_sketch_backend(self):
-        from pipelinedp_tpu import plan as plan_mod
-        cands = plan_mod.autotune_candidates()
-        assert {"sketch_backend": "xla"}.items() <= cands[-1].items()
-        assert all("sketch_width" not in c for c in cands)
 
     def test_params_validation(self):
         with pytest.raises(ValueError, match="eps"):

@@ -45,7 +45,7 @@ def _isolated_topology_registry():
 
 @contextlib.contextmanager
 def topology_env(mode=None, hosts=None):
-    """Pin the mesh_topology knob (env outranks seam and plan) and the
+    """Pin the mesh_topology knob (env outranks seam and default) and the
     simulated host count for the duration — make_mesh reads both; the
     registered topology is what the kernels consult afterwards."""
     pairs = ((TOPOLOGY_ENV, mode),
@@ -499,7 +499,7 @@ class TestShardedSketchParity:
 
 
 # ---------------------------------------------------------------------------
-# Planner-driven sweep chunk sizing
+# Sweep chunk sizing
 # ---------------------------------------------------------------------------
 
 class TestPlannedSweepChunk:
@@ -511,49 +511,3 @@ class TestPlannedSweepChunk:
         assert js._lane_align(100) == 64
         assert js._lane_align(1) == 1
         assert js._lane_align(0) == 1
-
-    def test_no_plan_keeps_static_formula(self, monkeypatch):
-        from pipelinedp_tpu.analysis import jax_sweep as js
-        from pipelinedp_tpu.plan import planner
-        monkeypatch.setattr(planner, "current_cost_model", lambda: None)
-        assert js._plan_chunk(4096, 10_000, 128) == (4096, "static")
-
-    def test_fitted_model_scales_chunk(self, monkeypatch):
-        from pipelinedp_tpu.analysis import jax_sweep as js
-        from pipelinedp_tpu.plan import planner
-
-        class FakeModel:
-            def predict_hbm_peak(self, dk, phase, rows, parts, q):
-                assert phase == "sweep"
-                return js._SWEEP_HBM_BUDGET * 2  # peak 2x over budget
-
-        monkeypatch.setattr(planner, "current_cost_model", FakeModel)
-        chunk, source = js._plan_chunk(512, 10_000, 128)
-        assert source == "model"
-        assert chunk == 256  # halved, already lane-aligned
-        # The static cap still binds when the model would widen.
-        chunk_hi, _ = js._plan_chunk(js._CHUNK_CAP * 8, 10_000, 128)
-        assert 1 <= chunk_hi <= js._CHUNK_CAP
-
-    def test_poisoned_history_fits_empty_model_and_falls_back(
-            self, monkeypatch):
-        """A ledger of degraded runs and foreign fingerprints fits an
-        EMPTY cost model (plan/model.py skips both), whose predictions
-        are all None — the chunk sizing must degrade to the static
-        formula, never to a fit over poisoned samples."""
-        from pipelinedp_tpu.analysis import jax_sweep as js
-        from pipelinedp_tpu.plan import model, planner
-        entries = [
-            {"fingerprint": "me", "degraded": True, "device_costs": [
-                {"phase": "sweep", "rows": 10_000, "partitions": 128,
-                 "quantiles": 0, "hbm_peak": 123456}]},
-            {"fingerprint": "someone-else", "device_costs": [
-                {"phase": "sweep", "rows": 10_000, "partitions": 128,
-                 "quantiles": 0, "hbm_peak": 123456}]},
-        ]
-        poisoned = model.fit(entries, fingerprint="me")
-        assert poisoned.predict_hbm_peak(
-            None, "sweep", 10_000, 128, 0) is None
-        monkeypatch.setattr(planner, "current_cost_model",
-                            lambda: poisoned)
-        assert js._plan_chunk(4096, 10_000, 128) == (4096, "static")
